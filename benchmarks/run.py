@@ -1029,6 +1029,8 @@ ALL = {"fig2": fig2_ssm_operator_profile,
 
 def main() -> None:
     global OBS_TRACE
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     argv = list(sys.argv[1:])
     if "--obs-trace" in argv:
         i = argv.index("--obs-trace")

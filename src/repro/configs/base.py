@@ -72,7 +72,9 @@ class ArchConfig:
     # execution
     dtype: str = "bfloat16"           # activation/compute dtype
     param_dtype: str = "float32"
-    use_pallas: bool = False          # flip on real TPU for kernel hot paths
+    use_pallas: bool = False          # Pallas kernels for conv + scan
+                                      # (Mosaic on a TPU, interpreted on
+                                      # the CPU; the XLA scan otherwise)
     pallas_schedule: str = "blocked"  # step | blocked (Pallas scan kernel;
                                       # blocked = SSD-style subtile matmuls,
                                       # step = per-step reference walk)

@@ -148,8 +148,21 @@ def param_pspecs(params_shape, mesh: Mesh):
     return jax.tree_util.tree_map_with_path(one, params_shape)
 
 
-def shardings_for(tree_shape, pspecs, mesh: Mesh):
-    return jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs)
+def named(mesh: Mesh, pspecs):
+    """PartitionSpec tree → NamedSharding tree on ``mesh``."""
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
+                        is_leaf=lambda x: isinstance(x, P))
+
+
+def train_state_pspecs(state_shape, mesh: Mesh):
+    """PartitionSpecs for a Trainer state ``{"params", "opt": AdamWState}``:
+    the optimizer moments (and the f32 master copy, when there is one)
+    inherit the param spec; the step counter is replicated."""
+    pspec = param_pspecs(state_shape["params"], mesh)
+    opt = state_shape["opt"]
+    return {"params": pspec,
+            "opt": opt._replace(step=P(), m=pspec, v=pspec,
+                                master=None if opt.master is None else pspec)}
 
 
 # ---------------------------------------------------------------------------

@@ -2,9 +2,10 @@
 layout transposes, custom_vjp wiring, and backend dispatch.
 
 ``backend='xla'`` routes to the chunked pure-JAX implementations in
-repro.core (the dry-run / roofline path — SPMD-partitionable and visible to
-cost_analysis); ``backend='pallas'`` routes to the TPU kernels (validated in
-interpret mode on CPU; the path you flip on real v5e).
+repro.core (SPMD-partitionable and visible to cost_analysis);
+``backend='pallas'`` routes to the TPU kernels — compiled by Mosaic on a
+TPU, run under the Pallas interpreter on the CPU (kernels/__init__.py
+decides from the platform the call is lowered for).
 
 Structure note: the custom_vjp is defined over *already padded, fully
 normalized* operands (no Nones, tile-multiple shapes); the public wrappers
@@ -26,6 +27,20 @@ from repro.kernels import conv1d_pack as conv_k
 from repro.kernels import selective_scan as scan_k
 
 _F0 = jax.dtypes.float0
+
+
+def _chunk(chunk: int, L: int) -> int:
+    """Kernel chunk length: ``chunk``, or L rounded up to whole 8-row
+    sublane groups when the sequence is shorter than one chunk."""
+    return min(chunk, -(-L // 8) * 8)
+
+
+def _positions(positions, Bz: int, L: int, T: int):
+    """(B, L, 1) i32 position column padded to whole chunks with pos=1 (no
+    reset); ``None`` means one unpacked sequence per row."""
+    pos = positions if positions is not None else \
+        jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (Bz, L))
+    return _pad_to(pos.astype(jnp.int32), 1, T, value=1)[..., None]
 
 
 def _pad_to(x: jnp.ndarray, axis: int, mult: int, value=0):
@@ -134,7 +149,7 @@ def selective_scan(u, delta, A, B, C, D=None, positions=None, *,
         raise ValueError(f"unknown backend {backend!r}")
     Bz, L, Dm = u.shape
     bd = min(block_d, max(Dm, 8))
-    T = min(chunk, L)
+    T = _chunk(chunk, L)
     # channel padding: A=0 ⇒ a=1 but b=0 keeps padded h = 0; y sliced off
     up, dtp = _pad_to(u, 2, bd), _pad_to(delta, 2, bd)
     At = _pad_to(A.T, 1, bd)
@@ -143,9 +158,7 @@ def selective_scan(u, delta, A, B, C, D=None, positions=None, *,
     # L padding: pos=1 (no reset), delta=0 ⇒ a=1 carry; y sliced off
     up, dtp = _pad_to(up, 1, T), _pad_to(dtp, 1, T)
     Bp, Cp = _pad_to(B, 1, T), _pad_to(C, 1, T)
-    pos = positions if positions is not None else \
-        jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (Bz, L))
-    posp = _pad_to(pos.astype(jnp.int32), 1, T, value=1)
+    posp = _positions(positions, Bz, L, T)
     y = _scan_padded(up, dtp, At, Bp, Cp, Dp, posp, bd, T, schedule, sub_t)
     return y[:, :L, :Dm]
 
@@ -235,18 +248,16 @@ def selective_scan_heads(u, delta, A, B, C, D=None, positions=None, *,
     if schedule not in ("blocked_heads", "blocked_heads_dual"):
         raise ValueError(f"unknown heads schedule {schedule!r}")
     Bz, L, H, P = u.shape
-    T = min(chunk, L)
+    T = _chunk(chunk, L)
     uh = jnp.moveaxis(u, 2, 1)                       # (B, H, L, P)
-    dth = jnp.moveaxis(delta, 2, 1)                  # (B, H, L)
-    Ah = A.astype(jnp.float32)[:, None]              # (H, 1)
+    dth = jnp.moveaxis(delta, 2, 1)[..., None]       # (B, H, L, 1)
+    Ah = A.astype(jnp.float32)[:, None, None]        # (H, 1, 1)
     Dp = (D if D is not None else
-          jnp.zeros(H, u.dtype)).astype(jnp.float32)[:, None]
+          jnp.zeros(H, u.dtype)).astype(jnp.float32)[:, None, None]
     # L padding: pos=1 (no reset), delta=0 ⇒ decay 1 / b-term 0 (carry)
     uh, dth = _pad_to(uh, 2, T), _pad_to(dth, 2, T)
     Bp, Cp = _pad_to(B, 1, T), _pad_to(C, 1, T)
-    pos = positions if positions is not None else \
-        jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (Bz, L))
-    posp = _pad_to(pos.astype(jnp.int32), 1, T, value=1)
+    posp = _positions(positions, Bz, L, T)
     y = _scan_heads_padded(uh, dth, Ah, Bp, Cp, Dp, posp, T, schedule, sub_t)
     return jnp.moveaxis(y, 1, 2)[:, :L]
 
@@ -278,7 +289,7 @@ def _conv_bwd_rule(block_d, chunk, res, dy):
     dws = []
     for k in range(W):                    # weight row j = W-1-k ↔ back-off k
         shifted = jnp.pad(x32, ((0, 0), (k, 0), (0, 0)))[:, :Lp]
-        masked = jnp.where((pos >= k)[..., None], shifted, 0.0)
+        masked = jnp.where(pos >= k, shifted, 0.0)
         dws.append(jnp.einsum("bld,bld->d", dy32, masked))
     dw = jnp.stack(dws[::-1], axis=0).astype(weight.dtype)
     dbias = dy32.sum((0, 1))[None, :].astype(bias.dtype)
@@ -298,13 +309,11 @@ def conv1d_pack(x, weight, bias=None, positions=None, *,
         raise ValueError(f"unknown backend {backend!r}")
     Bz, L, Dm = x.shape
     bd = min(block_d, max(Dm, 8))
-    T = min(chunk, L)
+    T = _chunk(chunk, L)
     xp = _pad_to(_pad_to(x, 2, bd), 1, T)
     wp = _pad_to(weight, 1, bd)
     bp = _pad_to((bias if bias is not None else
                   jnp.zeros(Dm, x.dtype))[None, :], 1, bd)
-    pos = positions if positions is not None else \
-        jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (Bz, L))
-    posp = _pad_to(pos.astype(jnp.int32), 1, T, value=1)
+    posp = _positions(positions, Bz, L, T)
     y = _conv_padded(xp, wp, bp, posp, bd, T)
     return y[:, :L, :Dm]

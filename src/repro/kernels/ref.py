@@ -58,23 +58,17 @@ def conv1d_pack_ref(x: jnp.ndarray, weight: jnp.ndarray,
 
     Causal depthwise conv; tap reaching back k is dropped when
     k > positions[t] (Algorithm 1)."""
-    Bz, L, Dm = x.shape
     W = weight.shape[0]
     f = jnp.float32
     x32 = x.astype(f)
-    y = jnp.zeros((Bz, L, Dm), f)
-    for t in range(L):
-        acc = jnp.zeros((Bz, Dm), f)
-        for k in range(W):
-            src = t - k
-            if src < 0:
-                continue
-            tap = x32[:, src] * weight[W - 1 - k].astype(f)
-            if positions is not None:
-                ok = positions[:, t] >= k
-                tap = jnp.where(ok[:, None], tap, 0.0)
-            acc = acc + tap
-        y = y.at[:, t].set(acc)
+    y = jnp.zeros(x.shape, f)
+    for k in range(W):
+        # tap k: x[t - k] (zero before the buffer start)
+        tap = jnp.pad(x32, ((0, 0), (k, 0), (0, 0)))[:, :x.shape[1]]
+        tap = tap * weight[W - 1 - k].astype(f)
+        if positions is not None:
+            tap = jnp.where((positions >= k)[..., None], tap, 0.0)
+        y = y + tap
     if bias is not None:
         y = y + bias.astype(f)
     return y.astype(x.dtype)

@@ -10,61 +10,53 @@ coalesced loads. The TPU-native reformulation:
     sequence-chunk dimension is sequential and the recurrent state ``h``
     lives in a VMEM scratch that persists across grid steps along it —
     the TPU analogue of the chunk-carried scan.
-  * The ``(N=16, bd=128)`` state layout matches the (sublane, lane) native
-    tile exactly — one f32 VREG pair per state tile, so the per-step update
-    ``h = a⊙h + b`` is pure VPU work with no relayout.
+  * Every in-kernel value is 2-D with time on sublanes and channels on
+    lanes; the ``(N=16, bd=128)`` state tile is one f32 VREG pair, so the
+    per-step update ``h = a⊙h + b`` is pure VPU work with no relayout.
+    Row reads are aligned groups (``pl.multiple_of``) and per-step scalars
+    come from static slices of those groups.
   * ``position_indices`` ride the same BlockSpec pipeline as the
-    activations: one (1, T) int32 VMEM block per grid step — a single DMA
-    amortized over the whole (T × bd) tile, the VMEM counterpart of the
-    paper's coalesced-HBM/SRAM staging. Inside the loop the reset test
-    ``pos[t] == 0`` folds into the decay computation (Ā→0), costing zero
+    activations: one (T, 1) int32 column per grid step (positions arrive
+    as (B, L, 1)) — a single DMA amortized over the whole (T × bd) tile,
+    the VMEM counterpart of the paper's coalesced-HBM/SRAM staging. The
+    reset test ``pos[t] == 0`` folds into the decay (Ā→0), costing zero
     extra memory passes — their "no extra kernel overhead" property.
+  * Cumulative sums (decay exponents, reset counts) are matmuls with a
+    lower-triangular ones matrix on the MXU, at f32 (``HIGHEST``)
+    precision.
   * The backward pass (paper §3.4: "modifications only require setting
     Ā_{pos==0}→0" in the reverse scans) is a second kernel that walks the
     L-grid in *reverse*, recomputes h within each chunk from a per-chunk
     checkpoint saved by the forward (flash-style recompute: checkpoints are
     L/T× smaller than the full state trajectory), and carries the adjoint
-    dh in VMEM scratch.
+    in VMEM scratch.
 
-VMEM budget per grid step (T=256, bd=128, N=16, f32):
-  in/out blocks: u, Δ, y (3 × T·bd·4 = 384 KiB) + B, C (2 × T·N·4 = 32 KiB)
-  + A (8 KiB) + pos (1 KiB); scratch h (8 KiB); bwd adds h_buf
-  ((T+1)·N·bd·4 ≈ 2.06 MiB) + dh/dA (16 KiB) — comfortably inside the
-  ~16 MiB/core VMEM with room for double buffering.
+VMEM per grid step at the defaults (T=256, bd=128, N=16): blocks ≈ 1.5 MiB
+double-buffered; the backward adds the recomputed (N, T, bd) state and
+adjoint trajectories, 2 MiB each — inside v5e's 16 MiB scoped default.
 
-Three schedules share this grid/BlockSpec structure (`schedule=` knob):
-  * ``step``    — the kernels above: a per-step fori_loop VPU walk. The
-                  reference path; matches the paper's ScanOp_pack closely.
+Schedules (``schedule=`` knob):
+  * ``step``    — per-step walk over aligned row groups. The reference
+                  path; matches the paper's ScanOp_pack closely.
   * ``blocked`` — SSD-style (Gu & Dao duality): each in-chunk subtile of
-                  length Tt is evaluated at once as a masked
-                  cumulative-decay contraction dec @ b (see
-                  core/ssm.py::_blocked_ssm for the math). The sequential
-                  chain shrinks T→T/Tt and the (Tt, Tt, N, bd) contraction
-                  is dense matmul-shaped work the MXU can absorb, instead
-                  of T dependent (N, bd) VPU updates that leave it idle —
-                  the Baruah et al. bottleneck this PR attacks. Backward
-                  blocks the same way (transpose contraction for the
-                  adjoint scan; elementwise grads fully vectorized).
-                  Extra VMEM: ~4 MiB (gbuf + subtile dec) at defaults.
+                  length Tt is evaluated at once, per state row n, as the
+                  masked cumulative-decay contraction over (Tt, Tt, bd)
+                  (core/ssm.py::_blocked_ssm has the math). The sequential
+                  chain shrinks T→T/Tt. Backward blocks the same way
+                  (transpose contraction for the adjoint scan; elementwise
+                  grads vectorized over the chunk).
   * ``blocked_heads`` — head-structured (Mamba-2/SSD proper): grid
                   ``(B, H, L/T)``, per-head SCALAR decay, state (dh, N) per
                   head in VMEM scratch. The masked cumulative-decay matrix
-                  is one (Tt, Tt) f32 tile per head (vs (Tt, Tt, N, bd) for
-                  ``blocked``), and the entire subtile evaluates as ONE
-                  dense (Tt, Tt) @ (Tt, dh·N) matmul — the widest MXU shape
-                  of the three, with ~N·bd/Tt× less decay-matrix traffic.
-                  Backward mirrors ``blocked``: transpose contraction
-                  (Tt, Tt)ᵀ @ (Tt, dh·N) for the adjoint scan, elementwise
-                  grads vectorized over the chunk, per-head dA/dD scalar
-                  accumulators. Operands arrive head-major ((B, H, L, dh) /
-                  (B, H, L)); ops.py does the layout transpose.
+                  is one (Tt, Tt) tile per head; the in-chunk states are
+                  formed explicitly, one state column n at a time, each a
+                  (Tt, Tt) @ (Tt, dh) matmul.
   * ``blocked_heads_dual`` — the attention-like dual form of
                   ``blocked_heads`` (structured-state-space duality): the
                   (Tt, Tt) decay folds into a C·Bᵀ Gram matrix and outputs
                   come straight from (Tt, Tt) @ (Tt, dh) matmuls without
-                  forming the in-chunk (Tt, dh, N) states — Tt²·(dh + N)
-                  FLOPs vs the quad form's Tt²·dh·N, the measured winner at
-                  dh ≫ Tt. Shares the ``blocked_heads`` backward kernel
+                  forming the in-chunk states. Both heads schedules share
+                  one backward kernel, written in the same dual form
                   (identical ckpt contract; adjoint math is schedule-free).
                   The quad-vs-dual pick, chunk, and subtile are shape-keyed
                   autotuner decisions (repro/tune), not constants.
@@ -72,21 +64,27 @@ Three schedules share this grid/BlockSpec structure (`schedule=` knob):
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
+from repro.kernels import pallas_call
 
 DEF_BLOCK_D = 128
 DEF_CHUNK_T = 256
 DEF_SUB_T = 16     # blocked schedule: in-chunk subtile for the M contraction
 #   (the *default* — every kernel entry takes an explicit ``sub_t`` so the
 #   shape-keyed autotuner (repro/tune) can sweep measured subtiles instead)
-INTERPRET = True   # flipped by ops.configure_for_tpu() on real hardware
+
+f32 = jnp.float32
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+_NN = ((1,), (0,))      # a @ b
+_NT = ((1,), (1,))      # a @ bᵀ
+_TN = ((0,), (0,))      # aᵀ @ b
 
 
 def _pick_subtile(T: int, sub_t=None) -> int:
@@ -109,288 +107,168 @@ def _pick_subtile(T: int, sub_t=None) -> int:
     return 1
 
 
+def _dot(a, b, dims=_NN):
+    return jax.lax.dot_general(a, b, (dims, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=f32)
+
+
+def _tri(n: int, strict: bool = False, upper: bool = False):
+    """(n, n) f32 ones where j ≤ i (j < i if strict); transposed if upper.
+    ``_tri(n) @ x`` is the inclusive cumulative sum of x along rows."""
+    i = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    if upper:
+        i, j = j, i
+    return ((j < i) if strict else (j <= i)).astype(f32)
+
+
+def _last(v):
+    """Last row of a (tt, 1) column as a (1, 1) value. Built by a reduction:
+    Mosaic cannot broadcast a sliced (1, 1) across both sublanes and lanes."""
+    row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+    return jnp.sum(jnp.where(row == v.shape[0] - 1, v, 0.0), axis=0,
+                   keepdims=True)
+
+
+def _rowsel(n: int, width: int):
+    """Row index of an (n, width) tile, for assembling rows with where()."""
+    return jax.lax.broadcasted_iota(jnp.int32, (n, width), 0)
+
+
+def _rows(pos_ref, u_ref, dt_ref, Bm_ref, Cm_ref, t0, n):
+    """Rows [t0, t0+n) of one chunk as f32: dt, u (n, bd); B, C (n, N);
+    keep (n, 1) — False where a packed sequence starts (the Ā→0 reset)."""
+    sl = pl.ds(t0, n)
+    return (dt_ref[0, sl, :].astype(f32), u_ref[0, sl, :].astype(f32),
+            Bm_ref[0, sl, :].astype(f32), Cm_ref[0, sl, :].astype(f32),
+            pos_ref[0, sl, :] != 0)
+
+
+def _at(i, n: int):
+    """Start row of the i-th group of n rows, marked aligned for Mosaic."""
+    return pl.multiple_of(i * n, n)
+
+
 # ---------------------------------------------------------------------------
-# forward kernel
+# Mamba-1 (per-channel decay) forward kernels
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(pos_ref, u_ref, dt_ref, At_ref, Bm_ref, Cm_ref, Dp_ref,
                 y_ref, ckpt_ref, h_ref):
-    """One (b, d-block, l-chunk) grid step.
+    """One (b, d-block, l-chunk) grid step, per-step walk.
 
-    pos (1,T) i32 | u, dt (1,T,bd) | At (N,bd) | Bm, Cm (1,T,N) | Dp (1,bd)
-    y (1,T,bd) | ckpt (1,1,N,bd) — chunk-entry state | h scratch (N,bd) f32.
+    pos (1,T,1) i32 | u, dt (1,T,bd) | At (N,bd) | Bm, Cm (1,T,N) |
+    Dp (1,bd) | y (1,T,bd) | ckpt (1,1,N,bd) — chunk-entry state |
+    h scratch (N,bd) f32.
     """
     T = u_ref.shape[1]
+    G = _pick_subtile(T)
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
     ckpt_ref[0, 0] = h_ref[...]          # h at chunk entry (for backward)
-    At = At_ref[...].astype(jnp.float32)          # (N, bd)
-    Dp = Dp_ref[0, :].astype(jnp.float32)         # (bd,)
+    At = At_ref[...].astype(f32)                  # (N, bd)
+    Dp = Dp_ref[...].astype(f32)                  # (1, bd)
+    row = _rowsel(G, At.shape[1])
 
-    def step(t, _):
-        dt = dt_ref[0, t, :].astype(jnp.float32)              # (bd,)
-        u_t = u_ref[0, t, :].astype(jnp.float32)
-        a = jnp.exp(dt[None, :] * At)                         # (N, bd)
-        a = jnp.where(pos_ref[0, t] == 0, 0.0, a)             # PackMamba reset
-        b = Bm_ref[0, t, :].astype(jnp.float32)[:, None] * \
-            (dt * u_t)[None, :]                               # (N, bd)
-        h = a * h_ref[...] + b
+    def group(gi, _):
+        t0 = _at(gi, G)
+        dt, u, Bv, Cv, keep = _rows(pos_ref, u_ref, dt_ref, Bm_ref, Cm_ref,
+                                    t0, G)
+        BT, CT = Bv.T, Cv.T                       # (N, G)
+        du = dt * u
+        kf = jnp.where(keep, jnp.ones_like(dt), jnp.zeros_like(dt))
+        h = h_ref[...]
+        y = Dp * u
+        for i in range(G):
+            a = jnp.exp(dt[i:i + 1] * At) * kf[i:i + 1]
+            h = a * h + BT[:, i:i + 1] * du[i:i + 1]
+            y_i = jnp.sum(h * CT[:, i:i + 1], axis=0, keepdims=True)
+            y = y + jnp.where(row == i, y_i, 0.0)
         h_ref[...] = h
-        y = jnp.sum(h * Cm_ref[0, t, :].astype(jnp.float32)[:, None], axis=0)
-        y_ref[0, t, :] = (y + Dp * u_t).astype(y_ref.dtype)
+        y_ref[0, pl.ds(t0, G), :] = y.astype(y_ref.dtype)
         return ()
 
-    jax.lax.fori_loop(0, T, step, ())
+    jax.lax.fori_loop(0, T // G, group, ())
 
 
-# ---------------------------------------------------------------------------
-# forward kernel — blocked (SSD-style) schedule
-# ---------------------------------------------------------------------------
+def _blocked_tile(pos_ref, u_ref, dt_ref, Bm_ref, Cm_ref, t0, tt):
+    """Shared per-subtile tensors of the Mamba-1 blocked schedule:
+    the rows, cdt = Σ_{k≤i} dt_k (tt, bd), rid = resets in [0, i] (tt, 1),
+    and the (tt, tt, 1) mask [j ≤ i]·[no reset in (j, i]]."""
+    dt, u, Bv, Cv, keep = _rows(pos_ref, u_ref, dt_ref, Bm_ref, Cm_ref,
+                                t0, tt)
+    incl = _tri(tt)
+    cdt = _dot(incl, dt)
+    rid = _dot(incl, (~keep).astype(f32))
+    i3 = jax.lax.broadcasted_iota(jnp.int32, (tt, tt, 1), 0)
+    j3 = jax.lax.broadcasted_iota(jnp.int32, (tt, tt, 1), 1)
+    same = (rid[:, None, :] == rid[None, :, :]) & (i3 >= j3)
+    return dt, u, Bv, Cv, keep, cdt, rid, same
+
+
+def _pair_decay(s, same):
+    """dec[i, j, :] = exp(s_i − s_j) where ``same`` else 0 — (tt, tt, bd)."""
+    diff = s[:, None, :] - s[None, :, :]
+    return jnp.where(same, jnp.exp(jnp.where(same, diff, 0.0)), 0.0)
+
 
 def _fwd_kernel_blocked(pos_ref, u_ref, dt_ref, At_ref, Bm_ref, Cm_ref,
                         Dp_ref, y_ref, ckpt_ref, h_ref, *, sub_t):
     """Same block shapes and carry semantics as ``_fwd_kernel``, but instead
     of T dependent per-step VPU updates, each in-chunk subtile of length Tt
-    is evaluated at once via the masked cumulative-decay contraction
-    (core/ssm.py 'blocked'/'matmul' formulation):
+    is evaluated at once, per state row n, via the masked cumulative-decay
+    contraction (core/ssm.py 'blocked'/'matmul' formulation):
 
+        s_i      = A_n · Σ_{k≤i} Δ_k
         dec[i,j] = exp(s_i − s_j)·[j ≤ i]·[no reset in (j, i]]
         h_i      = Σ_j dec[i,j]·b_j + 1[no reset ≤ i]·exp(s_i)·h_carry
 
-    The sequential chain shrinks from T steps to T/Tt subtile steps; the
-    (Tt, Tt, N, bd) contraction is dense matmul-shaped work for the MXU.
-    Peak extra VMEM: Tt²·N·bd f32 (2 MiB at Tt=16, bd=128, N=16).
+    The sequential chain shrinks from T steps to T/Tt subtile steps.
     """
     T = u_ref.shape[1]
-    nsub = T // sub_t
+    N = At_ref.shape[0]
+    tt = sub_t
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
     ckpt_ref[0, 0] = h_ref[...]
-    At = At_ref[...].astype(jnp.float32)              # (N, bd)
-    Dp = Dp_ref[0, :].astype(jnp.float32)             # (bd,)
-    ii = jax.lax.broadcasted_iota(jnp.int32, (sub_t, sub_t), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (sub_t, sub_t), 1)
-    causal = ii >= jj
+    At = At_ref[...].astype(f32)                  # (N, bd)
+    Dp = Dp_ref[...].astype(f32)                  # (1, bd)
 
     def sub(si, _):
-        t0 = si * sub_t
-        dt = dt_ref[0, pl.ds(t0, sub_t), :].astype(jnp.float32)   # (Tt, bd)
-        u_t = u_ref[0, pl.ds(t0, sub_t), :].astype(jnp.float32)
-        Bv = Bm_ref[0, pl.ds(t0, sub_t), :].astype(jnp.float32)   # (Tt, N)
-        Cv = Cm_ref[0, pl.ds(t0, sub_t), :].astype(jnp.float32)
-        r = pos_ref[0, pl.ds(t0, sub_t)] == 0                     # (Tt,)
-        la = dt[:, None, :] * At[None]                            # (Tt, N, bd)
-        s = jnp.cumsum(la, axis=0)
-        rid = jnp.cumsum(r.astype(jnp.int32))
-        m = (rid[:, None] == rid[None, :]) & causal               # (Tt, Tt)
-        mm = m[..., None, None]
-        diff = s[:, None] - s[None, :]                     # (Tt, Tt, N, bd)
-        dec = jnp.where(mm, jnp.exp(jnp.where(mm, diff, 0.0)), 0.0)
-        bt = Bv[..., None] * (dt * u_t)[:, None, :]               # (Tt, N, bd)
-        h = jnp.sum(dec * bt[None], axis=1)                       # Σ_j
-        cin = jnp.where((rid == 0)[:, None, None], jnp.exp(s), 0.0)
-        h = h + cin * h_ref[...][None]
-        y = jnp.sum(h * Cv[..., None], axis=1)                    # (Tt, bd)
-        y_ref[0, pl.ds(t0, sub_t), :] = (y + Dp[None] * u_t).astype(
-            y_ref.dtype)
-        h_ref[...] = h[-1]
+        t0 = _at(si, tt)
+        dt, u, Bv, Cv, _, cdt, rid, same = _blocked_tile(
+            pos_ref, u_ref, dt_ref, Bm_ref, Cm_ref, t0, tt)
+        du = dt * u
+        carry_in = rid == 0                       # (tt, 1)
+        h0 = h_ref[...]
+        y = Dp * u
+        for n in range(N):
+            s = cdt * At[n:n + 1]                 # (tt, bd)
+            h = jnp.sum(_pair_decay(s, same) * (Bv[:, n:n + 1] * du)[None],
+                        axis=1)                   # Σ_j
+            h = h + jnp.where(carry_in, jnp.exp(s), 0.0) * h0[n:n + 1]
+            y = y + Cv[:, n:n + 1] * h
+            h_ref[n:n + 1, :] = h[tt - 1:tt]
+        y_ref[0, pl.ds(t0, tt), :] = y.astype(y_ref.dtype)
         return ()
 
-    jax.lax.fori_loop(0, nsub, sub, ())
-
-
-# ---------------------------------------------------------------------------
-# forward kernel — blocked_heads (scalar per-head decay) schedule
-# ---------------------------------------------------------------------------
-
-def _fwd_kernel_blocked_heads(pos_ref, u_ref, dt_ref, A_ref, Bm_ref, Cm_ref,
-                              Dp_ref, y_ref, ckpt_ref, h_ref, *, sub_t):
-    """One (b, head, l-chunk) grid step, scalar per-head decay.
-
-    pos (1,T) i32 | u (1,1,T,P) | dt (1,1,T) | A, Dp (1,1) scalars |
-    Bm, Cm (1,T,N) | y (1,1,T,P) | ckpt (1,1,1,P,N) | h scratch (P,N) f32.
-
-    Per subtile of length Tt the masked cumulative-decay matrix is a single
-    (Tt, Tt) tile and all states evaluate as ONE matmul:
-
-        dec[i,j] = exp(s_i − s_j)·[j ≤ i]·[no reset in (j, i]]
-        h        = dec @ bterm.reshape(Tt, P·N)   + carry·exp(s)
-        y_i      = Σ_n h[i,·,n]·C[i,n] + D·u_i
-    """
-    T = u_ref.shape[2]
-    P = u_ref.shape[3]
-    N = Bm_ref.shape[2]
-    nsub = T // sub_t
-
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        h_ref[...] = jnp.zeros_like(h_ref)
-
-    ckpt_ref[0, 0, 0] = h_ref[...]
-    A = A_ref[0, 0]                                    # per-head scalar
-    Dp = Dp_ref[0, 0]
-    ii = jax.lax.broadcasted_iota(jnp.int32, (sub_t, sub_t), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (sub_t, sub_t), 1)
-    causal = ii >= jj
-
-    def sub(si, _):
-        t0 = si * sub_t
-        dt = dt_ref[0, 0, pl.ds(t0, sub_t)].astype(jnp.float32)   # (Tt,)
-        u_t = u_ref[0, 0, pl.ds(t0, sub_t), :].astype(jnp.float32)  # (Tt,P)
-        Bv = Bm_ref[0, pl.ds(t0, sub_t), :].astype(jnp.float32)   # (Tt, N)
-        Cv = Cm_ref[0, pl.ds(t0, sub_t), :].astype(jnp.float32)
-        r = pos_ref[0, pl.ds(t0, sub_t)] == 0                     # (Tt,)
-        s = jnp.cumsum(dt * A)                                    # (Tt,)
-        rid = jnp.cumsum(r.astype(jnp.int32))
-        m = (rid[:, None] == rid[None, :]) & causal               # (Tt, Tt)
-        diff = s[:, None] - s[None, :]
-        dec = jnp.where(m, jnp.exp(jnp.where(m, diff, 0.0)), 0.0)
-        bt = Bv[:, None, :] * (dt[:, None] * u_t)[:, :, None]     # (Tt,P,N)
-        h = jnp.dot(dec, bt.reshape(sub_t, P * N),
-                    preferred_element_type=jnp.float32).reshape(sub_t, P, N)
-        cin = jnp.where(rid == 0, jnp.exp(s), 0.0)                # (Tt,)
-        h = h + cin[:, None, None] * h_ref[...][None]
-        y = jnp.sum(h * Cv[:, None, :], axis=2)                   # (Tt, P)
-        y_ref[0, 0, pl.ds(t0, sub_t), :] = (y + Dp * u_t).astype(
-            y_ref.dtype)
-        h_ref[...] = h[-1]
-        return ()
-
-    jax.lax.fori_loop(0, nsub, sub, ())
-
-
-# ---------------------------------------------------------------------------
-# forward kernel — blocked_heads_dual (C·Bᵀ attention-like) schedule
-# ---------------------------------------------------------------------------
-
-def _fwd_kernel_blocked_heads_dual(pos_ref, u_ref, dt_ref, A_ref, Bm_ref,
-                                   Cm_ref, Dp_ref, y_ref, ckpt_ref, h_ref, *,
-                                   sub_t):
-    """Dual-form twin of ``_fwd_kernel_blocked_heads`` (same grid, block
-    shapes, carry semantics, and ckpt output — so the quad backward kernel
-    serves both). Per subtile the masked decay folds into the (Tt, Tt)
-    C·Bᵀ Gram matrix and the outputs come straight from two matmuls,
-    without forming the (Tt, P, N) in-chunk states:
-
-        G        = dec ⊙ (C @ Bᵀ)                 (Tt, Tt)
-        y        = G @ (Δ·u)  +  cin·(C @ h_inᵀ)  (Tt,Tt)@(Tt,P)
-        h_new    = dec[last,:] @ bterm  +  cin[last]·h_in
-
-    FLOPs Tt²·(N + P) + Tt·P·N vs the quad form's Tt²·P·N — the measured
-    winner when dh ≫ Tt (see repro/tune; core/ssm.py has the XLA math).
-    """
-    T = u_ref.shape[2]
-    P = u_ref.shape[3]
-    N = Bm_ref.shape[2]
-    nsub = T // sub_t
-
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        h_ref[...] = jnp.zeros_like(h_ref)
-
-    ckpt_ref[0, 0, 0] = h_ref[...]
-    A = A_ref[0, 0]                                    # per-head scalar
-    Dp = Dp_ref[0, 0]
-    ii = jax.lax.broadcasted_iota(jnp.int32, (sub_t, sub_t), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (sub_t, sub_t), 1)
-    causal = ii >= jj
-
-    def sub(si, _):
-        t0 = si * sub_t
-        dt = dt_ref[0, 0, pl.ds(t0, sub_t)].astype(jnp.float32)   # (Tt,)
-        u_t = u_ref[0, 0, pl.ds(t0, sub_t), :].astype(jnp.float32)  # (Tt,P)
-        Bv = Bm_ref[0, pl.ds(t0, sub_t), :].astype(jnp.float32)   # (Tt, N)
-        Cv = Cm_ref[0, pl.ds(t0, sub_t), :].astype(jnp.float32)
-        r = pos_ref[0, pl.ds(t0, sub_t)] == 0                     # (Tt,)
-        s = jnp.cumsum(dt * A)                                    # (Tt,)
-        rid = jnp.cumsum(r.astype(jnp.int32))
-        m = (rid[:, None] == rid[None, :]) & causal               # (Tt, Tt)
-        diff = s[:, None] - s[None, :]
-        dec = jnp.where(m, jnp.exp(jnp.where(m, diff, 0.0)), 0.0)
-        du = dt[:, None] * u_t                                    # (Tt, P)
-        h_in = h_ref[...]                                         # (P, N)
-        G = dec * jnp.dot(Cv, Bv.T, preferred_element_type=jnp.float32)
-        cin = jnp.where(rid == 0, jnp.exp(s), 0.0)                # (Tt,)
-        y = jnp.dot(G, du, preferred_element_type=jnp.float32)
-        y = y + cin[:, None] * jnp.dot(Cv, h_in.T,
-                                       preferred_element_type=jnp.float32)
-        bt = Bv[:, None, :] * du[:, :, None]                      # (Tt,P,N)
-        h_new = jnp.dot(dec[-1][None, :], bt.reshape(sub_t, P * N),
-                        preferred_element_type=jnp.float32).reshape(P, N)
-        h_ref[...] = h_new + cin[-1] * h_in
-        y_ref[0, 0, pl.ds(t0, sub_t), :] = (y + Dp * u_t).astype(
-            y_ref.dtype)
-        return ()
-
-    jax.lax.fori_loop(0, nsub, sub, ())
-
-
-_HEADS_FWD_KERNELS = {"blocked_heads": _fwd_kernel_blocked_heads,
-                      "blocked_heads_dual": _fwd_kernel_blocked_heads_dual}
-
-
-def selective_scan_heads_fwd_pallas(u, delta, Ah, Bm, Cm, Dp, positions,
-                                    chunk: int = DEF_CHUNK_T,
-                                    schedule: str = "blocked_heads",
-                                    sub_t: Optional[int] = None,
-                                    interpret: Optional[bool] = None):
-    """Head-major shapes (already padded/transposed by ops.py):
-    u (B, H, L, P); delta (B, H, L); Ah, Dp (H, 1); Bm, Cm (B, L, N);
-    positions (B, L) i32. ``schedule``: 'blocked_heads' (quad/state form) |
-    'blocked_heads_dual' (C·Bᵀ attention-like form; same ckpt contract).
-    Returns (y (B, H, L, P), ckpts (B, H, L/T, P, N))."""
-    Bz, H, L, P = u.shape
-    N = Bm.shape[-1]
-    T = chunk
-    nL = L // T
-    grid = (Bz, H, nL)
-    if schedule not in _HEADS_FWD_KERNELS:
-        raise ValueError(f"unknown heads schedule {schedule!r}")
-    kernel = functools.partial(_HEADS_FWD_KERNELS[schedule],
-                               sub_t=_pick_subtile(T, sub_t))
-    out_shape = (
-        jax.ShapeDtypeStruct((Bz, H, L, P), u.dtype),
-        jax.ShapeDtypeStruct((Bz, H, nL, P, N), jnp.float32),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, T), lambda b, h, l: (b, l)),            # pos
-            pl.BlockSpec((1, 1, T, P), lambda b, h, l: (b, h, l, 0)),  # u
-            pl.BlockSpec((1, 1, T), lambda b, h, l: (b, h, l)),      # dt
-            pl.BlockSpec((1, 1), lambda b, h, l: (h, 0)),            # A
-            pl.BlockSpec((1, T, N), lambda b, h, l: (b, l, 0)),      # Bm
-            pl.BlockSpec((1, T, N), lambda b, h, l: (b, l, 0)),      # Cm
-            pl.BlockSpec((1, 1), lambda b, h, l: (h, 0)),            # Dp
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, T, P), lambda b, h, l: (b, h, l, 0)),  # y
-            pl.BlockSpec((1, 1, 1, P, N),
-                         lambda b, h, l: (b, h, l, 0, 0)),             # ckpt
-        ],
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=INTERPRET if interpret is None else interpret,
-    )(positions, u, delta, Ah, Bm, Cm, Dp)
+    jax.lax.fori_loop(0, T // tt, sub, ())
 
 
 def selective_scan_fwd_pallas(u, delta, At, Bm, Cm, Dp, positions,
                               block_d: int = DEF_BLOCK_D,
                               chunk: int = DEF_CHUNK_T,
                               schedule: str = "step",
-                              sub_t: Optional[int] = None,
-                              interpret: Optional[bool] = None):
+                              sub_t: Optional[int] = None):
     """Shapes (already padded by ops.py): u, delta (B, L, Dm); At (N, Dm);
-    Bm, Cm (B, L, N); Dp (1, Dm); positions (B, L) i32.
+    Bm, Cm (B, L, N); Dp (1, Dm); positions (B, L, 1) i32.
     ``schedule``: 'step' (per-step VPU walk) | 'blocked' (SSD-style subtile
     contraction; ``sub_t`` overrides the default subtile).
     Returns (y (B, L, Dm), ckpts (B, L/T, N, Dm))."""
@@ -398,7 +276,6 @@ def selective_scan_fwd_pallas(u, delta, At, Bm, Cm, Dp, positions,
     N = At.shape[0]
     T, bd = chunk, block_d
     nL, nD = L // T, Dm // bd
-    grid = (Bz, nD, nL)
     if schedule == "blocked":
         kernel = functools.partial(_fwd_kernel_blocked,
                                    sub_t=_pick_subtile(T, sub_t))
@@ -406,15 +283,11 @@ def selective_scan_fwd_pallas(u, delta, At, Bm, Cm, Dp, positions,
         kernel = _fwd_kernel
     else:
         raise ValueError(f"unknown schedule {schedule!r}")
-    out_shape = (
-        jax.ShapeDtypeStruct((Bz, L, Dm), u.dtype),
-        jax.ShapeDtypeStruct((Bz, nL, N, Dm), jnp.float32),
-    )
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
-        grid=grid,
+        grid=(Bz, nD, nL),
         in_specs=[
-            pl.BlockSpec((1, T), lambda b, d, l: (b, l)),          # pos
+            pl.BlockSpec((1, T, 1), lambda b, d, l: (b, l, 0)),    # pos
             pl.BlockSpec((1, T, bd), lambda b, d, l: (b, l, d)),   # u
             pl.BlockSpec((1, T, bd), lambda b, d, l: (b, l, d)),   # dt
             pl.BlockSpec((N, bd), lambda b, d, l: (0, d)),         # At
@@ -426,16 +299,15 @@ def selective_scan_fwd_pallas(u, delta, At, Bm, Cm, Dp, positions,
             pl.BlockSpec((1, T, bd), lambda b, d, l: (b, l, d)),       # y
             pl.BlockSpec((1, 1, N, bd), lambda b, d, l: (b, l, 0, d)),  # ckpt
         ],
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((N, bd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=INTERPRET if interpret is None else interpret,
+        out_shape=(jax.ShapeDtypeStruct((Bz, L, Dm), u.dtype),
+                   jax.ShapeDtypeStruct((Bz, nL, N, Dm), f32)),
+        scratch_shapes=[pltpu.VMEM((N, bd), f32)],
+        compiler_params=_PARAMS,
     )(positions, u, delta, At, Bm, Cm, Dp)
 
 
 # ---------------------------------------------------------------------------
-# backward kernel — reverse L-grid walk, per-chunk recompute
+# Mamba-1 backward kernels — reverse L-grid walk, per-chunk recompute
 # ---------------------------------------------------------------------------
 
 def _bwd_kernel(pos_ref, u_ref, dt_ref, At_ref, Bm_ref, Cm_ref, Dp_ref,
@@ -455,7 +327,8 @@ def _bwd_kernel(pos_ref, u_ref, dt_ref, At_ref, Bm_ref, Cm_ref, Dp_ref,
       dC_t = Σ_d dy_t ⊙ h_t ;  du += D·dy ;  dD += Σ_t dy·u
     """
     T = u_ref.shape[1]
-    N = At_ref.shape[0]
+    N, bd = At_ref.shape
+    G = _pick_subtile(T)
 
     @pl.when(pl.program_id(2) == 0)          # first step of the REVERSE walk
     def _init():
@@ -463,64 +336,74 @@ def _bwd_kernel(pos_ref, u_ref, dt_ref, At_ref, Bm_ref, Cm_ref, Dp_ref,
         dA_acc[...] = jnp.zeros_like(dA_acc)
         dD_acc[...] = jnp.zeros_like(dD_acc)
 
-    At = At_ref[...].astype(jnp.float32)
-    Dp = Dp_ref[0, :].astype(jnp.float32)
+    At = At_ref[...].astype(f32)
+    Dp = Dp_ref[...].astype(f32)
+    row = _rowsel(G, bd)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (N, G), 1)
 
     # ---- recompute h trajectory within the chunk from the checkpoint ----
     hbuf_ref[0] = ckpt_ref[0, 0]
 
-    def fstep(t, _):
-        dt = dt_ref[0, t, :].astype(jnp.float32)
-        u_t = u_ref[0, t, :].astype(jnp.float32)
-        a = jnp.exp(dt[None, :] * At)
-        a = jnp.where(pos_ref[0, t] == 0, 0.0, a)
-        b = Bm_ref[0, t, :].astype(jnp.float32)[:, None] * (dt * u_t)[None, :]
-        hbuf_ref[t + 1] = a * hbuf_ref[t] + b
+    def fgroup(gi, _):
+        t0 = _at(gi, G)
+        dt, u, Bv, _, keep = _rows(pos_ref, u_ref, dt_ref, Bm_ref, Cm_ref,
+                                   t0, G)
+        BT, du = Bv.T, dt * u
+        kf = jnp.where(keep, jnp.ones_like(dt), jnp.zeros_like(dt))
+        h = hbuf_ref[t0]
+        for i in range(G):
+            a = jnp.exp(dt[i:i + 1] * At) * kf[i:i + 1]
+            h = a * h + BT[:, i:i + 1] * du[i:i + 1]
+            hbuf_ref[t0 + i + 1] = h
         return ()
 
-    jax.lax.fori_loop(0, T, fstep, ())
+    jax.lax.fori_loop(0, T // G, fgroup, ())
 
     # ---- reverse adjoint walk ----
-    def rstep(i, _):
-        t = T - 1 - i
-        dt = dt_ref[0, t, :].astype(jnp.float32)              # (bd,)
-        u_t = u_ref[0, t, :].astype(jnp.float32)
-        dy = dy_ref[0, t, :].astype(jnp.float32)
-        Bv = Bm_ref[0, t, :].astype(jnp.float32)              # (N,)
-        Cv = Cm_ref[0, t, :].astype(jnp.float32)
-        a = jnp.exp(dt[None, :] * At)
-        a = jnp.where(pos_ref[0, t] == 0, 0.0, a)
-        h_t = hbuf_ref[t + 1]
-        h_prev = hbuf_ref[t]
-        g = Cv[:, None] * dy[None, :] + g_ref[...]            # dL/dh_t
-        # parameter/input adjoints
-        da = g * h_prev
-        ddt_a = jnp.sum(da * a * At, axis=0)                  # (bd,)
-        gB = jnp.sum(g * Bv[:, None], axis=0)                 # (bd,)
-        du = dt * gB + Dp * dy
-        ddt_b = u_t * gB
-        dB_t = jnp.sum(g * (dt * u_t)[None, :], axis=1)       # (N,)
-        dC_t = jnp.sum(h_t * dy[None, :], axis=1)             # (N,)
-        du_ref[0, t, :] = du.astype(du_ref.dtype)
-        ddt_ref[0, t, :] = (ddt_a + ddt_b).astype(ddt_ref.dtype)
-        dB_ref[0, 0, t, :] = dB_t.astype(dB_ref.dtype)
-        dC_ref[0, 0, t, :] = dC_t.astype(dC_ref.dtype)
-        dA_acc[...] += da * a * dt[None, :]
-        dD_acc[0, :] += dy * u_t
-        g_ref[...] = a * g                                    # carry to t-1
+    def rgroup(k, _):
+        t0 = _at(T // G - 1 - k, G)
+        dt, u, Bv, Cv, keep = _rows(pos_ref, u_ref, dt_ref, Bm_ref, Cm_ref,
+                                    t0, G)
+        dy = dy_ref[0, pl.ds(t0, G), :].astype(f32)
+        BT, CT, du = Bv.T, Cv.T, dt * u
+        kf = jnp.where(keep, jnp.ones_like(dt), jnp.zeros_like(dt))
+        g = g_ref[...]
+        du_out = Dp * dy
+        ddt_out = jnp.zeros_like(dy)
+        dBT = jnp.zeros((N, G), f32)
+        dCT = jnp.zeros((N, G), f32)
+        dA = jnp.zeros((N, bd), f32)
+        for i in reversed(range(G)):
+            a = jnp.exp(dt[i:i + 1] * At) * kf[i:i + 1]
+            h_t = hbuf_ref[t0 + i + 1]
+            g = CT[:, i:i + 1] * dy[i:i + 1] + g             # dL/dh_t
+            da = g * hbuf_ref[t0 + i] * a
+            gB = jnp.sum(g * BT[:, i:i + 1], axis=0, keepdims=True)
+            ddt_i = jnp.sum(da * At, axis=0, keepdims=True) + u[i:i + 1] * gB
+            du_out = du_out + jnp.where(row == i, dt[i:i + 1] * gB, 0.0)
+            ddt_out = ddt_out + jnp.where(row == i, ddt_i, 0.0)
+            dBT = dBT + jnp.where(lane == i, jnp.sum(
+                g * du[i:i + 1], axis=1, keepdims=True), 0.0)
+            dCT = dCT + jnp.where(lane == i, jnp.sum(
+                h_t * dy[i:i + 1], axis=1, keepdims=True), 0.0)
+            dA = dA + da * dt[i:i + 1]
+            g = a * g                                         # carry to t-1
+        g_ref[...] = g
+        du_ref[0, pl.ds(t0, G), :] = du_out.astype(du_ref.dtype)
+        ddt_ref[0, pl.ds(t0, G), :] = ddt_out.astype(ddt_ref.dtype)
+        dB_ref[0, 0, pl.ds(t0, G), :] = dBT.T.astype(dB_ref.dtype)
+        dC_ref[0, 0, pl.ds(t0, G), :] = dCT.T.astype(dC_ref.dtype)
+        dA_acc[...] += dA
+        dD_acc[...] += jnp.sum(dy * u, axis=0, keepdims=True)
         return ()
 
-    jax.lax.fori_loop(0, T, rstep, ())
+    jax.lax.fori_loop(0, T // G, rgroup, ())
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _flush():
         dA_ref[0] = dA_acc[...]
-        dD_ref[0, 0] = dD_acc[0, :]
+        dD_ref[0] = dD_acc[...]
 
-
-# ---------------------------------------------------------------------------
-# backward kernel — blocked (SSD-style) schedule
-# ---------------------------------------------------------------------------
 
 def _bwd_kernel_blocked(pos_ref, u_ref, dt_ref, At_ref, Bm_ref, Cm_ref,
                         Dp_ref, ckpt_ref, dy_ref,
@@ -536,12 +419,14 @@ def _bwd_kernel_blocked(pos_ref, u_ref, dt_ref, At_ref, Bm_ref, Cm_ref,
         contraction of the same masked decay matrix, with the VMEM carry
         G = a_first·g_first handed to the previous subtile/chunk.
 
-    The per-position parameter/input adjoints are then pure elementwise
-    (T, N, bd) tensor work — no sequential walk at all.
-    Extra VMEM vs step bwd: gbuf (T, N, bd) ≈ 2 MiB at T=256, bd=128.
+    The per-position parameter/input adjoints are then elementwise
+    (T, bd) work per state row n — no sequential walk at all.
+    Scratch: hbuf, gbuf (N, T, bd) — the post-step states and adjoints.
     """
     T = u_ref.shape[1]
-    nsub = T // sub_t
+    N, bd = At_ref.shape
+    tt = sub_t
+    nsub = T // tt
 
     @pl.when(pl.program_id(2) == 0)          # first step of the REVERSE walk
     def _init():
@@ -549,297 +434,110 @@ def _bwd_kernel_blocked(pos_ref, u_ref, dt_ref, At_ref, Bm_ref, Cm_ref,
         dA_acc[...] = jnp.zeros_like(dA_acc)
         dD_acc[...] = jnp.zeros_like(dD_acc)
 
-    At = At_ref[...].astype(jnp.float32)
-    Dp = Dp_ref[0, :].astype(jnp.float32)
-    ii = jax.lax.broadcasted_iota(jnp.int32, (sub_t, sub_t), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (sub_t, sub_t), 1)
-    causal = ii >= jj
-
-    def _tile(si):
-        """Masked decay matrix + shared per-subtile tensors."""
-        t0 = si * sub_t
-        dt = dt_ref[0, pl.ds(t0, sub_t), :].astype(jnp.float32)
-        u_t = u_ref[0, pl.ds(t0, sub_t), :].astype(jnp.float32)
-        r = pos_ref[0, pl.ds(t0, sub_t)] == 0
-        la = dt[:, None, :] * At[None]                  # (Tt, N, bd)
-        s = jnp.cumsum(la, axis=0)
-        rid = jnp.cumsum(r.astype(jnp.int32))
-        m = (rid[:, None] == rid[None, :]) & causal
-        mm = m[..., None, None]
-        diff = s[:, None] - s[None, :]
-        dec = jnp.where(mm, jnp.exp(jnp.where(mm, diff, 0.0)), 0.0)
-        return t0, dt, u_t, r, la, s, rid, dec
+    At = At_ref[...].astype(f32)
+    Dp = Dp_ref[...].astype(f32)
+    h_entry = ckpt_ref[0, 0]                        # (N, bd)
 
     # ---- recompute h within the chunk, blocked per subtile ----
-    hbuf_ref[0] = ckpt_ref[0, 0]
-
     def fsub(si, _):
-        t0, dt, u_t, r, la, s, rid, dec = _tile(si)
-        Bv = Bm_ref[0, pl.ds(t0, sub_t), :].astype(jnp.float32)
-        bt = Bv[..., None] * (dt * u_t)[:, None, :]
-        h = jnp.sum(dec * bt[None], axis=1)
-        cin = jnp.where((rid == 0)[:, None, None], jnp.exp(s), 0.0)
-        h = h + cin * hbuf_ref[t0][None]
-        hbuf_ref[pl.ds(t0 + 1, sub_t)] = h
+        t0 = _at(si, tt)
+        dt, u, Bv, _, _, cdt, rid, same = _blocked_tile(
+            pos_ref, u_ref, dt_ref, Bm_ref, Cm_ref, t0, tt)
+        du = dt * u
+        carry_in = rid == 0
+        for n in range(N):
+            s = cdt * At[n:n + 1]
+            h0 = jnp.where(si == 0, h_entry[n:n + 1],
+                           hbuf_ref[n, pl.ds(jnp.maximum(t0 - 1, 0), 1), :])
+            h = jnp.sum(_pair_decay(s, same) * (Bv[:, n:n + 1] * du)[None],
+                        axis=1)
+            h = h + jnp.where(carry_in, jnp.exp(s), 0.0) * h0
+            hbuf_ref[n, pl.ds(t0, tt), :] = h
         return ()
 
     jax.lax.fori_loop(0, nsub, fsub, ())
 
     # ---- reverse adjoint walk, blocked per subtile ----
-    def rsub(si, _):
-        t0, dt, u_t, r, la, s, rid, dec = _tile(nsub - 1 - si)
-        Cv = Cm_ref[0, pl.ds(t0, sub_t), :].astype(jnp.float32)
-        dy = dy_ref[0, pl.ds(t0, sub_t), :].astype(jnp.float32)
-        c = Cv[..., None] * dy[:, None, :]              # (Tt, N, bd)
-        g = jnp.sum(dec * c[:, None], axis=0)           # Σ_{i≥j} decᵀ·c
-        g = g + dec[-1] * g_ref[...][None]              # carry through M[last,j]
-        gbuf_ref[pl.ds(t0, sub_t)] = g
-        a0 = jnp.where(r[0], 0.0, jnp.exp(la[0]))
-        g_ref[...] = a0 * g[0]                          # hand to t0 − 1
+    def rsub(k, _):
+        t0 = _at(nsub - 1 - k, tt)
+        dt, _, _, Cv, keep, cdt, rid, same = _blocked_tile(
+            pos_ref, u_ref, dt_ref, Bm_ref, Cm_ref, t0, tt)
+        dy = dy_ref[0, pl.ds(t0, tt), :].astype(f32)
+        to_last = rid == rid[tt - 1:tt]           # no reset in (j, last]
+        G = g_ref[...]
+        for n in range(N):
+            s = cdt * At[n:n + 1]
+            c = Cv[:, n:n + 1] * dy                               # (tt, bd)
+            g = jnp.sum(_pair_decay(s, same) * c[:, None, :], axis=0)
+            g = g + jnp.where(to_last, jnp.exp(s[tt - 1:tt] - s), 0.0) * \
+                G[n:n + 1]                                # carry M[last,j]
+            gbuf_ref[n, pl.ds(t0, tt), :] = g
+            a0 = jnp.where(keep[0:1], jnp.exp(dt[0:1] * At[n:n + 1]), 0.0)
+            g_ref[n:n + 1, :] = a0 * g[0:1]               # hand to t0 − 1
         return ()
 
     jax.lax.fori_loop(0, nsub, rsub, ())
 
     # ---- elementwise adjoints, vectorized over the whole chunk ----
-    dt = dt_ref[0].astype(jnp.float32)                  # (T, bd)
-    u_t = u_ref[0].astype(jnp.float32)
-    dy = dy_ref[0].astype(jnp.float32)
-    Bv = Bm_ref[0].astype(jnp.float32)                  # (T, N)
-    a = jnp.exp(dt[:, None, :] * At[None])              # (T, N, bd)
-    a = jnp.where((pos_ref[0] == 0)[:, None, None], 0.0, a)
-    hb = hbuf_ref[...]
-    h_prev, h_t = hb[:-1], hb[1:]
-    g = gbuf_ref[...]
-    da = g * h_prev
-    gB = jnp.sum(g * Bv[..., None], axis=1)             # (T, bd)
-    du_ref[0] = (dt * gB + Dp[None] * dy).astype(du_ref.dtype)
-    ddt_ref[0] = (jnp.sum(da * a * At[None], axis=1) +
-                  u_t * gB).astype(ddt_ref.dtype)
-    dB_ref[0, 0] = jnp.sum(g * (dt * u_t)[:, None, :],
-                           axis=2).astype(dB_ref.dtype)
-    dC_ref[0, 0] = jnp.sum(h_t * dy[:, None, :], axis=2).astype(dC_ref.dtype)
-    dA_acc[...] += jnp.sum(da * a * dt[:, None, :], axis=0)
-    dD_acc[0, :] += jnp.sum(dy * u_t, axis=0)
+    dt = dt_ref[0].astype(f32)                      # (T, bd)
+    u = u_ref[0].astype(f32)
+    dy = dy_ref[0].astype(f32)
+    Bv = Bm_ref[0].astype(f32)                      # (T, N)
+    keep = pos_ref[0] != 0                          # (T, 1)
+    du = dt * u
+    first = _rowsel(T, bd) == 0
+    gB = jnp.zeros_like(dt)
+    ddt = jnp.zeros_like(dt)
+    for n in range(N):
+        a = jnp.where(keep, jnp.exp(dt * At[n:n + 1]), 0.0)
+        h = hbuf_ref[n]                                  # h_t
+        h_prev = jnp.where(first, h_entry[n:n + 1], pltpu.roll(h, 1, 0))
+        g = gbuf_ref[n]
+        da = g * h_prev * a
+        ddt = ddt + da * At[n:n + 1]
+        gB = gB + g * Bv[:, n:n + 1]
+        dB_ref[0, 0, :, n:n + 1] = jnp.sum(
+            g * du, axis=1, keepdims=True).astype(dB_ref.dtype)
+        dC_ref[0, 0, :, n:n + 1] = jnp.sum(
+            h * dy, axis=1, keepdims=True).astype(dC_ref.dtype)
+        dA_acc[n:n + 1, :] += jnp.sum(da * dt, axis=0, keepdims=True)
+    du_ref[0] = (dt * gB + Dp * dy).astype(du_ref.dtype)
+    ddt_ref[0] = (ddt + u * gB).astype(ddt_ref.dtype)
+    dD_acc[...] += jnp.sum(dy * u, axis=0, keepdims=True)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _flush():
         dA_ref[0] = dA_acc[...]
-        dD_ref[0, 0] = dD_acc[0, :]
-
-
-# ---------------------------------------------------------------------------
-# backward kernel — blocked_heads schedule
-# ---------------------------------------------------------------------------
-
-def _bwd_kernel_blocked_heads(pos_ref, u_ref, dt_ref, A_ref, Bm_ref, Cm_ref,
-                              Dp_ref, ckpt_ref, dy_ref,
-                              du_ref, ddt_ref, dB_ref, dC_ref, dA_ref,
-                              dD_ref,
-                              hbuf_ref, gbuf_ref, g_ref, dA_acc, dD_acc, *,
-                              sub_t):
-    """Adjoint of one (b, head, l-chunk), scalar per-head decay. Mirrors
-    ``_bwd_kernel_blocked``: h recomputed per subtile from the chunk-entry
-    checkpoint via the forward matmul, the adjoint scan
-
-        g_t = C_t ⊗ dy_t + a_{t+1}·g_{t+1}
-
-    evaluated per subtile as the TRANSPOSE contraction decᵀ @ (C⊗dy) (one
-    (Tt, Tt) @ (Tt, P·N) matmul) with the VMEM carry G = a_first·g_first,
-    then all per-position parameter/input adjoints as elementwise chunk-wide
-    tensor work. Per-head dA/dD reduce into (1, 1) scalar accumulators
-    flushed on the last reverse grid step.
-    """
-    T = u_ref.shape[2]
-    P = u_ref.shape[3]
-    N = Bm_ref.shape[2]
-    nsub = T // sub_t
-
-    @pl.when(pl.program_id(2) == 0)          # first step of the REVERSE walk
-    def _init():
-        g_ref[...] = jnp.zeros_like(g_ref)
-        dA_acc[...] = jnp.zeros_like(dA_acc)
-        dD_acc[...] = jnp.zeros_like(dD_acc)
-
-    A = A_ref[0, 0]
-    Dp = Dp_ref[0, 0]
-    ii = jax.lax.broadcasted_iota(jnp.int32, (sub_t, sub_t), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (sub_t, sub_t), 1)
-    causal = ii >= jj
-
-    def _tile(si):
-        """Masked (Tt, Tt) decay matrix + shared per-subtile tensors."""
-        t0 = si * sub_t
-        dt = dt_ref[0, 0, pl.ds(t0, sub_t)].astype(jnp.float32)   # (Tt,)
-        u_t = u_ref[0, 0, pl.ds(t0, sub_t), :].astype(jnp.float32)
-        r = pos_ref[0, pl.ds(t0, sub_t)] == 0
-        la = dt * A
-        s = jnp.cumsum(la)
-        rid = jnp.cumsum(r.astype(jnp.int32))
-        m = (rid[:, None] == rid[None, :]) & causal
-        diff = s[:, None] - s[None, :]
-        dec = jnp.where(m, jnp.exp(jnp.where(m, diff, 0.0)), 0.0)
-        return t0, dt, u_t, r, la, s, rid, dec
-
-    # ---- recompute h within the chunk, one matmul per subtile ----
-    hbuf_ref[0] = ckpt_ref[0, 0, 0]
-
-    def fsub(si, _):
-        t0, dt, u_t, r, la, s, rid, dec = _tile(si)
-        Bv = Bm_ref[0, pl.ds(t0, sub_t), :].astype(jnp.float32)
-        bt = Bv[:, None, :] * (dt[:, None] * u_t)[:, :, None]     # (Tt,P,N)
-        h = jnp.dot(dec, bt.reshape(sub_t, P * N),
-                    preferred_element_type=jnp.float32).reshape(sub_t, P, N)
-        cin = jnp.where(rid == 0, jnp.exp(s), 0.0)
-        h = h + cin[:, None, None] * hbuf_ref[t0][None]
-        hbuf_ref[pl.ds(t0 + 1, sub_t)] = h
-        return ()
-
-    jax.lax.fori_loop(0, nsub, fsub, ())
-
-    # ---- reverse adjoint walk, transpose contraction per subtile ----
-    def rsub(si, _):
-        t0, dt, u_t, r, la, s, rid, dec = _tile(nsub - 1 - si)
-        Cv = Cm_ref[0, pl.ds(t0, sub_t), :].astype(jnp.float32)
-        dy = dy_ref[0, 0, pl.ds(t0, sub_t), :].astype(jnp.float32)
-        c = dy[:, :, None] * Cv[:, None, :]                       # (Tt,P,N)
-        g = jnp.dot(dec.T, c.reshape(sub_t, P * N),
-                    preferred_element_type=jnp.float32).reshape(sub_t, P, N)
-        g = g + dec[-1][:, None, None] * g_ref[...][None]   # carry M[last,j]
-        gbuf_ref[pl.ds(t0, sub_t)] = g
-        a0 = jnp.where(r[0], 0.0, jnp.exp(la[0]))
-        g_ref[...] = a0 * g[0]                              # hand to t0 − 1
-        return ()
-
-    jax.lax.fori_loop(0, nsub, rsub, ())
-
-    # ---- elementwise adjoints, vectorized over the whole chunk ----
-    dt = dt_ref[0, 0].astype(jnp.float32)                   # (T,)
-    u_t = u_ref[0, 0].astype(jnp.float32)                   # (T, P)
-    dy = dy_ref[0, 0].astype(jnp.float32)
-    Bv = Bm_ref[0].astype(jnp.float32)                      # (T, N)
-    a = jnp.exp(dt * A)                                     # (T,)
-    a = jnp.where(pos_ref[0] == 0, 0.0, a)
-    hb = hbuf_ref[...]
-    h_prev, h_t = hb[:-1], hb[1:]                           # (T, P, N)
-    g = gbuf_ref[...]
-    da = jnp.sum(g * h_prev, axis=(1, 2))                   # (T,) scalar/step
-    gB = jnp.sum(g * Bv[:, None, :], axis=2)                # (T, P)
-    du_ref[0, 0] = (dt[:, None] * gB + Dp * dy).astype(du_ref.dtype)
-    ddt_ref[0, 0] = (da * a * A +
-                     jnp.sum(u_t * gB, axis=1)).astype(ddt_ref.dtype)
-    dB_ref[0, 0] = jnp.sum(g * (dt[:, None] * u_t)[:, :, None],
-                           axis=1).astype(dB_ref.dtype)
-    dC_ref[0, 0] = jnp.sum(h_t * dy[:, :, None], axis=1).astype(dC_ref.dtype)
-    dA_acc[0, 0] += jnp.sum(da * a * dt)
-    dD_acc[0, 0] += jnp.sum(dy * u_t)
-
-    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
-    def _flush():
-        dA_ref[0, 0] = dA_acc[0]
-        dD_ref[0, 0] = dD_acc[0]
-
-
-def selective_scan_heads_bwd_pallas(u, delta, Ah, Bm, Cm, Dp, positions,
-                                    ckpts, dy,
-                                    chunk: int = DEF_CHUNK_T,
-                                    sub_t: Optional[int] = None,
-                                    interpret: Optional[bool] = None):
-    """Head-major shapes as in the forward. Returns (du (B,H,L,P),
-    ddelta (B,H,L), dB_partial (B,H,L,N), dC_partial (B,H,L,N),
-    dA_partial (B,H,1), dD_partial (B,H,1)).
-
-    Serves BOTH forward schedules: the adjoint math is schedule-independent
-    and the dual forward writes the same chunk-entry ckpts."""
-    Bz, H, L, P = u.shape
-    N = Bm.shape[-1]
-    T = chunk
-    nL = L // T
-    grid = (Bz, H, nL)
-    rev = lambda l: nL - 1 - l                 # walk the L dimension backwards
-    f32 = jnp.float32
-    kernel = functools.partial(_bwd_kernel_blocked_heads,
-                               sub_t=_pick_subtile(T, sub_t))
-    scratch = [
-        pltpu.VMEM((T + 1, P, N), f32),        # recomputed h trajectory
-        pltpu.VMEM((T, P, N), f32),            # adjoint trajectory g
-        pltpu.VMEM((P, N), f32),               # adjoint carry G
-        pltpu.VMEM((1, 1), f32),               # per-head dA accumulator
-        pltpu.VMEM((1, 1), f32),               # per-head dD accumulator
-    ]
-    out_shape = (
-        jax.ShapeDtypeStruct((Bz, H, L, P), f32),     # du
-        jax.ShapeDtypeStruct((Bz, H, L), f32),        # ddelta
-        jax.ShapeDtypeStruct((Bz, H, L, N), f32),     # dB partials
-        jax.ShapeDtypeStruct((Bz, H, L, N), f32),     # dC partials
-        jax.ShapeDtypeStruct((Bz, H, 1), f32),        # dA partials
-        jax.ShapeDtypeStruct((Bz, H, 1), f32),        # dD partials
-    )
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, T), lambda b, h, l: (b, rev(l))),       # pos
-            pl.BlockSpec((1, 1, T, P), lambda b, h, l: (b, h, rev(l), 0)),
-            pl.BlockSpec((1, 1, T), lambda b, h, l: (b, h, rev(l))),  # dt
-            pl.BlockSpec((1, 1), lambda b, h, l: (h, 0)),            # A
-            pl.BlockSpec((1, T, N), lambda b, h, l: (b, rev(l), 0)),  # Bm
-            pl.BlockSpec((1, T, N), lambda b, h, l: (b, rev(l), 0)),  # Cm
-            pl.BlockSpec((1, 1), lambda b, h, l: (h, 0)),            # Dp
-            pl.BlockSpec((1, 1, 1, P, N),
-                         lambda b, h, l: (b, h, rev(l), 0, 0)),      # ckpt
-            pl.BlockSpec((1, 1, T, P), lambda b, h, l: (b, h, rev(l), 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, T, P), lambda b, h, l: (b, h, rev(l), 0)),
-            pl.BlockSpec((1, 1, T), lambda b, h, l: (b, h, rev(l))),
-            pl.BlockSpec((1, 1, T, N), lambda b, h, l: (b, h, rev(l), 0)),
-            pl.BlockSpec((1, 1, T, N), lambda b, h, l: (b, h, rev(l), 0)),
-            pl.BlockSpec((1, 1, 1), lambda b, h, l: (b, h, 0)),
-            pl.BlockSpec((1, 1, 1), lambda b, h, l: (b, h, 0)),
-        ],
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=INTERPRET if interpret is None else interpret,
-    )(positions, u, delta, Ah, Bm, Cm, Dp, ckpts, dy)
+        dD_ref[0] = dD_acc[...]
 
 
 def selective_scan_bwd_pallas(u, delta, At, Bm, Cm, Dp, positions, ckpts, dy,
                               block_d: int = DEF_BLOCK_D,
                               chunk: int = DEF_CHUNK_T,
                               schedule: str = "step",
-                              sub_t: Optional[int] = None,
-                              interpret: Optional[bool] = None):
+                              sub_t: Optional[int] = None):
     """Returns (du, ddelta, dB_partial (B,nD,L,N), dC_partial (B,nD,L,N),
     dA_partial (B,N,Dm), dD_partial (B,1,Dm))."""
     Bz, L, Dm = u.shape
     N = At.shape[0]
     T, bd = chunk, block_d
     nL, nD = L // T, Dm // bd
-    grid = (Bz, nD, nL)
     rev = lambda l: nL - 1 - l                 # walk the L dimension backwards
-    f32 = jnp.float32
     if schedule == "blocked":
         kernel = functools.partial(_bwd_kernel_blocked,
                                    sub_t=_pick_subtile(T, sub_t))
-        scratch = [
-            pltpu.VMEM((T + 1, N, bd), f32),   # recomputed h trajectory
-            pltpu.VMEM((T, N, bd), f32),       # adjoint trajectory g
-            pltpu.VMEM((N, bd), f32),          # adjoint carry G
-            pltpu.VMEM((N, bd), f32),          # dA accumulator
-            pltpu.VMEM((1, bd), f32),          # dD accumulator
-        ]
+        traj = [pltpu.VMEM((N, T, bd), f32),   # recomputed h trajectory
+                pltpu.VMEM((N, T, bd), f32)]   # adjoint trajectory g
     elif schedule == "step":
         kernel = _bwd_kernel
-        scratch = [
-            pltpu.VMEM((T + 1, N, bd), f32),   # recomputed h trajectory
-            pltpu.VMEM((N, bd), f32),          # adjoint carry g
-            pltpu.VMEM((N, bd), f32),          # dA accumulator
-            pltpu.VMEM((1, bd), f32),          # dD accumulator
-        ]
+        traj = [pltpu.VMEM((T + 1, N, bd), f32)]   # recomputed h trajectory
     else:
         raise ValueError(f"unknown schedule {schedule!r}")
+    scratch = traj + [
+        pltpu.VMEM((N, bd), f32),              # adjoint carry
+        pltpu.VMEM((N, bd), f32),              # dA accumulator
+        pltpu.VMEM((1, bd), f32),              # dD accumulator
+    ]
     out_shape = (
         jax.ShapeDtypeStruct((Bz, L, Dm), f32),       # du
         jax.ShapeDtypeStruct((Bz, L, Dm), f32),       # ddelta
@@ -848,11 +546,11 @@ def selective_scan_bwd_pallas(u, delta, At, Bm, Cm, Dp, positions, ckpts, dy,
         jax.ShapeDtypeStruct((Bz, N, Dm), f32),       # dA partials
         jax.ShapeDtypeStruct((Bz, 1, Dm), f32),       # dD partials
     )
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
-        grid=grid,
+        grid=(Bz, nD, nL),
         in_specs=[
-            pl.BlockSpec((1, T), lambda b, d, l: (b, rev(l))),
+            pl.BlockSpec((1, T, 1), lambda b, d, l: (b, rev(l), 0)),    # pos
             pl.BlockSpec((1, T, bd), lambda b, d, l: (b, rev(l), d)),   # u
             pl.BlockSpec((1, T, bd), lambda b, d, l: (b, rev(l), d)),   # dt
             pl.BlockSpec((N, bd), lambda b, d, l: (0, d)),              # At
@@ -872,7 +570,314 @@ def selective_scan_bwd_pallas(u, delta, At, Bm, Cm, Dp, positions, ckpts, dy,
         ],
         out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=INTERPRET if interpret is None else interpret,
+        compiler_params=_PARAMS,
     )(positions, u, delta, At, Bm, Cm, Dp, ckpts, dy)
+
+
+# ---------------------------------------------------------------------------
+# head-structured (scalar per-head decay) kernels
+# ---------------------------------------------------------------------------
+
+def _heads_tile(pos_ref, u_ref, dt_ref, Bm_ref, Cm_ref, A, t0, tt):
+    """Per-subtile tensors of the heads schedules, all 2-D:
+    dt (tt,1) | u (tt,P) | B, C (tt,N) | x = Δ·u (tt,P) |
+    Lm (tt,tt) masked decay  Lm[i,j] = exp(Σ_{(j,i]} Δ·A)·[j ≤ i]·[no reset
+    in (j,i]] | c (tt,1) decay from the subtile-entry state to row i (0
+    after a reset) | wl (tt,1) = Lm[last, :] as a column."""
+    sl = pl.ds(t0, tt)
+    dt = dt_ref[0, 0, sl, :].astype(f32)
+    u = u_ref[0, 0, sl, :].astype(f32)
+    Bv = Bm_ref[0, sl, :].astype(f32)
+    Cv = Cm_ref[0, sl, :].astype(f32)
+    r = (pos_ref[0, sl, :] == 0).astype(f32)
+    la = dt * A                                     # (tt, 1) log-decay
+    incl, strict = _tri(tt), _tri(tt, strict=True)
+    causal = incl > 0
+    seg = _dot(incl, la * strict)                   # Σ_{k∈(j,i]} la_k
+    cuts = _dot(incl, r * strict)                   # resets in (j, i]
+    ok = causal & (cuts == 0)
+    Lm = jnp.where(ok, jnp.exp(jnp.where(ok, seg, 0.0)), 0.0)
+    s = _dot(incl, la)                              # Σ_{k≤i} la_k
+    rid = _dot(incl, r)
+    c = jnp.where(rid == 0, jnp.exp(s), 0.0)
+    wl = jnp.where(rid == rid[tt - 1:tt], jnp.exp(s[tt - 1:tt] - s), 0.0)
+    return dt, u, Bv, Cv, dt * u, Lm, c, wl
+
+
+def _fwd_kernel_blocked_heads(pos_ref, u_ref, dt_ref, A_ref, Bm_ref, Cm_ref,
+                              Dp_ref, y_ref, ckpt_ref, h_ref, *, sub_t):
+    """One (b, head, l-chunk) grid step, scalar per-head decay, state form.
+
+    pos (1,T,1) i32 | u (1,1,T,P) | dt (1,1,T,1) | A, Dp (1,1,1) scalars |
+    Bm, Cm (1,T,N) | y (1,1,T,P) | ckpt (1,1,1,P,N) | h scratch (P,N) f32.
+
+    Per subtile the in-chunk states are formed one state column n at a
+    time, each a single (Tt, Tt) @ (Tt, P) matmul:
+
+        h[:, :, n] = Lm @ (x ⊙ B[:, n]) + c ⊗ h_in[:, n]
+        y          = Σ_n C[:, n] ⊙ h[:, :, n] + D·u
+    """
+    T = u_ref.shape[2]
+    P, N = h_ref.shape
+    tt = sub_t
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        h_ref[...] = jnp.zeros_like(h_ref)
+
+    ckpt_ref[0, 0, 0] = h_ref[...]
+    A = A_ref[0].astype(f32)                           # (1, 1)
+    Dp = Dp_ref[0].astype(f32)
+    rowN = _rowsel(N, P)
+
+    def sub(si, _):
+        t0 = _at(si, tt)
+        _, u, Bv, Cv, x, Lm, c, _ = _heads_tile(
+            pos_ref, u_ref, dt_ref, Bm_ref, Cm_ref, A, t0, tt)
+        hT = h_ref[...].T                              # (N, P)
+        y = Dp * u
+        hT_new = jnp.zeros_like(hT)
+        for n in range(N):
+            h = _dot(Lm, x * Bv[:, n:n + 1]) + c * hT[n:n + 1]   # (tt, P)
+            y = y + Cv[:, n:n + 1] * h
+            hT_new = hT_new + jnp.where(rowN == n, h[tt - 1:tt], 0.0)
+        h_ref[...] = hT_new.T
+        y_ref[0, 0, pl.ds(t0, tt), :] = y.astype(y_ref.dtype)
+        return ()
+
+    jax.lax.fori_loop(0, T // tt, sub, ())
+
+
+def _fwd_kernel_blocked_heads_dual(pos_ref, u_ref, dt_ref, A_ref, Bm_ref,
+                                   Cm_ref, Dp_ref, y_ref, ckpt_ref, h_ref, *,
+                                   sub_t):
+    """Dual-form twin of ``_fwd_kernel_blocked_heads`` (same grid, block
+    shapes, carry semantics, and ckpt output — so one backward kernel
+    serves both). Per subtile the masked decay folds into the (Tt, Tt)
+    C·Bᵀ Gram matrix and the outputs come straight from matmuls, without
+    forming the (Tt, P, N) in-chunk states:
+
+        y        = (Lm ⊙ C Bᵀ) @ x  +  c ⊙ (C @ h_inᵀ)  + D·u
+        h_new    = (wl ⊙ x)ᵀ @ B  +  c[last]·h_in
+
+    FLOPs Tt²·(N + P) + Tt·P·N vs the state form's Tt²·P·N — the measured
+    winner when dh ≫ Tt (see repro/tune; core/ssm.py has the XLA math).
+    """
+    T = u_ref.shape[2]
+    tt = sub_t
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        h_ref[...] = jnp.zeros_like(h_ref)
+
+    ckpt_ref[0, 0, 0] = h_ref[...]
+    A = A_ref[0].astype(f32)
+    Dp = Dp_ref[0].astype(f32)
+
+    def sub(si, _):
+        t0 = _at(si, tt)
+        _, u, Bv, Cv, x, Lm, c, wl = _heads_tile(
+            pos_ref, u_ref, dt_ref, Bm_ref, Cm_ref, A, t0, tt)
+        h_in = h_ref[...]                                         # (P, N)
+        y = _dot(Lm * _dot(Cv, Bv, _NT), x) + \
+            c * _dot(Cv, h_in, _NT) + Dp * u
+        h_ref[...] = _dot(wl * x, Bv, _TN) + _last(c) * h_in
+        y_ref[0, 0, pl.ds(t0, tt), :] = y.astype(y_ref.dtype)
+        return ()
+
+    jax.lax.fori_loop(0, T // tt, sub, ())
+
+
+_HEADS_FWD_KERNELS = {"blocked_heads": _fwd_kernel_blocked_heads,
+                      "blocked_heads_dual": _fwd_kernel_blocked_heads_dual}
+
+
+def selective_scan_heads_fwd_pallas(u, delta, Ah, Bm, Cm, Dp, positions,
+                                    chunk: int = DEF_CHUNK_T,
+                                    schedule: str = "blocked_heads",
+                                    sub_t: Optional[int] = None):
+    """Head-major shapes (already padded/transposed by ops.py):
+    u (B, H, L, P); delta (B, H, L, 1); Ah, Dp (H, 1, 1); Bm, Cm (B, L, N);
+    positions (B, L, 1) i32. ``schedule``: 'blocked_heads' (state form) |
+    'blocked_heads_dual' (C·Bᵀ attention-like form; same ckpt contract).
+    Returns (y (B, H, L, P), ckpts (B, H, L/T, P, N))."""
+    Bz, H, L, P = u.shape
+    N = Bm.shape[-1]
+    T = chunk
+    nL = L // T
+    if schedule not in _HEADS_FWD_KERNELS:
+        raise ValueError(f"unknown heads schedule {schedule!r}")
+    kernel = functools.partial(_HEADS_FWD_KERNELS[schedule],
+                               sub_t=_pick_subtile(T, sub_t))
+    return pallas_call(
+        kernel,
+        grid=(Bz, H, nL),
+        in_specs=[
+            pl.BlockSpec((1, T, 1), lambda b, h, l: (b, l, 0)),        # pos
+            pl.BlockSpec((1, 1, T, P), lambda b, h, l: (b, h, l, 0)),  # u
+            pl.BlockSpec((1, 1, T, 1), lambda b, h, l: (b, h, l, 0)),  # dt
+            pl.BlockSpec((1, 1, 1), lambda b, h, l: (h, 0, 0)),        # A
+            pl.BlockSpec((1, T, N), lambda b, h, l: (b, l, 0)),        # Bm
+            pl.BlockSpec((1, T, N), lambda b, h, l: (b, l, 0)),        # Cm
+            pl.BlockSpec((1, 1, 1), lambda b, h, l: (h, 0, 0)),        # Dp
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, T, P), lambda b, h, l: (b, h, l, 0)),  # y
+            pl.BlockSpec((1, 1, 1, P, N),
+                         lambda b, h, l: (b, h, l, 0, 0)),             # ckpt
+        ],
+        out_shape=(jax.ShapeDtypeStruct((Bz, H, L, P), u.dtype),
+                   jax.ShapeDtypeStruct((Bz, H, nL, P, N), f32)),
+        scratch_shapes=[pltpu.VMEM((P, N), f32)],
+        compiler_params=_PARAMS,
+    )(positions, u, delta, Ah, Bm, Cm, Dp)
+
+
+def _bwd_kernel_blocked_heads(pos_ref, u_ref, dt_ref, A_ref, Bm_ref, Cm_ref,
+                              Dp_ref, ckpt_ref, dy_ref,
+                              du_ref, ddt_ref, dB_ref, dC_ref, dA_ref,
+                              dD_ref, hs_ref, g_ref, dA_acc, dD_acc, *,
+                              sub_t):
+    """Adjoint of one (b, head, l-chunk), scalar per-head decay, in the
+    dual (matmul) form — no (T, P, N) trajectory is stored. The subtile
+    entry states are recomputed from the chunk checkpoint into hs
+    (T/Tt, P, N); then, walking the subtiles in reverse with the adjoint
+    carry G = dL/dh at the subtile's end, per subtile (M = Lm ⊙ C Bᵀ,
+    Q = dy xᵀ):
+
+        dx   = Mᵀ dy + wl ⊙ (B Gᵀ)            du = Δ·dx + D·dy
+        dB   = (Lm ⊙ Q)ᵀ C + wl ⊙ (x G)
+        dC   = (Lm ⊙ Q) B + c ⊙ (dy h_in)
+        dla_m = Σ_{k≥m>j} (M⊙Q)[k,j] + Σ_{j<m} wl_j x_j·(G B_j)
+               + Σ_{k≥m} c_k dy_k·(h_in C_k) + c_last (h_in : G)
+        dΔ   = dla·A + Σ_p u·dx ;   dA += Σ dla·Δ ;   dD += Σ dy·u
+        G   ← (c ⊙ dy)ᵀ C + c_last·G          (dL/dh at the subtile entry)
+    """
+    T = u_ref.shape[2]
+    tt = sub_t
+    nsub = T // tt
+
+    @pl.when(pl.program_id(2) == 0)          # first step of the REVERSE walk
+    def _init():
+        g_ref[...] = jnp.zeros_like(g_ref)
+        dA_acc[...] = jnp.zeros_like(dA_acc)
+        dD_acc[...] = jnp.zeros_like(dD_acc)
+
+    A = A_ref[0].astype(f32)
+    Dp = Dp_ref[0].astype(f32)
+    incl = _tri(tt)
+    strict = _tri(tt, strict=True)
+    upper = _tri(tt, upper=True)                       # [k ≥ m]
+    after = _tri(tt, strict=True, upper=True)          # [j < m]
+    ones = jnp.ones((tt, 1), f32)
+
+    # ---- recompute the subtile-entry states ----
+    hs_ref[0] = ckpt_ref[0, 0, 0]
+
+    def fsub(si, _):
+        _, _, Bv, _, x, _, c, wl = _heads_tile(
+            pos_ref, u_ref, dt_ref, Bm_ref, Cm_ref, A, _at(si, tt), tt)
+        hs_ref[si + 1] = _dot(wl * x, Bv, _TN) + _last(c) * hs_ref[si]
+        return ()
+
+    jax.lax.fori_loop(0, nsub - 1, fsub, ())
+
+    # ---- reverse walk over subtiles ----
+    def rsub(k, _):
+        si = nsub - 1 - k
+        t0 = _at(si, tt)
+        sl = pl.ds(t0, tt)
+        dt, u, Bv, Cv, x, Lm, c, wl = _heads_tile(
+            pos_ref, u_ref, dt_ref, Bm_ref, Cm_ref, A, t0, tt)
+        dy = dy_ref[0, 0, sl, :].astype(f32)                      # (tt, P)
+        h0 = hs_ref[si]                                           # (P, N)
+        G = g_ref[...]
+        M = Lm * _dot(Cv, Bv, _NT)
+        Q = _dot(dy, x, _NT)
+        LQ = Lm * Q
+        BG = _dot(Bv, G, _NT)                                     # (tt, P)
+        dx = _dot(M, dy, _TN) + wl * BG
+        dB = _dot(LQ, Cv, _TN) + wl * _dot(x, G)
+        dC = _dot(LQ, Bv) + c * _dot(dy, h0)
+        c_last = _last(c)
+        dla = (_dot(_dot(M * Q, after) * incl, ones, _TN) +
+               _dot(strict, wl * jnp.sum(x * BG, axis=1, keepdims=True)) +
+               _dot(upper, c * jnp.sum(dy * _dot(Cv, h0, _NT), axis=1,
+                                       keepdims=True)) +
+               c_last * jnp.sum(h0 * G, keepdims=True))
+        du_ref[0, 0, sl, :] = (dt * dx + Dp * dy).astype(du_ref.dtype)
+        ddt_ref[0, 0, sl, :] = (dla * A + jnp.sum(
+            u * dx, axis=1, keepdims=True)).astype(ddt_ref.dtype)
+        dB_ref[0, 0, sl, :] = dB.astype(dB_ref.dtype)
+        dC_ref[0, 0, sl, :] = dC.astype(dC_ref.dtype)
+        dA_acc[...] += jnp.sum(dla * dt, keepdims=True)
+        dD_acc[...] += jnp.sum(dy * u, keepdims=True)
+        g_ref[...] = _dot(c * dy, Cv, _TN) + c_last * G
+        return ()
+
+    jax.lax.fori_loop(0, nsub, rsub, ())
+
+    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+    def _flush():
+        dA_ref[0, 0] = dA_acc[...]
+        dD_ref[0, 0] = dD_acc[...]
+
+
+def selective_scan_heads_bwd_pallas(u, delta, Ah, Bm, Cm, Dp, positions,
+                                    ckpts, dy,
+                                    chunk: int = DEF_CHUNK_T,
+                                    sub_t: Optional[int] = None):
+    """Head-major shapes as in the forward. Returns (du (B,H,L,P),
+    ddelta (B,H,L,1), dB_partial (B,H,L,N), dC_partial (B,H,L,N),
+    dA_partial (B,H,1,1), dD_partial (B,H,1,1)).
+
+    Serves BOTH forward schedules: the adjoint math is schedule-independent
+    and the dual forward writes the same chunk-entry ckpts."""
+    Bz, H, L, P = u.shape
+    N = Bm.shape[-1]
+    T = chunk
+    nL = L // T
+    tt = _pick_subtile(T, sub_t)
+    rev = lambda l: nL - 1 - l                 # walk the L dimension backwards
+    kernel = functools.partial(_bwd_kernel_blocked_heads, sub_t=tt)
+    scratch = [
+        pltpu.VMEM((T // tt, P, N), f32),      # subtile-entry states
+        pltpu.VMEM((P, N), f32),               # adjoint carry G
+        pltpu.VMEM((1, 1), f32),               # per-head dA accumulator
+        pltpu.VMEM((1, 1), f32),               # per-head dD accumulator
+    ]
+    out_shape = (
+        jax.ShapeDtypeStruct((Bz, H, L, P), f32),     # du
+        jax.ShapeDtypeStruct((Bz, H, L, 1), f32),     # ddelta
+        jax.ShapeDtypeStruct((Bz, H, L, N), f32),     # dB partials
+        jax.ShapeDtypeStruct((Bz, H, L, N), f32),     # dC partials
+        jax.ShapeDtypeStruct((Bz, H, 1, 1), f32),     # dA partials
+        jax.ShapeDtypeStruct((Bz, H, 1, 1), f32),     # dD partials
+    )
+    return pallas_call(
+        kernel,
+        grid=(Bz, H, nL),
+        in_specs=[
+            pl.BlockSpec((1, T, 1), lambda b, h, l: (b, rev(l), 0)),   # pos
+            pl.BlockSpec((1, 1, T, P), lambda b, h, l: (b, h, rev(l), 0)),
+            pl.BlockSpec((1, 1, T, 1), lambda b, h, l: (b, h, rev(l), 0)),
+            pl.BlockSpec((1, 1, 1), lambda b, h, l: (h, 0, 0)),        # A
+            pl.BlockSpec((1, T, N), lambda b, h, l: (b, rev(l), 0)),   # Bm
+            pl.BlockSpec((1, T, N), lambda b, h, l: (b, rev(l), 0)),   # Cm
+            pl.BlockSpec((1, 1, 1), lambda b, h, l: (h, 0, 0)),        # Dp
+            pl.BlockSpec((1, 1, 1, P, N),
+                         lambda b, h, l: (b, h, rev(l), 0, 0)),        # ckpt
+            pl.BlockSpec((1, 1, T, P), lambda b, h, l: (b, h, rev(l), 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, T, P), lambda b, h, l: (b, h, rev(l), 0)),
+            pl.BlockSpec((1, 1, T, 1), lambda b, h, l: (b, h, rev(l), 0)),
+            pl.BlockSpec((1, 1, T, N), lambda b, h, l: (b, h, rev(l), 0)),
+            pl.BlockSpec((1, 1, T, N), lambda b, h, l: (b, h, rev(l), 0)),
+            pl.BlockSpec((1, 1, 1, 1), lambda b, h, l: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 1), lambda b, h, l: (b, h, 0, 0)),
+        ],
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=_PARAMS,
+    )(positions, u, delta, Ah, Bm, Cm, Dp, ckpts, dy)

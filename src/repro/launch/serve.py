@@ -132,6 +132,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import use_compile_cache
 from repro.configs.base import get_config
 from repro.core import packing
 from repro.faults import (EngineKilled, FaultPlan, poison_cache_rows,
@@ -1942,6 +1943,7 @@ def main():
                          "TensorBoard format) into this directory")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.tiny:
         cfg = dataclasses.replace(cfg, d_model=128, n_layers=4, vocab=512,

@@ -97,11 +97,6 @@ class Cell:
     meta: Dict[str, Any]
 
 
-def _ns(mesh, tree):
-    return jax.tree.map(lambda s: NamedSharding(mesh, s), tree,
-                        is_leaf=lambda x: isinstance(x, P))
-
-
 def _resolve_act_pspec(cfg: ArchConfig, mesh, batch: int) -> ArchConfig:
     """act_pspec=("auto",) → sequence-shard the residual carry over 'model'
     (Megatron-SP; right for attention-only stacks). ("auto_d",) → shard the
@@ -130,9 +125,7 @@ def build_train_cell(cfg: ArchConfig, mesh, shape_name: str = "train_4k",
     params_shape = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     opt_shape = jax.eval_shape(opt.init, params_shape)
     state_shape = {"params": params_shape, "opt": opt_shape}
-    pspec = shd.param_pspecs(params_shape, mesh)
-    state_spec = {"params": pspec,
-                  "opt": type(opt_shape)(step=P(), m=pspec, v=pspec)}
+    state_spec = shd.train_state_pspecs(state_shape, mesh)
     batch_shape = train_batch_specs(cfg, s["batch"], s["seq"])
     batch_spec = shd.batch_pspecs(batch_shape, mesh)
     metrics_spec = jax.tree.map(
@@ -140,8 +133,10 @@ def build_train_cell(cfg: ArchConfig, mesh, shape_name: str = "train_4k",
     return Cell(
         fn=step_fn,
         args=(state_shape, batch_shape),
-        in_shardings=(_ns(mesh, state_spec), _ns(mesh, batch_spec)),
-        out_shardings=(_ns(mesh, state_spec), _ns(mesh, metrics_spec)),
+        in_shardings=(shd.named(mesh, state_spec),
+                      shd.named(mesh, batch_spec)),
+        out_shardings=(shd.named(mesh, state_spec),
+                       shd.named(mesh, metrics_spec)),
         meta={"kind": "train", "batch": s["batch"], "seq": s["seq"],
               "fn_name": "train_step"},
     )
@@ -169,8 +164,9 @@ def build_prefill_cell(cfg: ArchConfig, mesh,
     return Cell(
         fn=prefill,
         args=(params_shape, batch_shape),
-        in_shardings=(_ns(mesh, pspec), _ns(mesh, batch_spec)),
-        out_shardings=_ns(mesh, P(shd.batch_axis(mesh, s["batch"]), vshard)),
+        in_shardings=(shd.named(mesh, pspec), shd.named(mesh, batch_spec)),
+        out_shardings=NamedSharding(
+            mesh, P(shd.batch_axis(mesh, s["batch"]), vshard)),
         meta={"kind": "prefill", "batch": s["batch"], "seq": s["seq"],
               "fn_name": "prefill"},
     )
@@ -196,11 +192,11 @@ def build_decode_cell(cfg: ArchConfig, mesh, shape_name: str) -> Cell:
     return Cell(
         fn=serve_step,
         args=(params_shape, ins["cache"], ins["tokens_t"], ins["cache_len"]),
-        in_shardings=(_ns(mesh, pspec), _ns(mesh, cache_spec),
+        in_shardings=(shd.named(mesh, pspec), shd.named(mesh, cache_spec),
                       NamedSharding(mesh, P(b, None)),
                       NamedSharding(mesh, P(b))),
         out_shardings=(NamedSharding(mesh, P(b, vshard)),
-                       _ns(mesh, cache_spec)),
+                       shd.named(mesh, cache_spec)),
         meta={"kind": "decode", "batch": s["batch"], "seq": s["seq"],
               "fn_name": "serve_step"},
     )

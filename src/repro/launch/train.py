@@ -1,9 +1,11 @@
 """Production training launcher: sharded train step on the production mesh
 (or whatever devices exist), checkpoint/resume, SIGTERM-safe.
 
-On a real TPU pod slice this is the entry each host runs (jax.distributed
-initializes from the TPU environment; the mesh axes map onto the physical
-topology). On CPU it runs the same code path on a local mesh.
+With more than one device it builds a ("data", "model") mesh over them
+(``--model-axis`` sets the model axis) and the Trainer creates the state
+sharded by distributed/sharding.py and places every batch over the data
+axis. On one device it runs the same step unsharded. The persistent
+compilation cache is on (repro/compile_cache.py).
 
   PYTHONPATH=src python -m repro.launch.train --arch mamba-110m \
       --rows 8 --seq-len 4096 --steps 100 --ckpt-dir /tmp/ckpt
@@ -22,16 +24,16 @@ import dataclasses
 import os
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.compile_cache import use_compile_cache
 from repro.configs.base import get_config
 from repro.data.dataset import SyntheticCorpus, CorpusConfig
 from repro.data.packing_loader import PackingLoader, LoaderConfig
-from repro.distributed import sharding as shd
+from repro.launch.mesh import make_local_mesh
 from repro.models.lm import build_model
 from repro.obs import Obs, profiler_session
 from repro.optim.adamw import AdamW, AdamWConfig, cosine_schedule
-from repro.train.trainer import Trainer, TrainerConfig, make_train_step
+from repro.train.trainer import Trainer, TrainerConfig
 
 
 def main():
@@ -86,6 +88,7 @@ def main():
             "use: python -m repro.launch.dryrun --arch "
             f"{args.arch} --shape train_4k --mesh both")
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.tiny:
         cfg = dataclasses.replace(cfg, d_model=128, n_layers=4, vocab=512,
@@ -119,24 +122,14 @@ def main():
                 AdamWConfig(weight_decay=0.1, clip_norm=1.0))
 
     n_dev = len(jax.devices())
-    step_fn = make_train_step(model, opt, accum=args.accum)
+    mesh = None
     if n_dev > 1:
-        from repro.launch.mesh import make_local_mesh
         mesh = make_local_mesh(model_axis=args.model_axis)
-        pspec = shd.param_pspecs(
-            jax.eval_shape(model.init, jax.random.PRNGKey(0)), mesh)
-        ns = lambda t: jax.tree.map(
-            lambda s: NamedSharding(mesh, s), t,
-            is_leaf=lambda x: isinstance(x, P))
-        state_spec = ns({"params": pspec})
         print(f"mesh {dict(mesh.shape)}; sharded train step")
-        # jit with param shardings; batch follows data axis
-        step_fn = jax.jit(step_fn, donate_argnums=(0,))
     trainer = Trainer(model, opt, loader, TrainerConfig(
         steps=args.steps, accum=args.accum, log_every=10,
         ckpt_every=args.ckpt_every if args.ckpt_dir else 0,
-        ckpt_dir=args.ckpt_dir), step_fn=None if n_dev == 1 else step_fn,
-        jit=(n_dev == 1), obs=obs)
+        ckpt_dir=args.ckpt_dir), obs=obs, mesh=mesh)
     print(f"training {cfg.name}: {args.steps} steps, mode={args.mode}, "
           f"rows={args.rows}x{args.seq_len}, devices={n_dev}")
     with profiler_session(args.profile_dir) as profiling:

@@ -1,45 +1,31 @@
-"""Optional jax.profiler integration with graceful degradation.
+"""jax.profiler integration.
 
 ``profile_region(obs, name)`` annotates a region so it shows up in an XLA
 profile (``jax.profiler.TraceAnnotation``) AND as a host span in the obs
 tracer. ``step_region`` is the per-train-step variant
 (``StepTraceAnnotation`` carries ``step_num`` into the profile's step
-view). When jax.profiler is missing (stripped builds) or tracing is off,
-both degrade cleanly: the jax side becomes a nullcontext, the host side a
-NullTracer no-op — callers never branch.
+view). With tracing off the host half is a NullTracer no-op and the
+annotation costs nothing outside a capture — callers never branch.
 
 ``profiler_session(dir)`` wraps ``jax.profiler.start_trace/stop_trace``
 for the ``--profile-dir`` flags on launch/serve.py and launch/train.py:
-the captured TensorBoard-format profile lands under ``dir`` and the
-context is a nullcontext when the profiler is unavailable.
+the captured TensorBoard-format profile lands under ``dir``. A capture that
+cannot start raises; it never degrades to a run without a profile.
 """
 from __future__ import annotations
 
 import contextlib
 from typing import Iterator, Optional
 
-
-def _jax_profiler():
-    try:
-        import jax.profiler as prof
-        return prof
-    except Exception:
-        return None
+import jax.profiler as prof
 
 
 @contextlib.contextmanager
 def profile_region(obs, name: str, track: Optional[str] = None,
                    **attrs) -> Iterator[None]:
-    """Host span (via ``obs.tracer``) + XLA TraceAnnotation when available.
-
-    ``obs`` is an ``Obs`` bundle (obs/__init__.py); a disabled tracer makes
-    the host half free, an absent jax.profiler makes the device half free.
-    """
-    prof = _jax_profiler()
-    ann = (prof.TraceAnnotation(name)
-           if prof is not None and hasattr(prof, "TraceAnnotation")
-           else contextlib.nullcontext())
-    with ann, obs.tracer.span(name, track=track, **attrs):
+    """Host span (via ``obs.tracer``) + XLA TraceAnnotation."""
+    with prof.TraceAnnotation(name), \
+            obs.tracer.span(name, track=track, **attrs):
         yield
 
 
@@ -48,21 +34,17 @@ def step_region(obs, name: str, step: int,
                 track: Optional[str] = None, **attrs) -> Iterator[None]:
     """Per-step profile_region: StepTraceAnnotation groups device ops under
     a step number in TensorBoard's profile step view."""
-    prof = _jax_profiler()
-    ann = (prof.StepTraceAnnotation(name, step_num=step)
-           if prof is not None and hasattr(prof, "StepTraceAnnotation")
-           else contextlib.nullcontext())
-    with ann, obs.tracer.span(name, track=track, step=step, **attrs):
+    with prof.StepTraceAnnotation(name, step_num=step), \
+            obs.tracer.span(name, track=track, step=step, **attrs):
         yield
 
 
 @contextlib.contextmanager
 def profiler_session(profile_dir: Optional[str]) -> Iterator[bool]:
     """Capture an XLA profile into ``profile_dir`` for the duration of the
-    block (the --profile-dir flag). Yields whether a capture is actually
-    running: False when dir is None or jax.profiler lacks start_trace."""
-    prof = _jax_profiler()
-    if not profile_dir or prof is None or not hasattr(prof, "start_trace"):
+    block (the --profile-dir flag). Yields whether a capture is running:
+    False only when no directory was asked for."""
+    if not profile_dir:
         yield False
         return
     prof.start_trace(profile_dir)

@@ -4,6 +4,9 @@ SIGTERM-safe emergency save, deterministic data replay, throughput metering.
 Distribution notes (the 1000+-node posture, exercised by the dry-run):
   * train_step is built once and jit'ed with in/out shardings from
     distributed/sharding.py — batch over ("pod","data"), params FSDP×TP.
+    ``Trainer(mesh=...)`` does this on a live mesh: the state is created
+    sharded, each batch is placed by its sharding before the step, and the
+    step's outputs keep the state's shardings.
   * gradient accumulation runs as a lax.scan over microbatches with an f32
     (or bf16 — ``grad_accum_dtype``, the memory-compression knob) carried
     accumulator; XLA overlaps the per-microbatch reduce-scatter with the
@@ -22,8 +25,11 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.optim.adamw import AdamW, AdamWState
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from repro.optim.adamw import AdamW
 from repro.checkpoint.checkpoint import CheckpointManager
+from repro.distributed import sharding as shd
 from repro.obs import Obs
 
 
@@ -86,14 +92,28 @@ def make_train_step(model, opt: AdamW, accum: int = 1,
 class Trainer:
     def __init__(self, model, opt: AdamW, loader, cfg: TrainerConfig,
                  step_fn: Optional[Callable] = None, jit: bool = True,
-                 obs: Optional[Obs] = None):
+                 obs: Optional[Obs] = None, mesh=None):
         self.model = model
         self.opt = opt
         self.loader = loader
         self.cfg = cfg
+        self.mesh = mesh
         fn = step_fn or make_train_step(model, opt, cfg.accum,
                                         cfg.grad_accum_dtype)
-        self.step_fn = jax.jit(fn, donate_argnums=(0,)) if jit else fn
+        self._init = self.init_state
+        self.state_sharding = None
+        if mesh is not None:
+            state_shape = jax.eval_shape(self.init_state,
+                                         jax.random.PRNGKey(0))
+            self.state_sharding = shd.named(
+                mesh, shd.train_state_pspecs(state_shape, mesh))
+            self._init = jax.jit(self.init_state,
+                                 out_shardings=self.state_sharding)
+            fn = jax.jit(fn, donate_argnums=(0,), out_shardings=(
+                self.state_sharding, NamedSharding(mesh, P())))
+        elif jit:
+            fn = jax.jit(fn, donate_argnums=(0,))
+        self.step_fn = fn
         self.ckpt = CheckpointManager(cfg.ckpt_dir, keep=cfg.keep_ckpts) \
             if cfg.ckpt_dir else None
         self._interrupted = False
@@ -126,11 +146,19 @@ class Trainer:
         params = self.model.init(key)
         return {"params": params, "opt": self.opt.init(params)}
 
+    def _place_batch(self, batch):
+        """Put a host batch on the mesh, rows over the data axes (a no-op
+        without a mesh: the jitted step transfers it)."""
+        if self.mesh is None:
+            return batch
+        return jax.device_put(
+            batch, shd.named(self.mesh, shd.batch_pspecs(batch, self.mesh)))
+
     def restore_or_init(self, key) -> Tuple[Dict[str, Any], int]:
-        state = self.init_state(key)
+        state = self._init(key)
         if self.ckpt and self.ckpt.latest_step() is not None:
             step = self.ckpt.latest_step()
-            state = self.ckpt.restore(state)
+            state = self.ckpt.restore(state, shardings=self.state_sharding)
             return state, int(self.ckpt.read_meta(step)["meta"]["step"])
         return state, 0
 
@@ -176,7 +204,7 @@ class Trainer:
                 self._c_compiles.inc()
             sid = tr.start("train.step", track="train", step=step,
                            compile=compiled)
-            state, metrics = self.step_fn(state, batch)
+            state, metrics = self.step_fn(state, self._place_batch(batch))
             # sync so the span covers device time, not dispatch time — a
             # no-op on the disabled tracer (no extra syncs when off)
             tr.sync(metrics["loss"])

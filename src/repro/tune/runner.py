@@ -11,9 +11,9 @@ can differ from the forward's). Winners are cached under objective-tagged
 keys and never served across objectives.
 
 Pallas candidates are included only where their timings mean something:
-real TPU kernels, not interpret mode (`INTERPRET` in
-kernels/selective_scan.py) — interpret-mode wall clock would "tune" the
-emulator.
+on a TPU, where the kernels are compiled — elsewhere they run under the
+Pallas interpreter (kernels/__init__.py), whose wall clock would "tune"
+the emulator.
 
 CLI — the bounded default sweep behind ``make bench-tune``:
 
@@ -49,12 +49,6 @@ def _timing():
             sys.path.insert(0, root)
         from benchmarks.timing import interleaved_min_of_rounds
     return interleaved_min_of_rounds
-
-
-def _pallas_usable() -> bool:
-    import jax
-    from repro.kernels import selective_scan as scan_k
-    return jax.default_backend() == "tpu" and not scan_k.INTERPRET
 
 
 # ---------------------------------------------------------------------------
@@ -150,16 +144,19 @@ def tune_key(key: ShapeKey, cache: Optional[TuneCache] = None,
     """Measure the candidate space at ``key``, cache and return the winner.
 
     Candidates that fail to build/compile are dropped (a knob combination
-    can be invalid for a shape); at least the default-equivalent candidates
-    always survive. ``obs`` (repro.obs.Obs) records one ``tune.sweep`` span
-    per key with nested ``tune.candidate`` compile probes, plus
-    ``tune.sweeps`` / ``tune.candidates`` counters."""
+    can be invalid for a shape), each with a printed line and a
+    ``tune.dropped`` count, so a kernel that fails to compile is never
+    dropped unseen; at least the default-equivalent candidates always
+    survive. ``obs`` (repro.obs.Obs) records one ``tune.sweep`` span per key
+    with nested ``tune.candidate`` compile probes, plus ``tune.sweeps`` /
+    ``tune.candidates`` / ``tune.dropped`` counters."""
     if obs is None:
         from repro.obs import Obs
         obs = Obs.off()
     tr = obs.tracer
     if include_pallas is None:
-        include_pallas = _pallas_usable()
+        import jax
+        include_pallas = jax.default_backend() == "tpu"
     cands = space_for(key, include_pallas=include_pallas)
     args = synth_args(key)
     cells: List[Tuple[str, object]] = []
@@ -173,8 +170,9 @@ def tune_key(key: ShapeKey, cache: Optional[TuneCache] = None,
                 thunk = make_thunk(key, c, args)
                 thunk()       # build + compile probe outside the timed loop
         except Exception as e:
-            if verbose:
-                print(f"#   tune drop {name}: {type(e).__name__}: {e}")
+            obs.metrics.counter("tune.dropped").inc()
+            print(f"#   tune drop {key.encode()} {name}: "
+                  f"{type(e).__name__}: {e}")
             continue
         cells.append((name, thunk))
         by_name[name] = c

@@ -140,16 +140,18 @@ def test_elastic_restore_across_mesh_sizes():
 import tempfile, numpy as np, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint.checkpoint import CheckpointManager
-from repro.distributed.compat import make_mesh
+from jax.sharding import AxisType
 
 d = tempfile.mkdtemp()
-mesh1 = make_mesh((4, 2), ("data", "model"))
+mesh1 = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 w = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
 w1 = jax.device_put(w, NamedSharding(mesh1, P("data", "model")))
 mgr = CheckpointManager(d, async_save=False)
 mgr.save(1, {"w": w1})
 
-mesh2 = make_mesh((2, 4), ("data", "model"))
+mesh2 = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 sh2 = {"w": NamedSharding(mesh2, P("data", "model"))}
 got = mgr.restore({"w": w}, shardings=sh2)
 assert got["w"].sharding == sh2["w"], got["w"].sharding

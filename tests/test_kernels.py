@@ -1,7 +1,8 @@
 """Pallas kernels vs pure-jnp oracle: shape/dtype sweeps, fwd + grads.
 
-Kernels run in interpret mode on CPU (the TPU target is validated
-structurally: BlockSpecs, VMEM scratch, grid semantics)."""
+On the CPU the kernels run under the Pallas interpreter (the mode follows
+the platform, kernels/__init__.py); tests/test_tpu_compile.py compiles the
+same entry points for a described TPU v5e."""
 import numpy as np
 import jax
 import jax.numpy as jnp

@@ -256,9 +256,9 @@ def test_blocked_heads_jaxpr_has_no_full_trajectory():
     want = (Bz, L, H, P, N)
 
     def subjaxprs(val):
-        if isinstance(val, jax.core.Jaxpr):
+        if isinstance(val, jax.extend.core.Jaxpr):
             yield val
-        elif isinstance(val, jax.core.ClosedJaxpr):
+        elif isinstance(val, jax.extend.core.ClosedJaxpr):
             yield val.jaxpr
         elif isinstance(val, (tuple, list)):
             for v in val:

@@ -96,8 +96,8 @@ def test_collective_bytes_from_sharded_module():
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.roofline.hlo_static import analyze
-from repro.distributed.compat import make_mesh
-mesh = make_mesh((4,), ("model",))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4,), ("model",), axis_types=(AxisType.Auto,))
 a = jax.ShapeDtypeStruct((128, 256), jnp.float32)
 b = jax.ShapeDtypeStruct((256, 128), jnp.float32)
 f = jax.jit(lambda a, b: a @ b,

@@ -82,8 +82,9 @@ MINI_DRYRUN = r"""
 import jax, dataclasses
 from repro.configs.base import get_config
 from repro.launch.shapes import build_cell, SHAPES
-from repro.distributed.compat import make_mesh
-mesh = make_mesh((4, 2), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 SHAPES["train_4k"] = dict(kind="train", seq=128, batch=8)
 SHAPES["decode_32k"] = dict(kind="decode", seq=128, batch=8)
 for arch in ARCHS:
@@ -129,7 +130,7 @@ from repro.models.lm import build_model
 from repro.optim.adamw import AdamW, constant_schedule
 from repro.train.trainer import make_train_step
 from repro.distributed import sharding as shd
-from repro.distributed.compat import make_mesh
+from jax.sharding import AxisType
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 cfg = dataclasses.replace(get_config("mamba-110m").reduced(), dtype="float32")
@@ -145,7 +146,8 @@ params = model.init(jax.random.PRNGKey(0))
 state = {"params": params, "opt": opt.init(params)}
 ref_state, ref_metrics = jax.jit(step)(state, batch)
 
-mesh = make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 pspec = shd.param_pspecs(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
                          mesh)
 ns = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
@@ -172,3 +174,62 @@ print("NUMERIC_OK")
     out = subprocess.run([sys.executable, "-c", src], env=env,
                          capture_output=True, text=True, timeout=600)
     assert "NUMERIC_OK" in out.stdout, out.stderr[-2000:]
+
+
+def test_trainer_mesh_places_state_and_batch_4dev():
+    """Trainer(mesh=...) creates the state sharded by the param rules, keeps
+    those shardings across steps, shards each batch over the data axis, and
+    trains to the same losses as the one-device Trainer."""
+    src = r"""
+import jax, jax.numpy as jnp, numpy as np, dataclasses
+from jax.sharding import PartitionSpec as P
+from repro.configs.base import get_config
+from repro.launch.mesh import make_local_mesh
+from repro.models.lm import build_model
+from repro.optim.adamw import AdamW, constant_schedule
+from repro.train.trainer import Trainer, TrainerConfig
+
+cfg = dataclasses.replace(get_config("mamba-110m").reduced(), dtype="float32")
+model = build_model(cfg)
+rng = np.random.default_rng(0)
+B, L = 4, 32
+batches = [{"tokens": jnp.asarray(rng.integers(1, cfg.vocab, (B, L)),
+                                  jnp.int32),
+            "positions": jnp.tile(jnp.arange(L)[None] % 13, (B, 1)),
+            "segment_ids": jnp.ones((B, L), jnp.int32)} for _ in range(3)]
+
+class Loader:
+    def batch(self, step):
+        return batches[step]
+
+def run(mesh):
+    tr = Trainer(model, AdamW(constant_schedule(1e-3)), Loader(),
+                 TrainerConfig(steps=3, log_every=100), mesh=mesh)
+    seen = []
+    step_fn = tr.step_fn
+    def spy(state, batch):
+        seen.append(batch["tokens"].sharding)
+        return step_fn(state, batch)
+    tr.step_fn = spy
+    state, hist = tr.train(jax.random.PRNGKey(0), verbose=False)
+    return state, [h["loss"] for h in hist], seen
+
+_, ref, _ = run(None)
+mesh = make_local_mesh(model_axis=2)
+state, losses, seen = run(mesh)
+np.testing.assert_allclose(losses, ref, rtol=1e-5)
+assert all(s.spec == P("data", None) for s in seen), seen
+w = state["params"]["units"]["0_mamba"]["in_proj"]
+assert w.sharding.spec == P(None, "data", "model"), w.sharding
+m = state["opt"].m["units"]["0_mamba"]["in_proj"]
+assert m.sharding.spec == w.sharding.spec, m.sharding
+assert len(w.sharding.device_set) == 4
+print("MESH_OK")
+"""
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    out = subprocess.run([sys.executable, "-c", src], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert "MESH_OK" in out.stdout, out.stderr[-2000:]

@@ -477,6 +477,26 @@ def test_runner_tune_key_and_ensure(monkeypatch):
                           N=4, cache=c) is False
 
 
+def test_runner_reports_every_dropped_candidate(monkeypatch, capsys):
+    """A candidate that fails to build is printed and counted
+    (``tune.dropped``) even without ``verbose`` — on the chip a Pallas
+    kernel that fails to compile must never vanish from a sweep unseen."""
+    from repro.obs import Obs
+    monkeypatch.setattr(
+        trunner, "space_for",
+        lambda key, include_pallas=False: [
+            {"backend": "xla", "method": "sequential"},
+            {"backend": "xla", "method": "not-a-method"},
+        ])
+    obs = Obs.off()
+    k = shape_key("selective_scan", B=1, L=32, D=8, N=4)
+    trunner.tune_key(k, cache=TuneCache(), rounds=1, obs=obs)
+    out = capsys.readouterr().out
+    assert "tune drop" in out and "not-a-method" in out
+    assert obs.metrics.counter("tune.dropped").value == 1
+    assert obs.metrics.counter("tune.candidates").value == 1
+
+
 def test_synth_positions_density():
     p = trunner.synth_positions(np.random.default_rng(0), 2, 256, "mid")
     assert p.shape == (2, 256)
