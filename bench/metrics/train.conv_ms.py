@@ -1,0 +1,9 @@
+"""train.conv_ms: device time of the packed causal conv per train step:
+the self time of the device ops under the program's ``mamba.conv`` scope
+(models/blocks.py ``apply_mamba``), forward and backward, in the traced
+window, over the window's steps (bench/lib/layers.py)."""
+from bench.lib.layers import per_step_ms
+
+
+def read(run: dict):
+    return per_step_ms(run, ("mamba.conv",))

@@ -202,6 +202,7 @@ def main():
           f"{obs.metrics.counter('serve.generated').value}; "
           f"timeline of req0:")
     print(obs.tracer.timeline("req0"))
+    obs.close()
 
     # 8. prefix caching on the O(1) SSM state (docs/serving.md §4): a
     #    session's whole inference state is a few KB regardless of prompt

@@ -124,7 +124,6 @@ Finally, two O(1)-state exploits ride on the same snapshot leaf layout
 import argparse
 import collections
 import dataclasses
-import functools
 import time
 from typing import Callable, Dict, List, Optional
 
@@ -473,10 +472,14 @@ class ServeEngine:
             "spec.accepted", help="draft tokens accepted by verify")
         self._spec_rate = m.gauge(
             "spec.accept_rate", help="accepted/proposed, cumulative")
-        self._prefill = jax.jit(
-            functools.partial(model.prefill_packed, max_len=max_len))
-        self._wave_prefill = jax.jit(
-            functools.partial(model.prefill, max_len=max_len))
+        def prefill_packed(params, batch, ends):
+            return model.prefill_packed(params, batch, max_len, ends)
+
+        def prefill_wave(params, batch):
+            return model.prefill(params, batch, max_len)
+
+        self._prefill = jax.jit(prefill_packed)
+        self._wave_prefill = jax.jit(prefill_wave)
 
         # chunked-prefill lane: a side cache of chunk_rows long prompts
         # being consumed slab by slab; handoff to a decode slot reuses the
@@ -857,9 +860,9 @@ class ServeEngine:
             self.status[req.rid] = "active"
             self._span_to(req.rid, "prefill", bucket=L)
         pidx = self.stats.prefills      # this dispatch's fault-plan index
-        dsid = self._tr.start("prefill_dispatch", track="engine", bucket=L,
-                              rows=self.prefill_rows, admitted=len(admitted),
-                              pidx=pidx)
+        dsid = self._tr.start("serve.prefill_dispatch", track="engine",
+                              bucket=L, rows=self.prefill_rows,
+                              admitted=len(admitted), pidx=pidx)
         if self.faults is not None and self.faults.fails_prefill(pidx):
             # the packed forward died (injected stand-in for device OOM /
             # preemption): fail this round's requests with an explicit
@@ -970,7 +973,7 @@ class ServeEngine:
 
     def _land_one(self, inf: dict):
         """Land one dispatched prefill: scatter states, activate slots."""
-        lsid = self._tr.start("prefill_land", track="engine",
+        lsid = self._tr.start("serve.prefill_land", track="engine",
                               pidx=inf["pidx"],
                               steps_waited=inf["steps_waited"])
         src_j, dst_j = inf["src"], inf["dst"]
@@ -991,8 +994,10 @@ class ServeEngine:
         # host bookkeeping + the prefill's own first token (the np.asarray
         # is the host sync point — TTFT is measured where the token becomes
         # observable, not where the prefill was dispatched)
-        first = np.asarray(inf["tok"])
-        ok = np.asarray(inf["ok"]).reshape(-1) if "ok" in inf else None
+        with self._tr.span("serve.prefill_wait", track="engine"):
+            first = np.asarray(inf["tok"])
+            ok = np.asarray(inf["ok"]).reshape(-1) if "ok" in inf else None
+        esid = self._tr.start("serve.prefill_emit", track="engine")
         now = self._clock()
         for qi, req in enumerate(inf["admitted"]):
             slot, r, s = inf["slot_of"][qi]
@@ -1034,6 +1039,7 @@ class ServeEngine:
             self._finish_token(slot, int(first[k]))
         if inf["steps_waited"] > 0:
             self.stats.overlapped_prefills += 1
+        self._tr.finish(esid)
         self._tr.finish(lsid)
 
     # ------------------------------------------------------- chunked prefill
@@ -1299,8 +1305,9 @@ class ServeEngine:
             entries[i] = (self.chunk_req[i].tokens, off, n)
             took[i] = n
         batch = packing.suffix_slab(entries, self.chunk_rows, T)
-        csid = self._tr.start("chunk_slab", track="engine", round=cidx,
-                              rows=len(rows), tokens=sum(took.values()))
+        csid = self._tr.start("serve.chunk_slab", track="engine",
+                              round=cidx, rows=len(rows),
+                              tokens=sum(took.values()))
         logits, self.chunk_cache, self.chunk_clen = self._chunk_fn(
             self.params, self.chunk_cache, batch, self.chunk_clen)
         self.stats.chunk_rounds += 1
@@ -1329,6 +1336,8 @@ class ServeEngine:
                 finishing.append(i)
         if not finishing:
             return
+        hsid = self._tr.start("serve.chunk_handoff", track="engine",
+                              handoffs=len(finishing))
         # handoff: sample each finished prompt's first token with its own
         # (seed, rid)-derived key stream, then scatter the carried state
         # into the reserved decode slot — fixed chunk_rows shapes, the
@@ -1367,7 +1376,8 @@ class ServeEngine:
             jnp.asarray(topk), mode="drop")
         self.slot_topp = self.slot_topp.at[dst_j].set(
             jnp.asarray(topp), mode="drop")
-        first = np.asarray(tok)         # host sync — TTFT observed here
+        with self._tr.span("serve.chunk_wait", track="engine"):
+            first = np.asarray(tok)     # host sync — TTFT observed here
         now = self._clock()
         for i in finishing:
             req = self.chunk_req[i]
@@ -1406,6 +1416,7 @@ class ServeEngine:
             self._tr.instant("first_token", track=f"req{req.rid}",
                              rid=req.rid)
             self._finish_token(slot, int(first[i]))
+        self._tr.finish(hsid)
 
     # --------------------------------------------------- speculative decode
     def _spec_draft(self):
@@ -1453,8 +1464,8 @@ class ServeEngine:
         if not have.any():
             return False
         K = self.spec_k
-        rsid = self._tr.start("spec_round", track="engine", step=step_idx,
-                              active=len(active), k=K)
+        rsid = self._tr.start("serve.spec_round", track="engine",
+                              step=step_idx, active=len(active), k=K)
         toks, fins, traj = self._spec_verify(
             self.params, self.cache, self.cur_tok, self.cache_len,
             jnp.asarray(draft))
@@ -1559,8 +1570,8 @@ class ServeEngine:
         if self.spec_k and not sampling and \
                 self._spec_round(active, step_idx):
             return
-        dsid = self._tr.start("decode_step", track="engine", step=step_idx,
-                              active=len(active))
+        dsid = self._tr.start("serve.decode_step", track="engine",
+                              step=step_idx, active=len(active))
         fin = None
         if self.guard:
             pv = None if self.faults is None else \
@@ -1593,7 +1604,9 @@ class ServeEngine:
         self.stats.decode_steps += 1
         for inf in self._prefill_pool:
             inf["steps_waited"] += 1
-        toks = np.asarray(tok)
+        with self._tr.span("serve.decode_wait", track="engine"):
+            toks = np.asarray(tok)
+        esid = self._tr.start("serve.decode_emit", track="engine")
         now = self._clock()
         for i in active:
             if fin is not None and not fin[i]:
@@ -1614,6 +1627,7 @@ class ServeEngine:
             self.slot_last_t[i] = now
             self._finish_token(i, int(toks[i]))
         self._expire_active(now)             # per-step deadline enforcement
+        self._tr.finish(esid)
         self._tr.finish(dsid)
 
     # ----------------------------------------------------------------- loop
@@ -2023,6 +2037,7 @@ def main():
         print(f"obs: wrote {len(obs.tracer.chrome_events())} trace events "
               f"to {args.obs_trace} (open in chrome://tracing or "
               f"ui.perfetto.dev)")
+    obs.close()
     if args.profile_dir and profiling:
         print(f"obs: XLA profile captured under {args.profile_dir}")
 

@@ -140,6 +140,7 @@ def main():
         print(f"obs: wrote {len(obs.tracer.chrome_events())} trace events "
               f"to {args.obs_trace} (open in chrome://tracing or "
               f"ui.perfetto.dev)")
+    obs.close()
     if args.profile_dir and profiling:
         print(f"obs: XLA profile captured under {args.profile_dir}")
 

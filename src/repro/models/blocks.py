@@ -400,64 +400,93 @@ def _tune_kw(cfg: ArchConfig):
     return {"tune": _cfg_tune(cfg), "tune_objective": cfg.tune_objective}
 
 
+def _mamba_in(p, x, cfg: ArchConfig):
+    """The block's input: its norm, then ``in_proj`` split into the scan
+    branch and the gate."""
+    with jax.named_scope("mamba.norm"):
+        h = _norm(p["norm"], x, cfg.norm_eps)
+    with jax.named_scope("mamba.in_proj"):
+        xz = h @ p["in_proj"].astype(h.dtype)
+        x_in, z = jnp.split(xz, 2, axis=-1)
+    return x_in, z
+
+
+def _mamba_gates(p, x_c, dtype, cfg: ArchConfig):
+    """``x_proj``, the dt projection and its softplus: (delta, B, C)."""
+    N, dtr = cfg.d_state, cfg.dtr
+    with jax.named_scope("mamba.x_proj"):
+        dbl = x_c @ p["x_proj"].astype(dtype)
+        dt_low, Bm, Cm = jnp.split(dbl, [dtr, dtr + N], axis=-1)
+        delta = jax.nn.softplus(dt_low @ p["dt_w"].astype(dtype) +
+                                p["dt_b"].astype(dtype))
+    return delta, Bm, Cm
+
+
+def _mamba_out(p, x, y, z):
+    """The gate, ``out_proj`` and the residual."""
+    with jax.named_scope("mamba.out_proj"):
+        y = y * jax.nn.silu(z)
+        return x + y @ p["out_proj"].astype(x.dtype)
+
+
 def apply_mamba(p, x, ctx: Ctx, cfg: ArchConfig, collect: int = 0,
                 collect_ends=None):
-    B, L, d = x.shape
-    di, N, dtr = cfg.d_inner, cfg.d_state, cfg.dtr
+    """Each piece runs under a named scope (``mamba.norm``, ``.in_proj``,
+    ``.conv``, ``.x_proj``, ``.scan``, ``.out_proj``), so the ops of a
+    device trace say which piece they belong to."""
     backend = "pallas" if cfg.use_pallas else "xla"
-    h = _norm(p["norm"], x, cfg.norm_eps)
-    xz = h @ p["in_proj"].astype(h.dtype)
-    x_in, z = jnp.split(xz, 2, axis=-1)
-    x_c = kops.conv1d_pack(x_in, p["conv_w"].astype(h.dtype),
-                           p["conv_b"].astype(h.dtype),
-                           ctx.positions, backend=backend)
-    x_c = jax.nn.silu(x_c)
-    dbl = x_c @ p["x_proj"].astype(h.dtype)
-    dt_low, Bm, Cm = jnp.split(dbl, [dtr, dtr + N], axis=-1)
-    delta = jax.nn.softplus(dt_low @ p["dt_w"].astype(h.dtype) +
-                            p["dt_b"].astype(h.dtype))
-    A = -jnp.exp(p["A_log"])
+    dt = x.dtype
+    x_in, z = _mamba_in(p, x, cfg)
+    with jax.named_scope("mamba.conv"):
+        x_c = kops.conv1d_pack(x_in, p["conv_w"].astype(dt),
+                               p["conv_b"].astype(dt),
+                               ctx.positions, backend=backend)
+        x_c = jax.nn.silu(x_c)
+    delta, Bm, Cm = _mamba_gates(p, x_c, dt, cfg)
     if collect and collect_ends is not None:
         # per-SEGMENT handoff: resets already isolate segments, so the state
         # sampled at each segment end IS its final state — no freezing, and
         # padding (pos == 0 ⇒ reset) cannot leak into earlier samples.
-        y, h_ends = core_ssm.selective_scan(
-            x_c, delta, A, Bm, Cm, p["D"], positions=ctx.positions,
-            method=cfg.scan_impl, chunk=cfg.scan_chunk,
-            intra=cfg.scan_intra, collect_ends=collect_ends,
-            **_tune_kw(cfg))
-        state = {"conv": _conv_tail_ends(x_in, collect_ends,
-                                         _ends_lens(ctx, collect_ends),
-                                         cfg.d_conv),
-                 "ssm": h_ends}
-        y = y * jax.nn.silu(z)
-        return x + y @ p["out_proj"].astype(x.dtype), state
+        with jax.named_scope("mamba.scan"):
+            A = -jnp.exp(p["A_log"])
+            y, h_ends = core_ssm.selective_scan(
+                x_c, delta, A, Bm, Cm, p["D"], positions=ctx.positions,
+                method=cfg.scan_impl, chunk=cfg.scan_chunk,
+                intra=cfg.scan_intra, collect_ends=collect_ends,
+                **_tune_kw(cfg))
+        with jax.named_scope("mamba.conv"):
+            conv = _conv_tail_ends(x_in, collect_ends,
+                                   _ends_lens(ctx, collect_ends), cfg.d_conv)
+        return _mamba_out(p, x, y, z), {"conv": conv, "ssm": h_ends}
     if collect:
         # freeze state across right-padding: Δ=0 ⇒ Ā=1, B̄x=0. Padding
         # positions are 0, which would trigger the Ā→0 reset and zero the
         # handed-off state — neutralize them (pos→1) there.
-        valid = _valid(ctx, x)
-        delta = delta * valid[..., None].astype(delta.dtype)
-        pos_nz = jnp.where(valid, ctx.positions, 1)
-        y, h_last = core_ssm.selective_scan(
-            x_c, delta, A, Bm, Cm, p["D"], positions=pos_nz,
-            method=cfg.scan_impl, chunk=cfg.scan_chunk, return_state=True,
-            intra=cfg.scan_intra, **_tune_kw(cfg))
-        state = {"conv": _conv_tail(x_in, valid.sum(-1), cfg.d_conv),
-                 "ssm": h_last}
-        y = y * jax.nn.silu(z)
-        return x + y @ p["out_proj"].astype(x.dtype), state
-    y = kops.selective_scan(x_c, delta, A, Bm, Cm, p["D"],
-                            positions=ctx.positions, backend=backend,
-                            xla_chunk=cfg.scan_chunk,
-                            xla_method=cfg.scan_impl,
-                            xla_dtype=(None if cfg.scan_dtype == "float32"
-                                       else cfg.scan_dtype),
-                            xla_intra=cfg.scan_intra,
-                            schedule=cfg.pallas_schedule,
-                            **_tune_kw(cfg))
-    y = y * jax.nn.silu(z)
-    return x + y @ p["out_proj"].astype(x.dtype)
+        with jax.named_scope("mamba.scan"):
+            A = -jnp.exp(p["A_log"])
+            valid = _valid(ctx, x)
+            delta = delta * valid[..., None].astype(delta.dtype)
+            pos_nz = jnp.where(valid, ctx.positions, 1)
+            y, h_last = core_ssm.selective_scan(
+                x_c, delta, A, Bm, Cm, p["D"], positions=pos_nz,
+                method=cfg.scan_impl, chunk=cfg.scan_chunk,
+                return_state=True, intra=cfg.scan_intra, **_tune_kw(cfg))
+        with jax.named_scope("mamba.conv"):
+            conv = _conv_tail(x_in, valid.sum(-1), cfg.d_conv)
+        return _mamba_out(p, x, y, z), {"conv": conv, "ssm": h_last}
+    with jax.named_scope("mamba.scan"):
+        A = -jnp.exp(p["A_log"])
+        y = kops.selective_scan(x_c, delta, A, Bm, Cm, p["D"],
+                                positions=ctx.positions, backend=backend,
+                                xla_chunk=cfg.scan_chunk,
+                                xla_method=cfg.scan_impl,
+                                xla_dtype=(None
+                                           if cfg.scan_dtype == "float32"
+                                           else cfg.scan_dtype),
+                                xla_intra=cfg.scan_intra,
+                                schedule=cfg.pallas_schedule,
+                                **_tune_kw(cfg))
+    return _mamba_out(p, x, y, z)
 
 
 def init_mamba_cache(cfg: ArchConfig, batch: int, dtype):
@@ -958,32 +987,29 @@ def _conv_resume(x_in, conv_cache, w, b, positions, backend):
 
 
 def chunk_mamba(p, x, cache, ctx: Ctx, cfg: ArchConfig):
-    B, T, d = x.shape
-    N, dtr, W = cfg.d_state, cfg.dtr, cfg.d_conv
+    W = cfg.d_conv
     backend = "pallas" if cfg.use_pallas else "xla"
-    h = _norm(p["norm"], x, cfg.norm_eps)
-    xz = h @ p["in_proj"].astype(h.dtype)
-    x_in, z = jnp.split(xz, 2, axis=-1)
-    x_c = _conv_resume(x_in, cache["conv"], p["conv_w"].astype(h.dtype),
-                       p["conv_b"].astype(h.dtype), ctx.positions, backend)
-    x_c = jax.nn.silu(x_c)
-    dbl = x_c @ p["x_proj"].astype(h.dtype)
-    dt_low, Bm, Cm = jnp.split(dbl, [dtr, dtr + N], axis=-1)
-    delta = jax.nn.softplus(dt_low @ p["dt_w"].astype(h.dtype) +
-                            p["dt_b"].astype(h.dtype))
-    A = -jnp.exp(p["A_log"])
-    valid = _valid(ctx, x)
-    delta = delta * valid[..., None].astype(delta.dtype)
-    pos_nz = jnp.where(valid, ctx.positions, 1)
-    y, h_last = core_ssm.selective_scan(
-        x_c, delta, A, Bm, Cm, p["D"], positions=pos_nz,
-        method=cfg.scan_impl, chunk=cfg.scan_chunk, return_state=True,
-        h0=cache["ssm"], intra=cfg.scan_intra, **_tune_kw(cfg))
-    ext = jnp.concatenate([cache["conv"].astype(x_in.dtype), x_in], axis=1)
-    state = {"conv": _conv_tail(ext, (W - 1) + valid.sum(-1), W),
-             "ssm": h_last}
-    y = y * jax.nn.silu(z)
-    return x + y @ p["out_proj"].astype(x.dtype), state
+    dt = x.dtype
+    x_in, z = _mamba_in(p, x, cfg)
+    with jax.named_scope("mamba.conv"):
+        x_c = _conv_resume(x_in, cache["conv"], p["conv_w"].astype(dt),
+                           p["conv_b"].astype(dt), ctx.positions, backend)
+        x_c = jax.nn.silu(x_c)
+    delta, Bm, Cm = _mamba_gates(p, x_c, dt, cfg)
+    with jax.named_scope("mamba.scan"):
+        A = -jnp.exp(p["A_log"])
+        valid = _valid(ctx, x)
+        delta = delta * valid[..., None].astype(delta.dtype)
+        pos_nz = jnp.where(valid, ctx.positions, 1)
+        y, h_last = core_ssm.selective_scan(
+            x_c, delta, A, Bm, Cm, p["D"], positions=pos_nz,
+            method=cfg.scan_impl, chunk=cfg.scan_chunk, return_state=True,
+            h0=cache["ssm"], intra=cfg.scan_intra, **_tune_kw(cfg))
+    with jax.named_scope("mamba.conv"):
+        ext = jnp.concatenate([cache["conv"].astype(x_in.dtype), x_in],
+                              axis=1)
+        conv = _conv_tail(ext, (W - 1) + valid.sum(-1), W)
+    return _mamba_out(p, x, y, z), {"conv": conv, "ssm": h_last}
 
 
 def chunk_mamba2(p, x, cache, ctx: Ctx, cfg: ArchConfig):

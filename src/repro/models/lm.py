@@ -144,6 +144,7 @@ class LM:
         return params
 
     # ------------------------------------------------------------- embedding
+    @jax.named_scope("lm.embed")
     def _embed(self, params, batch) -> jnp.ndarray:
         cfg = self.cfg
         dt = jnp.dtype(cfg.dtype)
@@ -209,7 +210,8 @@ class LM:
                 if aux:
                     lb = lb + aux["lb_loss"]
                     zl = zl + aux["z_loss"]
-        x = B._norm(params["final_norm"], x, cfg.norm_eps)
+        with jax.named_scope("lm.head"):
+            x = B._norm(params["final_norm"], x, cfg.norm_eps)
         return x, {"lb_loss": lb, "z_loss": zl}
 
     def _head_t(self, params):
@@ -228,9 +230,14 @@ class LM:
         """Packed next-token CE. Labels: explicit batch['labels'] (with -1 =
         masked) or derived by in-segment shift. Vocab-dim logits are computed
         in L-chunks so the (B, L, V) f32 tensor never materializes."""
-        cfg = self.cfg
         x = self._embed(params, batch)
         x, aux = self._stack(params, x, self._ctx(batch))
+        with jax.named_scope("lm.head"):
+            return self._head_loss(params, x, aux, batch, loss_chunk)
+
+    def _head_loss(self, params, x, aux, batch, loss_chunk: int):
+        """The head and the loss over the final hidden states ``x``."""
+        cfg = self.cfg
         if "labels" in batch:
             labels = batch["labels"]
         else:
@@ -314,10 +321,11 @@ class LM:
                 if st is not None:
                     tail_states[name] = st
             cache["tail"] = tail_states
-        x = B._norm(params["final_norm"], x, cfg.norm_eps)
-        xlast = x[jnp.arange(x.shape[0]), jnp.maximum(lens - 1, 0)]
-        W = self._head_t(params)
-        logits = (xlast @ W.astype(xlast.dtype)).astype(jnp.float32)
+        with jax.named_scope("lm.head"):
+            x = B._norm(params["final_norm"], x, cfg.norm_eps)
+            xlast = x[jnp.arange(x.shape[0]), jnp.maximum(lens - 1, 0)]
+            W = self._head_t(params)
+            logits = (xlast @ W.astype(xlast.dtype)).astype(jnp.float32)
         return logits, cache, lens
 
     def prefill_packed(self, params, batch, max_len: int, ends):
@@ -362,15 +370,16 @@ class LM:
                 if st is not None:
                     tail_states[name] = st
             states["tail"] = tail_states
-        x = B._norm(params["final_norm"], x, cfg.norm_eps)
-        Bsz, L, d = x.shape
-        S = ends.shape[1]
-        idx = jnp.clip(ends, 0, L - 1)[..., None]
-        xe = jnp.take_along_axis(x, jnp.broadcast_to(idx, (Bsz, S, d)),
-                                 axis=1)
-        W = self._head_t(params)
-        logits = (xe @ W.astype(xe.dtype)).astype(jnp.float32)
-        logits = jnp.where((ends >= 0)[..., None], logits, 0.0)
+        with jax.named_scope("lm.head"):
+            x = B._norm(params["final_norm"], x, cfg.norm_eps)
+            Bsz, L, d = x.shape
+            S = ends.shape[1]
+            idx = jnp.clip(ends, 0, L - 1)[..., None]
+            xe = jnp.take_along_axis(
+                x, jnp.broadcast_to(idx, (Bsz, S, d)), axis=1)
+            W = self._head_t(params)
+            logits = (xe @ W.astype(xe.dtype)).astype(jnp.float32)
+            logits = jnp.where((ends >= 0)[..., None], logits, 0.0)
         seg_lens = B._ends_lens(ctx, ends)
         return logits, states, seg_lens
 
@@ -429,10 +438,11 @@ class LM:
                         params["tail"][name], x, cache["tail"][name], ctx,
                         cfg)
             cache = dict(cache, tail=new_tail)
-        x = B._norm(params["final_norm"], x, cfg.norm_eps)
-        nvalid = (batch["segment_ids"] > 0).sum(-1)
-        xlast = x[jnp.arange(x.shape[0]), jnp.maximum(nvalid - 1, 0)]
-        logits = (xlast @ self._head_t(params).astype(xlast.dtype))
+        with jax.named_scope("lm.head"):
+            x = B._norm(params["final_norm"], x, cfg.norm_eps)
+            nvalid = (batch["segment_ids"] > 0).sum(-1)
+            xlast = x[jnp.arange(x.shape[0]), jnp.maximum(nvalid - 1, 0)]
+            logits = (xlast @ self._head_t(params).astype(xlast.dtype))
         return logits.astype(jnp.float32), cache, cache_len + nvalid
 
     def reset_cache_rows(self, cache, fresh):

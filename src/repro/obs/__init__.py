@@ -13,28 +13,36 @@ and at the end of a traced run:
     obs.export("trace.json")         # Chrome trace + metric snapshot
     print(obs.tracer.timeline())     # plain-text per-track view
     print(obs.metrics.prometheus_text())
+    obs.close()                      # stops recording GC passes
+
+A recording ``Obs`` puts the engine's and the Trainer's phases, and each
+GC pass (``host.gc``), into a jax.profiler capture as annotations of the
+same names (obs/trace.py); names on those tracks begin with one of
+``SPAN_PREFIXES``.
 
 Metric names are dotted (``serve.prefills``, ``train.real_tokens``,
 ``data.prefetch_wait_ms``) — the catalogue lives in obs/README.md.
 See obs/metrics.py and obs/trace.py for the pieces; obs/profile.py for
-the optional jax.profiler bridge; obs/check.py for the trace validator
+the ``--profile-dir`` capture; obs/check.py for the trace validator
 that ``make obs-smoke`` runs.
 """
 from __future__ import annotations
 
 import dataclasses
+import gc
 import time
 from typing import Callable, Optional, Union
 
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       percentiles)
-from .trace import NULL_TRACER, NullTracer, Tracer
-from .profile import profile_region, profiler_session, step_region
+from .trace import (NULL_TRACER, PROFILED_TRACKS, SPAN_PREFIXES,
+                    NullTracer, Tracer)
+from .profile import profiler_session
 
 __all__ = [
     "Obs", "MetricsRegistry", "Counter", "Gauge", "Histogram",
     "percentiles", "Tracer", "NullTracer", "NULL_TRACER",
-    "profile_region", "step_region", "profiler_session",
+    "PROFILED_TRACKS", "SPAN_PREFIXES", "profiler_session",
 ]
 
 
@@ -60,7 +68,13 @@ class Obs:
         reg = MetricsRegistry(clock=clock or time.time)
         tr = Tracer(clock=span_clock or clock or time.perf_counter,
                     max_events=max_events)
+        gc.callbacks.append(tr.gc_callback)
         return cls(metrics=reg, tracer=tr)
+
+    def close(self) -> None:
+        """Stop recording GC passes (the hook ``on()`` installed)."""
+        if self.tracer.gc_callback in gc.callbacks:
+            gc.callbacks.remove(self.tracer.gc_callback)
 
     @property
     def enabled(self) -> bool:

@@ -27,6 +27,15 @@ Design points:
     both emit.
   * bounded: past ``max_events`` new events are dropped and counted
     (``dropped``) instead of growing without bound.
+  * on the profiler's clock: every span on a nesting track
+    (``PROFILED_TRACKS``: the engine's phases, the Trainer's, and the
+    ``host`` track's GC passes) is also a ``jax.profiler.TraceAnnotation``
+    of the same name, so a device trace shows which phase the host was
+    in during each device gap. Their names begin with one of
+    ``SPAN_PREFIXES``, which is how a trace reader tells them from JAX's
+    own events. The per-request ``req<rid>`` tracks cross call boundaries
+    and stay host-only, as do ``complete()`` spans (stamped after the
+    fact) and instants.
 """
 from __future__ import annotations
 
@@ -38,6 +47,9 @@ from typing import Callable, Dict, List, Optional
 
 # Chrome trace-event constants
 _B, _E, _I, _META = "B", "E", "i", "M"
+
+PROFILED_TRACKS = frozenset({"engine", "train", "host"})
+SPAN_PREFIXES = ("serve.", "train.", "host.")
 
 
 class _SpanCtx:
@@ -75,11 +87,20 @@ class Tracer:
         self.process_name = process_name
         self.max_events = max_events
         self.dropped = 0
-        self._lock = threading.Lock()
+        # re-entrant: a GC pass may start inside a critical section, and
+        # the GC hook (``gc_callback``) records its span on this tracer
+        self._lock = threading.RLock()
         self._events: List[dict] = []
         self._tracks: Dict[str, int] = {}      # track name -> tid
         self._spans: Dict[int, dict] = {}      # open span id -> B event
         self._next_sid = 0
+        self._annotations: Dict[int, object] = {}  # open span id -> entered
+        self._gc_sid: Optional[int] = None
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:                      # host-only usage
+            TraceAnnotation = None
+        self._annotation = TraceAnnotation
 
     # ------------------------------------------------------------- plumbing
     def _tid(self, track: Optional[str]) -> int:
@@ -102,7 +123,18 @@ class Tracer:
     # ------------------------------------------------------------ recording
     def start(self, name: str, track: Optional[str] = None, **args) -> int:
         """Open a span; returns the id ``finish()`` closes. ``args`` become
-        the Chrome event's ``args`` payload (attributes)."""
+        the Chrome event's ``args`` payload (attributes). On a profiled
+        track the span is also entered as a profiler annotation."""
+        profiled = track in PROFILED_TRACKS
+        if profiled and not name.startswith(SPAN_PREFIXES):
+            raise ValueError(f"span {name!r} on track {track!r} must begin "
+                             f"with one of {SPAN_PREFIXES}")
+        ann = None
+        if profiled and self._annotation is not None:
+            # entered first and left last, so the profiler's span holds
+            # the tracer's own bookkeeping
+            ann = self._annotation(name)
+            ann.__enter__()
         ts = self.clock() * 1e6
         with self._lock:
             sid = self._next_sid
@@ -111,6 +143,8 @@ class Tracer:
                   "tid": self._tid(track), "args": args}
             self._emit(ev)
             self._spans[sid] = ev
+            if ann is not None:
+                self._annotations[sid] = ann
         return sid
 
     def finish(self, sid: Optional[int], **args) -> None:
@@ -120,11 +154,14 @@ class Tracer:
             return
         ts = self.clock() * 1e6
         with self._lock:
+            ann = self._annotations.pop(sid, None)
             b = self._spans.pop(sid, None)
-            if b is None:
-                return
-            self._emit({"ph": _E, "name": b["name"], "ts": max(ts, b["ts"]),
-                        "pid": self.pid, "tid": b["tid"], "args": args})
+            if b is not None:
+                self._emit({"ph": _E, "name": b["name"],
+                            "ts": max(ts, b["ts"]), "pid": self.pid,
+                            "tid": b["tid"], "args": args})
+        if ann is not None:
+            ann.__exit__(None, None, None)
 
     def span(self, name: str, track: Optional[str] = None,
              **args) -> _SpanCtx:
@@ -141,6 +178,17 @@ class Tracer:
                         "pid": self.pid, "tid": tid, "args": args})
             self._emit({"ph": _E, "name": name, "ts": max(t0, t1) * 1e6,
                         "pid": self.pid, "tid": tid, "args": {}})
+
+    def gc_callback(self, phase: str, info: dict) -> None:
+        """A ``gc.callbacks`` hook: each collection pass as a ``host.gc``
+        span on the ``host`` track (attrs ``generation``, ``collected``).
+        Passes never overlap (the interpreter runs one at a time)."""
+        if phase == "start":
+            self._gc_sid = self.start("host.gc", track="host",
+                                      generation=info["generation"])
+        else:
+            self.finish(self._gc_sid, collected=info["collected"])
+            self._gc_sid = None
 
     def instant(self, name: str, track: Optional[str] = None,
                 **args) -> None:
@@ -248,6 +296,9 @@ class NullTracer(Tracer):
         pass
 
     def instant(self, name, track=None, **args):
+        pass
+
+    def gc_callback(self, phase, info):
         pass
 
     def sync(self, x):

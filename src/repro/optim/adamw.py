@@ -85,6 +85,7 @@ class AdamW:
         return AdamWState(step=jnp.zeros((), jnp.int32),
                           m=zeros(params), v=zeros(params), master=master)
 
+    @jax.named_scope("optim.adamw")
     def update(self, grads, state: AdamWState, params
                ) -> Tuple[Any, AdamWState, Dict[str, jnp.ndarray]]:
         c = self.cfg

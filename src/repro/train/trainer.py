@@ -190,11 +190,6 @@ class Trainer:
             with tr.span("train.data", track="train", step=step):
                 batch = self.loader.batch(step)
             t1 = time.perf_counter()
-            # meter from the batch itself, not metrics["tokens"]: a loss fn
-            # that omits the metric must not silently report 0 tok/s
-            seg = batch.get("segment_ids")
-            real = int((seg > 0).sum()) if seg is not None \
-                else int(batch["tokens"].size)
             # first occurrence of a batch token-shape = jit compile on this
             # call (first-call timing shows up as an outsized train.step)
             shape = tuple(batch["tokens"].shape)
@@ -204,47 +199,58 @@ class Trainer:
                 self._c_compiles.inc()
             sid = tr.start("train.step", track="train", step=step,
                            compile=compiled)
+            # meter from the batch itself, not metrics["tokens"]: a loss fn
+            # that omits the metric must not silently report 0 tok/s
+            seg = batch.get("segment_ids")
+            real = int((seg > 0).sum()) if seg is not None \
+                else int(batch["tokens"].size)
             state, metrics = self.step_fn(state, self._place_batch(batch))
             # sync so the span covers device time, not dispatch time — a
             # no-op on the disabled tracer (no extra syncs when off)
             tr.sync(metrics["loss"])
             tr.finish(sid)
             t2 = time.perf_counter()
-            self._c_steps.inc()
-            self._c_real.inc(real)
-            self._c_buf.inc(int(batch["tokens"].size))
-            self._g_data.add((t1 - t0) * 1e3)
-            self._g_step.add((t2 - t1) * 1e3)
-            if verbose and (step + 1) % self.cfg.log_every == 0:
-                jax.block_until_ready(metrics["loss"])
-                self._g_loss.set(float(metrics["loss"]))
-                dt = time.perf_counter() - t_last
-                real_since = self._c_real.value - real_mark
-                buffer_since = self._c_buf.value - buf_mark
-                real_tput = real_since / max(dt, 1e-9)
-                buf_tput = buffer_since / max(dt, 1e-9)
-                print(f"step {step + 1:5d} loss {float(metrics['loss']):.4f} "
-                      f"gnorm {float(metrics['grad_norm']):.3f} "
-                      f"tok/s {real_tput:,.0f} "
-                      f"(buffer {buf_tput:,.0f}, "
-                      f"{real_since / max(buffer_since, 1):.0%} real)")
-                t_last = time.perf_counter()
-                real_mark = self._c_real.value
-                buf_mark = self._c_buf.value
-            row = {k: float(v) for k, v in metrics.items()
-                   if jnp.ndim(v) == 0}
-            row["real_tokens"] = float(real)
-            row["buffer_tokens"] = float(batch["tokens"].size)
-            history.append(row)
-            if self.ckpt and self.cfg.ckpt_every and \
-                    (step + 1) % self.cfg.ckpt_every == 0:
-                self.ckpt.save(step + 1, state, meta={"step": step + 1})
-            if self._interrupted:
-                if self.ckpt:        # emergency checkpoint on SIGTERM
-                    self.ckpt.save(step + 1, state,
-                                   meta={"step": step + 1,
-                                         "emergency": True}, blocking=True)
-                break
+            # the host's own work after each step: counters, the log
+            # line, the history row (which reads the metrics back from
+            # the device) and the checkpoint test
+            with tr.span("train.host", track="train", step=step):
+                self._c_steps.inc()
+                self._c_real.inc(real)
+                self._c_buf.inc(int(batch["tokens"].size))
+                self._g_data.add((t1 - t0) * 1e3)
+                self._g_step.add((t2 - t1) * 1e3)
+                if verbose and (step + 1) % self.cfg.log_every == 0:
+                    jax.block_until_ready(metrics["loss"])
+                    self._g_loss.set(float(metrics["loss"]))
+                    dt = time.perf_counter() - t_last
+                    real_since = self._c_real.value - real_mark
+                    buffer_since = self._c_buf.value - buf_mark
+                    real_tput = real_since / max(dt, 1e-9)
+                    buf_tput = buffer_since / max(dt, 1e-9)
+                    print(f"step {step + 1:5d} "
+                          f"loss {float(metrics['loss']):.4f} "
+                          f"gnorm {float(metrics['grad_norm']):.3f} "
+                          f"tok/s {real_tput:,.0f} "
+                          f"(buffer {buf_tput:,.0f}, "
+                          f"{real_since / max(buffer_since, 1):.0%} real)")
+                    t_last = time.perf_counter()
+                    real_mark = self._c_real.value
+                    buf_mark = self._c_buf.value
+                row = {k: float(v) for k, v in metrics.items()
+                       if jnp.ndim(v) == 0}
+                row["real_tokens"] = float(real)
+                row["buffer_tokens"] = float(batch["tokens"].size)
+                history.append(row)
+                if self.ckpt and self.cfg.ckpt_every and \
+                        (step + 1) % self.cfg.ckpt_every == 0:
+                    self.ckpt.save(step + 1, state, meta={"step": step + 1})
+                if self._interrupted:
+                    if self.ckpt:        # emergency checkpoint on SIGTERM
+                        self.ckpt.save(step + 1, state,
+                                       meta={"step": step + 1,
+                                             "emergency": True},
+                                       blocking=True)
+                    break
         if self.ckpt:
             self.ckpt.wait()
         return state, history

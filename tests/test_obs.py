@@ -207,7 +207,7 @@ def test_tracer_bounded_events():
 def test_chrome_export_schema_via_checker(tmp_path):
     obs = Obs.on(clock=_scripted_clock())
     with obs.tracer.span("serve.step", track="engine"):
-        with obs.tracer.span("decode_step", track="engine", step=0):
+        with obs.tracer.span("serve.decode_step", track="engine", step=0):
             obs.metrics.counter("serve.decode_steps").inc()
     obs.tracer.instant("shed", track="engine")
     path = tmp_path / "trace.json"
@@ -219,7 +219,7 @@ def test_chrome_export_schema_via_checker(tmp_path):
     # the checker flags real damage: drop an E and it reports imbalance
     doc["traceEvents"] = [e for e in doc["traceEvents"]
                           if not (e["ph"] == "E"
-                                  and e["name"] == "decode_step")]
+                                  and e["name"] == "serve.decode_step")]
     bad = tmp_path / "bad.json"
     bad.write_text(json.dumps(doc))
     errs = check_trace(str(bad))
@@ -362,3 +362,133 @@ def test_trainer_metering_through_registry():
     assert len(spans) == 3
     assert spans[0]["args"]["compile"] is True
     assert spans[1]["args"]["compile"] is False
+
+
+# ----------------------------------- profiler annotations, GC and scopes
+
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: logs enter and exit."""
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    import jax.profiler
+    _FakeAnnotation.log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _FakeAnnotation)
+    return _FakeAnnotation.log
+
+
+def test_recording_tracer_annotates_profiled_tracks(annotations):
+    tr = Tracer(clock=_scripted_clock())
+    with tr.span("serve.step", track="engine"):
+        sid = tr.start("serve.decode_step", track="engine")
+        tr.finish(sid)
+    with tr.span("train.host", track="train"):
+        pass
+    tr.gc_callback("start", {"generation": 0})
+    tr.gc_callback("stop", {"generation": 0, "collected": 3})
+    # host-only: per-request tracks, other tracks, stamped spans, instants
+    with tr.span("queued", track="req0"):
+        pass
+    with tr.span("tune.sweep", track="tune"):
+        pass
+    tr.complete("serve.late", 0.0, 1.0, track="engine")
+    tr.instant("cache_insert", track="engine")
+    assert annotations == [
+        ("enter", "serve.step"), ("enter", "serve.decode_step"),
+        ("exit", "serve.decode_step"), ("exit", "serve.step"),
+        ("enter", "train.host"), ("exit", "train.host"),
+        ("enter", "host.gc"), ("exit", "host.gc")]
+    gc_b, gc_e = [e for e in tr.chrome_events() if e["name"] == "host.gc"]
+    assert gc_b["args"] == {"generation": 0}
+    assert gc_e["args"] == {"collected": 3}
+
+
+def test_profiled_span_names_carry_a_program_prefix(annotations):
+    from repro.obs import PROFILED_TRACKS, SPAN_PREFIXES
+    assert PROFILED_TRACKS == {"engine", "train", "host"}
+    tr = Tracer(clock=_scripted_clock())
+    for track in sorted(PROFILED_TRACKS):
+        with pytest.raises(ValueError, match="must begin"):
+            tr.start("decode_step", track=track)
+    assert all(p.endswith(".") for p in SPAN_PREFIXES)
+    assert annotations == []
+
+
+def test_null_tracer_enters_no_annotation(annotations):
+    with NULL_TRACER.span("serve.step", track="engine"):
+        NULL_TRACER.finish(NULL_TRACER.start("train.step", track="train"))
+    NULL_TRACER.gc_callback("start", {"generation": 2})
+    assert annotations == []
+
+
+def test_gc_hook_records_only_until_closed():
+    import gc
+    before = list(gc.callbacks)
+    off = Obs.off()
+    assert gc.callbacks == before                    # nothing recording
+    obs = Obs.on()
+    gc.collect()
+    passes = [e for e in obs.tracer.chrome_events() if e["name"] ==
+              "host.gc"]
+    assert any(e["ph"] == "B" and e["args"]["generation"] == 2
+               for e in passes)
+    assert all("collected" in e["args"] for e in passes if e["ph"] == "E")
+    obs.close()
+    assert gc.callbacks == before
+    n = len(obs.tracer.chrome_events())
+    gc.collect()
+    assert len(obs.tracer.chrome_events()) == n
+    off.close()                                      # a no-op when off
+
+
+SCOPES = ("mamba.norm", "mamba.in_proj", "mamba.conv", "mamba.x_proj",
+          "mamba.scan", "mamba.out_proj", "lm.embed", "lm.head")
+
+
+@pytest.fixture(scope="module")
+def tiny_mamba():
+    import jax
+    from repro.configs.base import get_config
+    from repro.models.lm import build_model
+    model = build_model(get_config("mamba-110m").reduced())
+    return model, model.init(jax.random.PRNGKey(0))
+
+
+def test_train_step_and_prefill_carry_named_scopes(tiny_mamba):
+    """Each scope reaches the compiled HLO's op metadata, which is what a
+    device trace's ops are named by; the packed prefill jits under its own
+    name."""
+    import jax
+    import jax.numpy as jnp
+    from repro.launch.serve import ServeEngine
+    from repro.optim.adamw import AdamW, constant_schedule
+    from repro.train.trainer import make_train_step
+
+    model, params = tiny_mamba
+    opt = AdamW(constant_schedule(1e-3))
+    batch = {k: jnp.ones((2, 64), jnp.int32)
+             for k in ("tokens", "positions", "segment_ids")}
+    state = {"params": params, "opt": opt.init(params)}
+    train = jax.jit(make_train_step(model, opt)).lower(state, batch)
+    text = train.compile().as_text()
+    for scope in SCOPES + ("optim.adamw",):
+        assert f"/{scope}/" in text or f"({scope})" in text, scope
+    eng = ServeEngine(model, params, num_slots=2, max_len=128,
+                      buckets=(64,))
+    ends = jnp.full((eng.prefill_rows, eng.max_segments), -1, jnp.int32)
+    pre = eng._prefill.lower(params, batch, ends=ends)
+    text = pre.compile().as_text()
+    assert text.startswith("HloModule jit_prefill_packed")
+    for scope in SCOPES:
+        assert f"/{scope}/" in text or f"({scope})" in text, scope
