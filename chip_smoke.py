@@ -10,17 +10,19 @@ vocab 50280), with random weights and a synthetic corpus made from seeds:
            sequential oracles of kernels/ref.py, outputs and gradients, on
            a packed slab with resets;
   train    five packed steps at seq_len 4096 in bf16 through Trainer and
-           make_train_step, once on the XLA scan and once on the Pallas
-           kernels, on the same seeded batches; the step-1 losses agree;
+           make_train_step, once on the XLA scan and conv
+           (use_pallas=False) and once on the Pallas kernels
+           (use_pallas=True), on the same seeded batches; the step-1
+           losses agree;
   serve    a ServeEngine answers 16 requests with several packed prefills
            and mid-flight refills; every request gets its whole token
            budget, and two requests' greedy tokens equal a per-request
            model.prefill + decode_step loop.
 
 --chips 4 runs only the packed train step on a ("data", "model") = (2, 2)
-mesh (params and optimizer state sharded by distributed/sharding.py) and
-the one-chip step on the same global batch; their losses agree and the
-sharded program holds collectives.
+mesh (params and optimizer state sharded by distributed/sharding.py; the
+default scan kernel runs per shard) and the one-chip step on the same
+global batch; their losses agree and the sharded program holds collectives.
 
 The readings printed on the way (compile seconds, warm step time, tokens
 per step, peak device bytes) are smoke readings of one short run, not
@@ -252,7 +254,8 @@ def choose_rows(cfg, dev):
 
 
 def phase_train(cfg, dev):
-    rows, trainer, compiled = choose_rows(cfg, dev)
+    rows, trainer, compiled = choose_rows(
+        dataclasses.replace(cfg, use_pallas=False), dev)
     xla = run_train(f"train xla rows={rows}", trainer, compiled, rows, dev)
     del trainer, compiled
     pcfg = dataclasses.replace(cfg, use_pallas=True)

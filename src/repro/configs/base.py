@@ -72,20 +72,30 @@ class ArchConfig:
     # execution
     dtype: str = "bfloat16"           # activation/compute dtype
     param_dtype: str = "float32"
-    use_pallas: bool = False          # Pallas kernels for conv + scan
+    use_pallas: Optional[bool] = None  # None: the Mamba-1 train/forward
+                                      # scan runs the Pallas kernel where
+                                      # the default backend is a TPU and
+                                      # the XLA evaluator elsewhere; conv,
+                                      # Mamba-2 heads and the state-
+                                      # collecting prefills stay on XLA.
+                                      # True: Pallas conv + scan everywhere
                                       # (Mosaic on a TPU, interpreted on
-                                      # the CPU; the XLA scan otherwise)
-    pallas_schedule: str = "blocked"  # step | blocked (Pallas scan kernel;
-                                      # blocked = SSD-style subtile matmuls,
-                                      # step = per-step reference walk)
+                                      # the CPU). False: XLA everywhere.
+    pallas_schedule: str = "step"     # step | blocked (Pallas scan kernel;
+                                      # step = per-step walk, 2.2x faster
+                                      # fwd+bwd on a v5e; blocked = SSD-
+                                      # style subtile contraction)
     scan_chunk: int = 256             # chunk length for XLA-path scans
     scan_impl: str = "blocked"        # blocked | chunked | fused_seq (XLA
                                       # ssm path; blocked = SSD-style
                                       # block-parallel schedule, the default
                                       # hot path — see core/scan.py)
-    scan_intra: Optional[str] = None  # blocked in-chunk evaluator: None =
-                                      # auto (mamba1: matmul on TPU, assoc
-                                      # on CPU; mamba2: quad). Force
+    scan_intra: Optional[str] = None  # XLA blocked in-chunk evaluator:
+                                      # None = auto (mamba1: matmul on TPU
+                                      # — which, with use_pallas unset,
+                                      # serves only the state-collecting
+                                      # prefills there — assoc on CPU;
+                                      # mamba2: quad). Force
                                       # "matmul" | "assoc" (mamba1) or
                                       # "quad" | "dual" (mamba2; dual = the
                                       # C·Bᵀ attention-like form, wins when

@@ -282,12 +282,18 @@ def _blocked_ssm(u, delta, A, B, C, D, positions, h0, cdt,
 
     ``intra`` selects how the in-chunk operator is evaluated:
       * ``"matmul"`` — build M explicitly and contract h = M @ b as an
-        einsum: T× the FLOPs of the recurrence but matmul-shaped, so the
-        MXU absorbs them while the carry chain shrinks by T. The form the
-        Pallas ``blocked`` kernel implements; default when running on TPU.
-        Peak per-chunk intermediate is the (B, T, T, D, N) masked decay
-        (s_i − s_j ≤ 0 for unmasked pairs since A < 0, Δ ≥ 0; masked pairs
-        are clamped before the exp, so no overflow anywhere).
+        einsum. With Mamba-1's per-(channel, state) decay M differs for
+        every (b, d, n), so the einsum is a batched matrix–vector product:
+        no MXU shape, and the VPU/EUP pays T exponentials and T
+        multiply-adds per state element where the recurrence pays one of
+        each — the carry chain shrinks by T, the work grows by T. The form
+        the Pallas ``blocked`` kernel implements; default when running on
+        TPU, where the train step's scan now runs the Pallas ``step``
+        kernel instead (kernels/ops.py, 'auto') and this form serves only
+        the state-collecting prefills. Peak per-chunk intermediate is the
+        (B, T, T, D, N) masked decay (s_i − s_j ≤ 0 for unmasked pairs
+        since A < 0, Δ ≥ 0; masked pairs are clamped before the exp, so
+        no overflow anywhere).
       * ``"assoc"`` — evaluate the same masked operator with an in-chunk
         associative tree (log₂T passes of elementwise combines). No matrix
         units to feed on CPU, so this is the default there; it keeps the

@@ -57,6 +57,11 @@ def batch_axis(mesh: Mesh, batch_size: int):
     return None
 
 
+def model_axis(mesh: Mesh, dim: int):
+    """"model" where the mesh has it and it divides ``dim``, else None."""
+    return _fit(mesh, dim, "model")
+
+
 # ---------------------------------------------------------------------------
 # parameter rules — keyed by leaf name (last path component)
 # ---------------------------------------------------------------------------

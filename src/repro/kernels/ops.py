@@ -5,7 +5,11 @@ layout transposes, custom_vjp wiring, and backend dispatch.
 repro.core (SPMD-partitionable and visible to cost_analysis);
 ``backend='pallas'`` routes to the TPU kernels — compiled by Mosaic on a
 TPU, run under the Pallas interpreter on the CPU (kernels/__init__.py
-decides from the platform the call is lowered for).
+decides from the platform the call is lowered for). The Mamba-1 scan also
+takes ``backend='auto'``: the kernel where the default backend is a TPU,
+the XLA evaluator everywhere else. A Pallas call is not SPMD-partitioned,
+so under an abstract mesh of more than one device the scan kernel runs per
+shard inside ``shard_map``.
 
 Structure note: the custom_vjp is defined over *already padded, fully
 normalized* operands (no Nones, tile-multiple shapes); the public wrappers
@@ -20,9 +24,11 @@ from typing import Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import conv as core_conv
 from repro.core import ssm as core_ssm
+from repro.distributed import sharding as shd
 from repro.kernels import conv1d_pack as conv_k
 from repro.kernels import selective_scan as scan_k
 
@@ -108,17 +114,23 @@ def selective_scan(u, delta, A, B, C, D=None, positions=None, *,
                    backend: str = "xla", block_d: int = scan_k.DEF_BLOCK_D,
                    chunk: int = scan_k.DEF_CHUNK_T, xla_chunk: int = 256,
                    xla_method: str = "blocked", xla_dtype=None,
-                   xla_intra=None, schedule: str = "blocked",
+                   xla_intra=None, schedule: str = "step",
                    sub_t=None, tune=None, tune_objective: str = "fwd"):
     """Fused segmented selective scan. See kernels/ref.py for semantics.
 
     u, delta: (B, L, Dm) | A: (Dm, N) | B, C: (B, L, N) | D: (Dm,) |
     positions: (B, L) i32 (reset where == 0) → y (B, L, Dm).
 
-    ``schedule`` (pallas backend): 'blocked' (SSD-style subtile contraction,
-    the default hot path; ``sub_t`` overrides its subtile) | 'step'
-    (per-step reference walk). Both wire the same custom_vjp;
-    ``xla_method='blocked'`` (+ optional ``xla_intra``) is the XLA twin.
+    ``backend``: 'xla' | 'pallas' | 'auto' (the kernel where the default
+    backend is a TPU, the XLA evaluator elsewhere — chosen while tracing,
+    as core/ssm.py picks its in-chunk evaluator, so a CPU trace holds only
+    the XLA evaluator).
+
+    ``schedule`` (pallas backend): 'step' (per-step walk, the measured
+    default — kernels/selective_scan.py) | 'blocked' (SSD-style subtile
+    contraction; ``sub_t`` overrides its subtile).
+    Both wire the same custom_vjp; ``xla_method='blocked'`` (+ optional
+    ``xla_intra``) is the XLA twin.
 
     ``tune``: None (off) | "auto" | cache path | TuneCache — resolve every
     knob above (backend included) from the shape-keyed tuning cache; the
@@ -139,6 +151,8 @@ def selective_scan(u, delta, A, B, C, D=None, positions=None, *,
                 xla_method = kn.get("method", xla_method)
                 xla_chunk = kn.get("chunk", xla_chunk)
                 xla_intra = kn.get("intra", xla_intra)
+    if backend == "auto":
+        backend = "pallas" if jax.default_backend() == "tpu" else "xla"
     if backend == "xla":
         return core_ssm.selective_scan(u, delta, A, B, C, D,
                                        positions=positions,
@@ -147,6 +161,37 @@ def selective_scan(u, delta, A, B, C, D=None, positions=None, *,
                                        intra=xla_intra)
     if backend != "pallas":
         raise ValueError(f"unknown backend {backend!r}")
+    return _scan_pallas_on_mesh(u, delta, A, B, C, D, positions,
+                                block_d=block_d, chunk=chunk,
+                                schedule=schedule, sub_t=sub_t)
+
+
+def _scan_pallas_on_mesh(u, delta, A, B, C, D, positions, **kw):
+    """The scan kernel on the abstract mesh the call is traced under. With
+    more than one device it runs per shard — rows over the data axes,
+    channels over "model", each where it divides: both are independent in
+    the scan, and B, C are shared by every channel (their cotangents are
+    summed over the model axis by ``shard_map``'s transpose)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return _scan_pallas(u, delta, A, B, C, D, positions, **kw)
+    Bz, L, Dm = u.shape
+    rows, ch = shd.batch_axis(mesh, Bz), shd.model_axis(mesh, Dm)
+    if positions is None:
+        positions = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (Bz, L))
+    if D is None:
+        D = jnp.zeros(Dm, u.dtype)
+    x, bc = P(rows, None, ch), P(rows, None, None)
+    return jax.shard_map(
+        functools.partial(_scan_pallas, **kw), mesh=mesh,
+        in_specs=(x, x, P(ch, None), bc, bc, P(ch), P(rows, None)),
+        out_specs=x, check_vma=False)(u, delta, A, B, C, D, positions)
+
+
+def _scan_pallas(u, delta, A, B, C, D, positions, *, block_d, chunk,
+                 schedule, sub_t):
+    """The scan kernel on one device's operands: pad to whole tiles, run
+    the custom_vjp, slice the padding off."""
     Bz, L, Dm = u.shape
     bd = min(block_d, max(Dm, 8))
     T = _chunk(chunk, L)

@@ -400,6 +400,18 @@ def _tune_kw(cfg: ArchConfig):
     return {"tune": _cfg_tune(cfg), "tune_objective": cfg.tune_objective}
 
 
+def _scan_backend(cfg: ArchConfig, A):
+    """Backend of a block's sequence scan. ``use_pallas`` True or False
+    stands as given. Unset (None), the decay's form decides: a per-channel
+    decay (A (D, N), Mamba-1) at float32 state, the kernel's precision,
+    runs 'auto' — the Pallas kernel where the default backend is a TPU,
+    the XLA evaluator elsewhere — and scalar-decay heads (A (H,)) keep the
+    XLA evaluator, whose blocked form is matmul-shaped."""
+    if cfg.use_pallas is not None:
+        return "pallas" if cfg.use_pallas else "xla"
+    return "auto" if A.ndim == 2 and cfg.scan_dtype == "float32" else "xla"
+
+
 def _mamba_in(p, x, cfg: ArchConfig):
     """The block's input: its norm, then ``in_proj`` split into the scan
     branch and the gate."""
@@ -477,7 +489,8 @@ def apply_mamba(p, x, ctx: Ctx, cfg: ArchConfig, collect: int = 0,
     with jax.named_scope("mamba.scan"):
         A = -jnp.exp(p["A_log"])
         y = kops.selective_scan(x_c, delta, A, Bm, Cm, p["D"],
-                                positions=ctx.positions, backend=backend,
+                                positions=ctx.positions,
+                                backend=_scan_backend(cfg, A),
                                 xla_chunk=cfg.scan_chunk,
                                 xla_method=cfg.scan_impl,
                                 xla_dtype=(None
@@ -610,7 +623,8 @@ def apply_mamba2(p, x, ctx: Ctx, cfg: ArchConfig, collect: int = 0,
         y = _mamba2_gate_out(p, y.reshape(B, L, di), z, cfg)
         return x + y @ p["out_proj"].astype(x.dtype), state
     y = kops.selective_scan_heads(u_h, delta, A, Bm, Cm, p["D"],
-                                  positions=ctx.positions, backend=backend,
+                                  positions=ctx.positions,
+                                  backend=_scan_backend(cfg, A),
                                   xla_chunk=cfg.scan_chunk,
                                   xla_dtype=(None
                                              if cfg.scan_dtype == "float32"

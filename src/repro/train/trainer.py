@@ -6,7 +6,9 @@ Distribution notes (the 1000+-node posture, exercised by the dry-run):
     distributed/sharding.py — batch over ("pod","data"), params FSDP×TP.
     ``Trainer(mesh=...)`` does this on a live mesh: the state is created
     sharded, each batch is placed by its sharding before the step, and the
-    step's outputs keep the state's shardings.
+    step's outputs keep the state's shardings. The step is traced with the
+    mesh as its abstract mesh, so code that cannot be SPMD-partitioned (the
+    Pallas scan, kernels/ops.py) runs per shard.
   * gradient accumulation runs as a lax.scan over microbatches with an f32
     (or bf16 — ``grad_accum_dtype``, the memory-compression knob) carried
     accumulator; XLA overlaps the per-microbatch reduce-scatter with the
@@ -89,6 +91,14 @@ def make_train_step(model, opt: AdamW, accum: int = 1,
     return step_fn
 
 
+def _traced_on(mesh, fn: Callable) -> Callable:
+    """``fn`` traced with ``mesh`` as the abstract mesh."""
+    def traced(*args):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return fn(*args)
+    return traced
+
+
 class Trainer:
     def __init__(self, model, opt: AdamW, loader, cfg: TrainerConfig,
                  step_fn: Optional[Callable] = None, jit: bool = True,
@@ -109,8 +119,9 @@ class Trainer:
                 mesh, shd.train_state_pspecs(state_shape, mesh))
             self._init = jax.jit(self.init_state,
                                  out_shardings=self.state_sharding)
-            fn = jax.jit(fn, donate_argnums=(0,), out_shardings=(
-                self.state_sharding, NamedSharding(mesh, P())))
+            fn = jax.jit(_traced_on(mesh, fn), donate_argnums=(0,),
+                         out_shardings=(self.state_sharding,
+                                        NamedSharding(mesh, P())))
         elif jit:
             fn = jax.jit(fn, donate_argnums=(0,))
         self.step_fn = fn

@@ -5,7 +5,8 @@ Covers the acceptance surface of the block-parallel schedule:
     resets, chunk not dividing L, segments straddling chunk boundaries
   * ``core.ssm.selective_scan(method='blocked')`` fwd + grads, both
     in-chunk evaluators ('matmul' einsum and 'assoc' tree), h0 carry
-  * the Pallas ``schedule='blocked'`` kernels (interpret mode) fwd + grads
+  * the Pallas ``schedule='step'`` and ``'blocked'`` kernels (interpret
+    mode) fwd + grads, resets on a chunk's first row included
   * structural memory claim: no (B, L, D, N) intermediate in the jaxpr
 """
 import numpy as np
@@ -191,31 +192,55 @@ def test_pallas_schedules_fwd(rng, schedule, Bz, L, Dm, N):
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=1e-4)
 
 
-def test_pallas_blocked_grads(rng):
-    Bz, L, Dm, N = 2, 24, 10, 4
-    u, dt, A, Bm, Cm, Dk, pos = _ssm_inputs(rng, Bz, L, Dm, N)
+def _pallas_grads_match_ref(args, pos, schedule, **kw):
+    """Gradients of Σ y² through the Pallas custom_vjp against kernels/ref.py
+    (the naive f32 recurrence), for u, Δ, A, B, C, D."""
+    def lp(*a):
+        return (kops_scan(*a, pos, backend="pallas", schedule=schedule,
+                          **kw) ** 2).sum()
 
-    def lp(*args):
-        return (kops_scan(*args, pos, backend="pallas", block_d=8, chunk=8,
-                          schedule="blocked") ** 2).sum()
+    def lr(*a):
+        return (selective_scan_ref(*a, pos) ** 2).sum()
 
-    def lr(*args):
-        return (selective_scan_ref(*args, pos) ** 2).sum()
-
-    gp = jax.grad(lp, argnums=tuple(range(6)))(u, dt, A, Bm, Cm, Dk)
-    gr = jax.grad(lr, argnums=tuple(range(6)))(u, dt, A, Bm, Cm, Dk)
+    gp = jax.grad(lp, argnums=tuple(range(6)))(*args)
+    gr = jax.grad(lr, argnums=tuple(range(6)))(*args)
     for name, a, b in zip("u dt A B C D".split(), gp, gr):
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-3,
                                    err_msg=f"grad {name}")
 
 
-def test_pallas_blocked_reset_blocks_grad(rng):
+@pytest.mark.parametrize("schedule", ["step", "blocked"])
+def test_pallas_blocked_grads(rng, schedule):
+    Bz, L, Dm, N = 2, 24, 10, 4
+    u, dt, A, Bm, Cm, Dk, pos = _ssm_inputs(rng, Bz, L, Dm, N)
+    _pallas_grads_match_ref((u, dt, A, Bm, Cm, Dk), pos, schedule,
+                            block_d=8, chunk=8)
+
+
+@pytest.mark.parametrize("schedule", ["step", "blocked"])
+def test_pallas_grads_document_across_chunks(rng, schedule):
+    """Documents cross the chunk boundaries at 16 and 32, and resets fall
+    on the first row of the chunks at 16 (row 1) and 48 (row 0): the
+    backward's adjoint carry must pass the first and stop at the others."""
+    Bz, L, Dm, N = 2, 64, 8, 4
+    u, dt, A, Bm, Cm, Dk, _ = _ssm_inputs(rng, Bz, L, Dm, N)
+    starts = ([0, 5, 48], [0, 16, 40])
+    pos = np.zeros((Bz, L), np.int32)
+    for b, cuts in enumerate(starts):
+        for s0, s1 in zip(cuts, cuts[1:] + [L]):
+            pos[b, s0:s1] = np.arange(s1 - s0)
+    _pallas_grads_match_ref((u, dt, A, Bm, Cm, Dk), jnp.asarray(pos),
+                            schedule, block_d=8, chunk=16)
+
+
+@pytest.mark.parametrize("schedule", ["step", "blocked"])
+def test_pallas_blocked_reset_blocks_grad(rng, schedule):
     u, dt, A, Bm, Cm, Dk, _ = _ssm_inputs(rng, 1, 16, 8, 4)
     pos = jnp.concatenate([jnp.arange(8), jnp.arange(8)])[None]
 
     def loss(u_in):
         y = kops_scan(u_in, dt, A, Bm, Cm, Dk, pos, backend="pallas",
-                      block_d=8, chunk=8, schedule="blocked")
+                      block_d=8, chunk=8, schedule=schedule)
         return (y[:, 8:] ** 2).sum()
 
     g = jax.grad(loss)(u)

@@ -176,11 +176,13 @@ print("NUMERIC_OK")
     assert "NUMERIC_OK" in out.stdout, out.stderr[-2000:]
 
 
-def test_trainer_mesh_places_state_and_batch_4dev():
+@pytest.mark.parametrize("use_pallas", [None, True])
+def test_trainer_mesh_places_state_and_batch_4dev(use_pallas):
     """Trainer(mesh=...) creates the state sharded by the param rules, keeps
     those shardings across steps, shards each batch over the data axis, and
-    trains to the same losses as the one-device Trainer."""
-    src = r"""
+    trains to the same losses as the one-device Trainer — with the Pallas
+    kernels too, whose scan then runs per shard (shard_map)."""
+    src = f"USE_PALLAS = {use_pallas!r}\n" + r"""
 import jax, jax.numpy as jnp, numpy as np, dataclasses
 from jax.sharding import PartitionSpec as P
 from repro.configs.base import get_config
@@ -189,7 +191,8 @@ from repro.models.lm import build_model
 from repro.optim.adamw import AdamW, constant_schedule
 from repro.train.trainer import Trainer, TrainerConfig
 
-cfg = dataclasses.replace(get_config("mamba-110m").reduced(), dtype="float32")
+cfg = dataclasses.replace(get_config("mamba-110m").reduced(), dtype="float32",
+                          use_pallas=USE_PALLAS)
 model = build_model(cfg)
 rng = np.random.default_rng(0)
 B, L = 4, 32
@@ -205,19 +208,22 @@ class Loader:
 def run(mesh):
     tr = Trainer(model, AdamW(constant_schedule(1e-3)), Loader(),
                  TrainerConfig(steps=3, log_every=100), mesh=mesh)
-    seen = []
+    seen, hlo = [], []
     step_fn = tr.step_fn
     def spy(state, batch):
         seen.append(batch["tokens"].sharding)
+        if not hlo:
+            hlo.append(step_fn.lower(state, batch).as_text())
         return step_fn(state, batch)
     tr.step_fn = spy
     state, hist = tr.train(jax.random.PRNGKey(0), verbose=False)
-    return state, [h["loss"] for h in hist], seen
+    return state, [h["loss"] for h in hist], seen, hlo[0]
 
-_, ref, _ = run(None)
+_, ref, _, _ = run(None)
 mesh = make_local_mesh(model_axis=2)
-state, losses, seen = run(mesh)
+state, losses, seen, hlo = run(mesh)
 np.testing.assert_allclose(losses, ref, rtol=1e-5)
+assert ("manual_computation" in hlo) == bool(USE_PALLAS)
 assert all(s.spec == P("data", None) for s in seen), seen
 w = state["params"]["units"]["0_mamba"]["in_proj"]
 assert w.sharding.spec == P(None, "data", "model"), w.sharding
