@@ -39,6 +39,9 @@ class ArchConfig:
     mrope_sections: Optional[Tuple[int, ...]] = None   # vlm only
     encoder_only: bool = False
     tie_embeddings: bool = False
+    embed_scale: bool = False         # input embedding × sqrt(d_model), as
+                                      # the published Gemma-family models
+                                      # do (Mamba does not)
     norm_eps: float = 1e-6
     # MoE
     n_experts: int = 0

@@ -15,5 +15,6 @@ CONFIG = register(ArchConfig(
     vocab=256000,
     act="geglu",
     tie_embeddings=True,
+    embed_scale=True,
     notes="pure full attention ⇒ long_500k cell skipped (quadratic).",
 ))

@@ -1,4 +1,5 @@
-"""mamba-1.4b — paper §4: 48 layers, d_model=2048. Packed seq_len 4096."""
+"""mamba-1.4b — paper §4: 48 layers, d_model=2048. Packed seq_len 4096.
+Head tied to the embedding, as in state-spaces/mamba-1.4b's config.json."""
 from repro.configs.base import ArchConfig, register
 
 CONFIG = register(ArchConfig(
@@ -10,4 +11,5 @@ CONFIG = register(ArchConfig(
     d_ff=0,
     vocab=50280,
     d_state=16, d_conv=4, expand=2,
+    tie_embeddings=True,
 ))

@@ -23,6 +23,7 @@ CONFIG = register(ArchConfig(
     lru_width=2560,
     conv_width=4,
     tie_embeddings=True,
+    embed_scale=True,
     notes="RG-LRU diagonal recurrence gets the paper's Ā→0 segment reset; "
           "local attention gets the block-diagonal segment mask.",
 ))

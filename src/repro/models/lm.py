@@ -151,14 +151,21 @@ class LM:
         if cfg.family == "audio":
             x = batch["frames"].astype(dt) @ params["input_proj"].astype(dt)
             return x
-        x = jnp.take(params["embed"], batch["tokens"], axis=0).astype(dt)
-        if cfg.tie_embeddings:
-            x = x * jnp.asarray(cfg.d_model ** 0.5, dt)
+        x = self._lookup(params, batch["tokens"])
         if cfg.family == "vlm" and "vision_embeds" in batch:
             ve = batch["vision_embeds"].astype(dt)          # (B, Nv, d)
             vp = batch["vision_positions"]                  # (B, Nv) i32
             bidx = jnp.arange(x.shape[0])[:, None]
             x = x.at[bidx, vp].set(ve)
+        return x
+
+    def _lookup(self, params, tokens) -> jnp.ndarray:
+        """Embedding rows of ``tokens`` in the compute dtype, scaled by
+        sqrt(d_model) where the published model does so (``embed_scale``)."""
+        dt = jnp.dtype(self.cfg.dtype)
+        x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
+        if self.cfg.embed_scale:
+            x = x * jnp.asarray(self.cfg.d_model ** 0.5, dt)
         return x
 
     def _ctx(self, batch) -> Ctx:
@@ -528,10 +535,7 @@ class LM:
         """tokens_t (B, 1) [or frames_t (B,1,d) for audio, unused];
         cache_len (B,) cursor. Returns (logits (B, V), new_cache)."""
         cfg = self.cfg
-        dt = jnp.dtype(cfg.dtype)
-        x = jnp.take(params["embed"], tokens_t, axis=0).astype(dt)
-        if cfg.tie_embeddings:
-            x = x * jnp.asarray(cfg.d_model ** 0.5, dt)
+        x = self._lookup(params, tokens_t)
         ctx = Ctx(cache_len=cache_len, reset_t=reset,
                   mrope_positions=mrope_positions)
 

@@ -5,10 +5,11 @@ Distribution notes (the 1000+-node posture, exercised by the dry-run):
   * train_step is built once and jit'ed with in/out shardings from
     distributed/sharding.py — batch over ("pod","data"), params FSDP×TP.
     ``Trainer(mesh=...)`` does this on a live mesh: the state is created
-    sharded, each batch is placed by its sharding before the step, and the
-    step's outputs keep the state's shardings. The step is traced with the
-    mesh as its abstract mesh, so code that cannot be SPMD-partitioned (the
-    Pallas scan, kernels/ops.py) runs per shard.
+    sharded, each batch is placed by its sharding before the step (the
+    ``train.place`` span), and the step's outputs keep the state's
+    shardings. The step is traced with the mesh as its abstract mesh, so
+    code that cannot be SPMD-partitioned (the Pallas scan, kernels/ops.py)
+    runs per shard.
   * gradient accumulation runs as a lax.scan over microbatches with an f32
     (or bf16 — ``grad_accum_dtype``, the memory-compression knob) carried
     accumulator; XLA overlaps the per-microbatch reduce-scatter with the
@@ -158,10 +159,8 @@ class Trainer:
         return {"params": params, "opt": self.opt.init(params)}
 
     def _place_batch(self, batch):
-        """Put a host batch on the mesh, rows over the data axes (a no-op
-        without a mesh: the jitted step transfers it)."""
-        if self.mesh is None:
-            return batch
+        """Put a host batch on the mesh, rows over the data axes (without a
+        mesh the jitted step transfers it)."""
         return jax.device_put(
             batch, shd.named(self.mesh, shd.batch_pspecs(batch, self.mesh)))
 
@@ -215,7 +214,10 @@ class Trainer:
             seg = batch.get("segment_ids")
             real = int((seg > 0).sum()) if seg is not None \
                 else int(batch["tokens"].size)
-            state, metrics = self.step_fn(state, self._place_batch(batch))
+            if self.mesh is not None:
+                with tr.span("train.place", track="train", step=step):
+                    batch = self._place_batch(batch)
+            state, metrics = self.step_fn(state, batch)
             # sync so the span covers device time, not dispatch time — a
             # no-op on the disabled tracer (no extra syncs when off)
             tr.sync(metrics["loss"])

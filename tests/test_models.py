@@ -68,7 +68,7 @@ def test_arch_smoke_forward_and_train_step(arch, rng):
 
 
 @pytest.mark.parametrize("arch", ["stablelm-1.6b", "mamba-110m",
-                                  "mamba2-370m",
+                                  "mamba-1.4b", "gemma-7b", "mamba2-370m",
                                   "recurrentgemma-2b", "xlstm-125m",
                                   "mixtral-8x22b", "qwen2-vl-2b"])
 def test_decode_matches_forward(arch, rng):
@@ -141,12 +141,82 @@ def test_prefill_logits_matches_forward(rng):
     np.testing.assert_allclose(pl, full[:, n - 1], atol=1e-4)
 
 
-def test_tied_embeddings_shape():
-    cfg = get_config("gemma-7b").reduced()
+@pytest.mark.parametrize("arch", ["gemma-7b", "recurrentgemma-2b",
+                                  "mamba-1.4b"])
+def test_tied_embeddings_shape(arch):
+    cfg = get_config(arch).reduced()
+    assert cfg.tie_embeddings
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     assert "head" not in params
     assert params["embed"].shape == (cfg.vocab, cfg.d_model)
+
+
+@pytest.mark.parametrize("arch,scaled", [("gemma-7b", True),
+                                         ("recurrentgemma-2b", True),
+                                         ("mamba-1.4b", False),
+                                         ("mamba-110m", False)])
+def test_embedding_scale_follows_published_model(arch, scaled, rng):
+    """Gemma-family models multiply the input embedding by sqrt(d_model);
+    Mamba, tied or not, takes the table's rows as they are."""
+    cfg = get_config(arch).reduced()
+    assert cfg.embed_scale is scaled
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    tokens = jnp.asarray(rng.integers(1, cfg.vocab, (2, 8)), jnp.int32)
+    x = model._embed(params, {"tokens": tokens})
+    rows = params["embed"][tokens]
+    np.testing.assert_array_equal(
+        x, rows * (cfg.d_model ** 0.5 if scaled else 1.0))
+
+
+def _prefill_packed(model, params, tokens):
+    B, L = tokens.shape
+    batch = {"tokens": tokens, "positions": jnp.tile(jnp.arange(L), (B, 1)),
+             "segment_ids": jnp.ones((B, L), jnp.int32)}
+    ends = jnp.full((B, 1), L - 1, jnp.int32)
+    return model.prefill_packed(params, batch, 16, ends)[0][:, 0]
+
+
+def _prefill_chunk(model, params, tokens):
+    B, L = tokens.shape
+    batch = {"tokens": tokens, "positions": jnp.tile(jnp.arange(L), (B, 1)),
+             "segment_ids": jnp.ones((B, L), jnp.int32)}
+    return model.prefill_chunk(params, model.init_cache(B, 16), batch,
+                               jnp.zeros((B,), jnp.int32))[0]
+
+
+def _decode(model, params, tokens):
+    cache = model.init_cache(tokens.shape[0], 16)
+    for t in range(tokens.shape[1]):
+        logits, cache = model.decode_step(params, cache, tokens[:, t:t + 1],
+                                          jnp.full((tokens.shape[0],), t))
+    return logits
+
+
+def _forward(model, params, tokens):
+    B, L = tokens.shape
+    return model.forward(params, {
+        "tokens": tokens, "positions": jnp.tile(jnp.arange(L), (B, 1)),
+        "segment_ids": jnp.ones((B, L), jnp.int32)})[:, -1]
+
+
+@pytest.mark.parametrize("entry", [_forward, _decode, _prefill_packed,
+                                   _prefill_chunk])
+def test_tied_mamba_is_untied_with_head_copied(entry, rng):
+    """Every path that embeds treats a tied Mamba as the untied model whose
+    head holds the embedding's transpose: no path scales the input."""
+    cfg = get_config("mamba-1.4b").reduced()
+    tied = build_model(cfg)
+    untied = build_model(dataclasses.replace(cfg, tie_embeddings=False))
+    params = tied.init(jax.random.PRNGKey(0))
+    tokens = jnp.asarray(rng.integers(1, cfg.vocab, (2, 6)), jnp.int32)
+    got = entry(tied, params, tokens)
+    want = entry(untied, dict(params, head=params["embed"].T), tokens)
+    # the same float32 arithmetic; only the head operand's layout may
+    # differ (a transposed view against a copy), which can reorder the
+    # head's sums: 1e-6 is that rounding, a scaled input moves it by O(1)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
 
 
 def test_pattern_units():

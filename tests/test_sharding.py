@@ -179,15 +179,17 @@ print("NUMERIC_OK")
 @pytest.mark.parametrize("use_pallas", [None, True])
 def test_trainer_mesh_places_state_and_batch_4dev(use_pallas):
     """Trainer(mesh=...) creates the state sharded by the param rules, keeps
-    those shardings across steps, shards each batch over the data axis, and
-    trains to the same losses as the one-device Trainer — with the Pallas
-    kernels too, whose scan then runs per shard (shard_map)."""
+    those shardings across steps, shards each batch over the data axis
+    inside a ``train.place`` span (absent without a mesh), and trains to
+    the same losses as the one-device Trainer — with the Pallas kernels
+    too, whose scan then runs per shard (shard_map)."""
     src = f"USE_PALLAS = {use_pallas!r}\n" + r"""
 import jax, jax.numpy as jnp, numpy as np, dataclasses
 from jax.sharding import PartitionSpec as P
 from repro.configs.base import get_config
 from repro.launch.mesh import make_local_mesh
 from repro.models.lm import build_model
+from repro.obs import Obs
 from repro.optim.adamw import AdamW, constant_schedule
 from repro.train.trainer import Trainer, TrainerConfig
 
@@ -206,8 +208,9 @@ class Loader:
         return batches[step]
 
 def run(mesh):
+    obs = Obs.on()
     tr = Trainer(model, AdamW(constant_schedule(1e-3)), Loader(),
-                 TrainerConfig(steps=3, log_every=100), mesh=mesh)
+                 TrainerConfig(steps=3, log_every=100), mesh=mesh, obs=obs)
     seen, hlo = [], []
     step_fn = tr.step_fn
     def spy(state, batch):
@@ -217,11 +220,16 @@ def run(mesh):
         return step_fn(state, batch)
     tr.step_fn = spy
     state, hist = tr.train(jax.random.PRNGKey(0), verbose=False)
-    return state, [h["loss"] for h in hist], seen, hlo[0]
+    obs.close()
+    places = [e for e in obs.tracer.chrome_events()
+              if e["name"] == "train.place" and e["ph"] == "B"]
+    return state, [h["loss"] for h in hist], seen, hlo[0], places
 
-_, ref, _, _ = run(None)
+_, ref, _, _, places = run(None)
+assert places == [], places          # nothing to place without a mesh
 mesh = make_local_mesh(model_axis=2)
-state, losses, seen, hlo = run(mesh)
+state, losses, seen, hlo, places = run(mesh)
+assert [e["args"]["step"] for e in places] == [0, 1, 2], places
 np.testing.assert_allclose(losses, ref, rtol=1e-5)
 assert ("manual_computation" in hlo) == bool(USE_PALLAS)
 assert all(s.spec == P("data", None) for s in seen), seen
