@@ -85,8 +85,10 @@ def _scan_bwd_rule(block_d, chunk, schedule, sub_t, res, dy):
         u, delta, At, B, C, Dp, pos, ckpts, dy, block_d=block_d, chunk=chunk,
         schedule=schedule, sub_t=sub_t)
     return (du.astype(u.dtype), ddelta.astype(delta.dtype),
-            dA_p.sum(0).astype(At.dtype), dB_p.sum(1).astype(B.dtype),
-            dC_p.sum(1).astype(C.dtype), dD_p.sum(0).astype(Dp.dtype),
+            dA_p.sum(0).astype(At.dtype),
+            dB_p.sum(1).reshape(B.shape).astype(B.dtype),
+            dC_p.sum(1).reshape(C.shape).astype(C.dtype),
+            dD_p.sum(0).astype(Dp.dtype),
             np.zeros(pos.shape, _F0))
 
 

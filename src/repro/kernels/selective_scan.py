@@ -32,18 +32,22 @@ coalesced loads. The TPU-native reformulation:
     in VMEM scratch.
 
 VMEM per grid step at the defaults (T=256, bd=128, N=16): blocks ≈ 1.5 MiB
-double-buffered; the backward adds the recomputed state trajectory ((T+1, N,
-bd) for ``step``; for ``blocked`` (N, T, bd) state and adjoint trajectories,
-2 MiB each) — inside v5e's 16 MiB scoped default, which ``blocked`` exceeds
-at T=512 or bd=256. ``step`` at bd=256 does not compile.
+double-buffered. The ``step`` backward adds the recomputed states hbuf
+((T+1)·N, bd), 2 MiB, and one row group's stacked (G·N, bd) tiles of
+adjoints, states and products, 256 KiB each at G 32, which Mosaic keeps
+in its own scratch; ``blocked`` adds (N, T, bd) state and adjoint
+trajectories, 2 MiB each. Both fit v5e's 16 MiB scoped default, which
+``blocked`` exceeds at T=512; neither compiles at bd=256.
 
 Schedules (``schedule=`` knob):
   * ``step``    — per-step walk over aligned row groups, one exponential
                   per (t, d, n); matches the paper's ScanOp_pack closely.
-                  The default: on a v5e its forward+backward takes 64 ms
-                  per layer at (8, 4096, 2048, N 16), against 140 ms for
-                  ``blocked`` (115 ms at subtile 8) and 372 ms for the XLA
-                  blocked evaluator.
+                  The default: on a v5e its forward+backward takes 31 ms
+                  per layer at (8, 4096, 2048, N 16), forward 11.1 and
+                  backward 20.0 (52.4 with the per-step backward, when
+                  the pair took 64 ms against 140 ms for ``blocked``,
+                  115 ms at subtile 8, and 372 ms for the XLA blocked
+                  evaluator).
   * ``blocked`` — SSD-style (Gu & Dao duality): each in-chunk subtile of
                   length Tt is evaluated at once, per state row n, as the
                   masked cumulative-decay contraction over (Tt, Tt, bd)
@@ -81,8 +85,9 @@ from repro.kernels import pallas_call
 
 DEF_BLOCK_D = 128
 DEF_CHUNK_T = 256      # on a TPU v5e the `step` schedule's forward+backward
-#   at (8, 4096, 2048, N 16) reads 64.7 / 64.4 / 64.1 / 64.0 ms at chunks
-#   128 / 256 / 512 / 1024, and `blocked` fits in VMEM only up to 256
+#   at (8, 4096, 2048, N 16) read 64.7 / 64.4 / 64.1 / 64.0 ms at chunks
+#   128 / 256 / 512 / 1024 with the per-step backward (not measured since),
+#   and `blocked` fits in VMEM only up to 256
 DEF_SUB_T = 16     # blocked schedule: in-chunk subtile for the M contraction
 #   (the *default* — every kernel entry takes an explicit ``sub_t`` so the
 #   shape-keyed autotuner (repro/tune) can sweep measured subtiles instead)
@@ -318,25 +323,70 @@ def selective_scan_fwd_pallas(u, delta, At, Bm, Cm, Dp, positions,
 # Mamba-1 backward kernels — reverse L-grid walk, per-chunk recompute
 # ---------------------------------------------------------------------------
 
+def _spread(x, N: int):
+    """(G, w) rows → (G·N, w), each row repeated N times: a per-time-step
+    value beside the N state rows of that step in a stacked (G·N, bd) tile."""
+    G, w = x.shape
+    return jnp.concatenate(
+        [jnp.broadcast_to(x[i:i + 1], (N, w)) for i in range(G)], axis=0)
+
+
+def _seg_sum(x, N: int):
+    """(G·N, w) → (G, w): the sum over each step's N state rows."""
+    return jnp.sum(x.reshape(x.shape[0] // N, N, x.shape[1]), axis=1)
+
+
+def _lane_sums(x):
+    """(R, bd) → (1, R): the sum over channels of each row, laid out along
+    lanes. One transpose on the XLU, then plain adds over sublanes, in
+    place of R cross-lane reductions."""
+    return jnp.sum(x.T, axis=0, keepdims=True)
+
+
+def _lane_sums_mxu(x):
+    """``_lane_sums`` on the MXU: a ones matmul at f32 (``HIGHEST``), one
+    8-row sublane tile of ones of which the first row is kept."""
+    return _dot(jnp.ones((8, x.shape[1]), f32), x, _NT)[0:1]
+
+
+def _bwd_group(T: int, nL: int) -> int:
+    """Rows per group of the `step` backward: the most, up to 32, that
+    divide the chunk and keep its lane-dense (T/G, G·N) dB, dC block in
+    whole 8-row tiles (or the chunk is the whole sequence). Larger groups
+    give the scheduler more of the bulk work to fill the walk's chain with:
+    on a v5e the backward read 48.2 / 28.9 / 20.6 ms at G 8 / 16 / 32 at
+    (8, 4096, 2048), with both channel sums on the XLU."""
+    for G in (32, 16, 8, 4, 2, 1):
+        if T % G == 0 and (nL == 1 or (T // G) % 8 == 0):
+            return G
+
+
 def _bwd_kernel(pos_ref, u_ref, dt_ref, At_ref, Bm_ref, Cm_ref, Dp_ref,
                 ckpt_ref, dy_ref,
                 du_ref, ddt_ref, dB_ref, dC_ref, dA_ref, dD_ref,
-                hbuf_ref, g_ref, dA_acc, dD_acc):
+                hbuf_ref, g_ref, dA_acc, dD_acc, *, G):
     """Adjoint of one chunk. Same block shapes as forward plus:
-    dy (1,T,bd) | du, ddt (1,T,bd) | dB, dC (1,1,T,N) per-(b,dblk) partials |
+    dy (1,T,bd) | du, ddt (1,T,bd) | dB, dC (1,1,T/G,G·N) per-(b,dblk)
+    partials, lane-dense: row k holds steps [kG, kG+G) × N, (t, n) row-major |
     dA (1,N,bd), dD (1,1,bd) per-b partials |
-    scratch: hbuf (T+1, N, bd) recomputed states, g (N,bd) adjoint carry,
-    dA_acc (N,bd), dD_acc (1,bd).
+    scratch: hbuf ((T+1)·N, bd) recomputed states, N rows per step;
+    g (N,bd) adjoint carry; dA_acc (N,bd), dD_acc (1,bd).
 
     Reverse recurrence (paper §3.4 bwd: same Ā→0 rule):
       g_t ≡ dL/dh_t = C_t ⊗ dy_t + a_{t+1} · g_{t+1}
       da_t = g_t ⊙ h_{t-1}  →  dΔ += Σ_n da·a·A ;  dA += Σ_t da·a·Δ
       db_t = g_t            →  dB_t = Σ_d g·Δu ;  du += Δ·Σ_n g·B ; dΔ += u·Σ_n g·B
       dC_t = Σ_d dy_t ⊙ h_t ;  du += D·dy ;  dD += Σ_t dy·u
+
+    Per group of G rows (``_bwd_group``) the serial walk only forms g_t and
+    carries a_t·g_t; everything else is computed once for the group, after
+    its walk and in the same loop body, on the stacked (G·N, bd) tiles of
+    g, h and a: the N-row sums as adds, the channel sums of dC through one
+    transpose and those of dB on the MXU, so that the XLU and the MXU share
+    them, and du, dΔ stored as whole (G, bd) tiles.
     """
     T = u_ref.shape[1]
     N, bd = At_ref.shape
-    G = _pick_subtile(T)
 
     @pl.when(pl.program_id(2) == 0)          # first step of the REVERSE walk
     def _init():
@@ -346,62 +396,62 @@ def _bwd_kernel(pos_ref, u_ref, dt_ref, At_ref, Bm_ref, Cm_ref, Dp_ref,
 
     At = At_ref[...].astype(f32)
     Dp = Dp_ref[...].astype(f32)
-    row = _rowsel(G, bd)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (N, G), 1)
+    At_g = jnp.concatenate([At] * G, axis=0)             # (G·N, bd)
 
     # ---- recompute h trajectory within the chunk from the checkpoint ----
-    hbuf_ref[0] = ckpt_ref[0, 0]
+    hbuf_ref[pl.ds(0, N)] = ckpt_ref[0, 0]
 
     def fgroup(gi, _):
         t0 = _at(gi, G)
+        r0 = _at(gi, G * N)
         dt, u, Bv, _, keep = _rows(pos_ref, u_ref, dt_ref, Bm_ref, Cm_ref,
                                    t0, G)
         BT, du = Bv.T, dt * u
         kf = jnp.where(keep, jnp.ones_like(dt), jnp.zeros_like(dt))
-        h = hbuf_ref[t0]
+        h = hbuf_ref[pl.ds(r0, N)]
         for i in range(G):
             a = jnp.exp(dt[i:i + 1] * At) * kf[i:i + 1]
             h = a * h + BT[:, i:i + 1] * du[i:i + 1]
-            hbuf_ref[t0 + i + 1] = h
+            hbuf_ref[pl.ds(r0 + (i + 1) * N, N)] = h
         return ()
 
     jax.lax.fori_loop(0, T // G, fgroup, ())
 
-    # ---- reverse adjoint walk ----
+    # ---- reverse adjoint walk, then the group's adjoints in bulk ----
     def rgroup(k, _):
-        t0 = _at(T // G - 1 - k, G)
+        gi = T // G - 1 - k
+        t0 = _at(gi, G)
+        r0 = _at(gi, G * N)
         dt, u, Bv, Cv, keep = _rows(pos_ref, u_ref, dt_ref, Bm_ref, Cm_ref,
                                     t0, G)
         dy = dy_ref[0, pl.ds(t0, G), :].astype(f32)
-        BT, CT, du = Bv.T, Cv.T, dt * u
+        CT = Cv.T
         kf = jnp.where(keep, jnp.ones_like(dt), jnp.zeros_like(dt))
         g = g_ref[...]
-        du_out = Dp * dy
-        ddt_out = jnp.zeros_like(dy)
-        dBT = jnp.zeros((N, G), f32)
-        dCT = jnp.zeros((N, G), f32)
-        dA = jnp.zeros((N, bd), f32)
+        gs = [None] * G
         for i in reversed(range(G)):
-            a = jnp.exp(dt[i:i + 1] * At) * kf[i:i + 1]
-            h_t = hbuf_ref[t0 + i + 1]
             g = CT[:, i:i + 1] * dy[i:i + 1] + g             # dL/dh_t
-            da = g * hbuf_ref[t0 + i] * a
-            gB = jnp.sum(g * BT[:, i:i + 1], axis=0, keepdims=True)
-            ddt_i = jnp.sum(da * At, axis=0, keepdims=True) + u[i:i + 1] * gB
-            du_out = du_out + jnp.where(row == i, dt[i:i + 1] * gB, 0.0)
-            ddt_out = ddt_out + jnp.where(row == i, ddt_i, 0.0)
-            dBT = dBT + jnp.where(lane == i, jnp.sum(
-                g * du[i:i + 1], axis=1, keepdims=True), 0.0)
-            dCT = dCT + jnp.where(lane == i, jnp.sum(
-                h_t * dy[i:i + 1], axis=1, keepdims=True), 0.0)
-            dA = dA + da * dt[i:i + 1]
+            gs[i] = g
+            a = jnp.exp(dt[i:i + 1] * At) * kf[i:i + 1]
             g = a * g                                         # carry to t-1
         g_ref[...] = g
-        du_ref[0, pl.ds(t0, G), :] = du_out.astype(du_ref.dtype)
-        ddt_ref[0, pl.ds(t0, G), :] = ddt_out.astype(ddt_ref.dtype)
-        dB_ref[0, 0, pl.ds(t0, G), :] = dBT.T.astype(dB_ref.dtype)
-        dC_ref[0, 0, pl.ds(t0, G), :] = dCT.T.astype(dC_ref.dtype)
-        dA_acc[...] += dA
+        g = jnp.concatenate(gs, axis=0)                      # (G·N, bd)
+        h_prev = hbuf_ref[pl.ds(r0, G * N)]
+        h = hbuf_ref[pl.ds(r0 + N, G * N)]
+        da = g * h_prev * (jnp.exp(_spread(dt, N) * At_g) * _spread(kf, N))
+        BT = Bv.T                                            # (N, G)
+        Bcol = jnp.concatenate([BT[:, i:i + 1] for i in range(G)], axis=0)
+        gB = _seg_sum(g * Bcol, N)                           # (G, bd)
+        du = dt * u
+        du_ref[0, pl.ds(t0, G), :] = (Dp * dy + dt * gB).astype(du_ref.dtype)
+        ddt_ref[0, pl.ds(t0, G), :] = (_seg_sum(da * At_g, N) +
+                                       u * gB).astype(ddt_ref.dtype)
+        dB_ref[0, 0, pl.ds(gi, 1), :] = _lane_sums_mxu(
+            g * _spread(du, N)).astype(dB_ref.dtype)
+        dC_ref[0, 0, pl.ds(gi, 1), :] = _lane_sums(
+            h * _spread(dy, N)).astype(dC_ref.dtype)
+        dA_acc[...] += jnp.sum((da * _spread(dt, N)).reshape(G, N, bd),
+                               axis=0)
         dD_acc[...] += jnp.sum(dy * u, axis=0, keepdims=True)
         return ()
 
@@ -524,8 +574,10 @@ def selective_scan_bwd_pallas(u, delta, At, Bm, Cm, Dp, positions, ckpts, dy,
                               chunk: int = DEF_CHUNK_T,
                               schedule: str = "step",
                               sub_t: Optional[int] = None):
-    """Returns (du, ddelta, dB_partial (B,nD,L,N), dC_partial (B,nD,L,N),
-    dA_partial (B,N,Dm), dD_partial (B,1,Dm))."""
+    """Returns (du, ddelta, dB_partial, dC_partial, dA_partial (B,N,Dm),
+    dD_partial (B,1,Dm)). The dB, dC partials hold (B, nD, L·N) values in
+    (t, n) row-major order: shaped (B,nD,L,N) for ``blocked``, lane-dense
+    (B,nD,L/G,G·N) for ``step`` (G its row group)."""
     Bz, L, Dm = u.shape
     N = At.shape[0]
     T, bd = chunk, block_d
@@ -536,9 +588,16 @@ def selective_scan_bwd_pallas(u, delta, At, Bm, Cm, Dp, positions, ckpts, dy,
                                    sub_t=_pick_subtile(T, sub_t))
         traj = [pltpu.VMEM((N, T, bd), f32),   # recomputed h trajectory
                 pltpu.VMEM((N, T, bd), f32)]   # adjoint trajectory g
+        part = (L, N)
+        part_spec = pl.BlockSpec((1, 1, T, N),
+                                 lambda b, d, l: (b, d, rev(l), 0))
     elif schedule == "step":
-        kernel = _bwd_kernel
-        traj = [pltpu.VMEM((T + 1, N, bd), f32)]   # recomputed h trajectory
+        G = _bwd_group(T, nL)
+        kernel = functools.partial(_bwd_kernel, G=G)
+        traj = [pltpu.VMEM(((T + 1) * N, bd), f32)]   # recomputed h
+        part = (L // G, G * N)
+        part_spec = pl.BlockSpec((1, 1, T // G, G * N),
+                                 lambda b, d, l: (b, d, rev(l), 0))
     else:
         raise ValueError(f"unknown schedule {schedule!r}")
     scratch = traj + [
@@ -549,8 +608,8 @@ def selective_scan_bwd_pallas(u, delta, At, Bm, Cm, Dp, positions, ckpts, dy,
     out_shape = (
         jax.ShapeDtypeStruct((Bz, L, Dm), f32),       # du
         jax.ShapeDtypeStruct((Bz, L, Dm), f32),       # ddelta
-        jax.ShapeDtypeStruct((Bz, nD, L, N), f32),    # dB partials
-        jax.ShapeDtypeStruct((Bz, nD, L, N), f32),    # dC partials
+        jax.ShapeDtypeStruct((Bz, nD) + part, f32),   # dB partials
+        jax.ShapeDtypeStruct((Bz, nD) + part, f32),   # dC partials
         jax.ShapeDtypeStruct((Bz, N, Dm), f32),       # dA partials
         jax.ShapeDtypeStruct((Bz, 1, Dm), f32),       # dD partials
     )
@@ -571,8 +630,8 @@ def selective_scan_bwd_pallas(u, delta, At, Bm, Cm, Dp, positions, ckpts, dy,
         out_specs=[
             pl.BlockSpec((1, T, bd), lambda b, d, l: (b, rev(l), d)),
             pl.BlockSpec((1, T, bd), lambda b, d, l: (b, rev(l), d)),
-            pl.BlockSpec((1, 1, T, N), lambda b, d, l: (b, d, rev(l), 0)),
-            pl.BlockSpec((1, 1, T, N), lambda b, d, l: (b, d, rev(l), 0)),
+            part_spec,
+            part_spec,
             pl.BlockSpec((1, N, bd), lambda b, d, l: (b, 0, d)),
             pl.BlockSpec((1, 1, bd), lambda b, d, l: (b, 0, d)),
         ],

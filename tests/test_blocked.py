@@ -217,20 +217,32 @@ def test_pallas_blocked_grads(rng, schedule):
                             block_d=8, chunk=8)
 
 
-@pytest.mark.parametrize("schedule", ["step", "blocked"])
-def test_pallas_grads_document_across_chunks(rng, schedule):
-    """Documents cross the chunk boundaries at 16 and 32, and resets fall
-    on the first row of the chunks at 16 (row 1) and 48 (row 0): the
-    backward's adjoint carry must pass the first and stop at the others."""
-    Bz, L, Dm, N = 2, 64, 8, 4
+@pytest.mark.parametrize("schedule,L,Dm,N,block_d,chunk,starts", [
+    pytest.param("step", 64, 8, 4, 8, 16, ([0, 5, 48], [0, 16, 40]),
+                 id="step"),
+    pytest.param("blocked", 64, 8, 4, 8, 16, ([0, 5, 48], [0, 16, 40]),
+                 id="blocked"),
+    # the chip's tile: block_d 128, N 16, chunk 256 (the backward's row
+    # groups of 32); a reset on the second chunk's first row and one inside
+    # a row group
+    pytest.param("step", 512, 128, 16, 128, 256, ([0, 104, 256],),
+                 id="step-chip-tile"),
+])
+def test_pallas_grads_document_across_chunks(rng, schedule, L, Dm, N,
+                                             block_d, chunk, starts):
+    """Documents cross chunk boundaries, and resets fall on a chunk's first
+    row and inside it: the backward's adjoint carry must pass the first and
+    stop at the others. In the small case documents cross the boundaries
+    at 16 and 32, and resets fall on the first row of the chunks at 16
+    (row 1) and 48 (row 0)."""
+    Bz = len(starts)
     u, dt, A, Bm, Cm, Dk, _ = _ssm_inputs(rng, Bz, L, Dm, N)
-    starts = ([0, 5, 48], [0, 16, 40])
     pos = np.zeros((Bz, L), np.int32)
     for b, cuts in enumerate(starts):
         for s0, s1 in zip(cuts, cuts[1:] + [L]):
             pos[b, s0:s1] = np.arange(s1 - s0)
     _pallas_grads_match_ref((u, dt, A, Bm, Cm, Dk), jnp.asarray(pos),
-                            schedule, block_d=8, chunk=16)
+                            schedule, block_d=block_d, chunk=chunk)
 
 
 @pytest.mark.parametrize("schedule", ["step", "blocked"])

@@ -127,6 +127,27 @@ def test_kernel_compiles_for_v5e(one_chip, name, direction):
     assert hlo.count("tpu_custom_call") >= (1 if direction == "fwd" else 2)
 
 
+@pytest.mark.parametrize("rows,length,channels", [
+    (8, 4096, 2048),        # train.mamba-110m.pack4k
+    (4, 4096, 4096),        # train.mamba-1.4b-s12.pack4k
+    (4, 4096, 2048),        # train.mamba-1.4b.pack4k.x4, per shard
+])
+def test_scan_step_bwd_compiles_for_v5e_at_cell_shapes(one_chip, rows, length,
+                                                       channels):
+    """The `step` backward at the train cells' per-device kernel shapes:
+    its state buffer and the row group's bulk adjoint tiles fit the scoped
+    VMEM (Mosaic refuses the compile otherwise)."""
+    shapes = [((rows, length, channels), BF), ((rows, length, channels), BF),
+              ((channels, N), jnp.float32), ((rows, length, N), BF),
+              ((rows, length, N), BF), ((channels,), jnp.float32),
+              ((rows, length), jnp.int32)]
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+            for shape, dt in shapes]
+    hlo = jax.jit(_grad(_scan("step"))).lower(*args).compile().as_text()
+    calls = _kernel_calls(hlo)
+    assert {n for n, _ in calls} == {2, 6}, calls      # forward, backward
+
+
 # ---------------------------------------------------------------------------
 # the packed train step: the scan kernel on a TPU, the XLA scan on the CPU
 # ---------------------------------------------------------------------------
