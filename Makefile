@@ -12,17 +12,11 @@ SERVE_OLD ?= BENCH_serve.json
 SERVE_NEW ?= BENCH_serve.new.json
 TRAIN_OLD ?= BENCH_train.json
 TRAIN_NEW ?= BENCH_train.new.json
-# the shape-keyed scan-autotuning cache (repro/tune). bench-tune refreshes
-# it; tune-check verifies the committed file loads under this machine's
-# fingerprint (a clean STALE report on any other machine).
-TUNE ?= TUNE_CACHE.json
-
 # bench-smoke scratch outputs (gitignored experiments/): structure-checked,
 # never compared against the committed baselines
 SMOKE_SCAN ?= experiments/smoke_scan.json
 SMOKE_SERVE ?= experiments/smoke_serve.json
 SMOKE_TRAIN ?= experiments/smoke_train.json
-SMOKE_TUNE ?= experiments/smoke_tune_cache.json
 
 # obs-smoke scratch traces (gitignored experiments/): Chrome trace-event
 # JSON from tiny traced serve + train launcher runs
@@ -35,7 +29,7 @@ OBS_TRAIN_TRACE ?= experiments/obs_train_trace.json
 FAULT_CHAOS_SEED ?= 0
 
 .PHONY: verify verify-fast verify-faults ci bench-scan bench-serve \
-	bench-serve-open bench-train bench-tune tune-check bench-compare \
+	bench-serve-open bench-train bench-compare \
 	bench-smoke bench-accept obs-smoke docs-check quickstart
 
 verify:
@@ -54,15 +48,14 @@ verify-faults:
 		$(PY) -m pytest -q -m "not slow" tests/test_faults.py
 
 # one-shot CI bundle (what .github/workflows/ci.yml runs): fast tier-1 lane,
-# chaos lane, tune-cache audit, a bounded bench smoke whose JSON structure
+# chaos lane, a bounded bench smoke whose JSON structure
 # — never its timings — is checked, and the observability smoke (traced
 # tiny serve+train runs, trace structure validated)
-ci: verify-fast verify-faults tune-check bench-smoke obs-smoke docs-check
+ci: verify-fast verify-faults bench-smoke obs-smoke docs-check
 
-# regenerate the scan-schedule matrix into $(NEW) (fig2 also warms $(TUNE)
-# for any of its shape keys the bounded sweep hasn't covered yet)
+# regenerate the scan-schedule matrix into $(NEW)
 bench-scan:
-	BENCH_SCAN_JSON=$(NEW) REPRO_TUNE_CACHE=$(TUNE) $(PY) -m benchmarks.run fig2
+	BENCH_SCAN_JSON=$(NEW) $(PY) -m benchmarks.run fig2
 
 # regenerate every serving row — closed-loop padded-vs-packed, the
 # open-loop v1-vs-v2 scheduler rows, AND the prefix-cache / speculative
@@ -82,20 +75,11 @@ bench-serve-open:
 bench-train:
 	BENCH_TRAIN_JSON=$(TRAIN_NEW) $(PY) -m benchmarks.run train
 
-# bounded autotune sweep over the benchmark-matrix shapes -> $(TUNE)
-bench-tune:
-	REPRO_TUNE_CACHE=$(TUNE) $(PY) -m repro.tune.runner --out $(TUNE)
-
-# committed cache loads under the current fingerprint, or cleanly reports
-# stale (exit 1 only when missing/corrupt)
-tune-check:
-	$(PY) -m repro.tune --check $(TUNE)
-
 # gate on the perf trajectories: one invocation, every offender across both
 # files in one report; exits nonzero on >10% regressions. The scan pair is
 # REQUIRED (a missing regeneration fails the gate); the serve pair is
 # skipped if a side wasn't regenerated.
-bench-compare: tune-check
+bench-compare:
 	$(PY) benchmarks/compare.py --pair $(OLD) $(NEW) \
 		--optional-pair $(SERVE_OLD) $(SERVE_NEW) \
 		--optional-pair $(TRAIN_OLD) $(TRAIN_NEW)
@@ -110,13 +94,11 @@ bench-accept:
 
 # tiny-shape benchmark pass for CI: exercises fig2 + serve end to end and
 # validates the emitted JSON structure; timings are NOT gated (CI machines
-# are noisy), and the scratch tune cache keeps the committed TUNE_CACHE.json
-# untouched
+# are noisy)
 bench-smoke:
 	mkdir -p experiments
 	BENCH_SMOKE=1 BENCH_SCAN_JSON=$(SMOKE_SCAN) \
 		BENCH_SERVE_JSON=$(SMOKE_SERVE) BENCH_TRAIN_JSON=$(SMOKE_TRAIN) \
-		REPRO_TUNE_CACHE=$(SMOKE_TUNE) \
 		$(PY) -m benchmarks.run fig2 serve serve_open serve_cached train
 	$(PY) benchmarks/compare.py --schema $(SMOKE_SCAN) $(SMOKE_SERVE) \
 		$(SMOKE_TRAIN)

@@ -14,7 +14,8 @@ all pairs. The exit code is nonzero iff that list is non-empty (so
 trajectories while still showing a multi-row regression in full).
 
 Rows present in only one file are listed as added/removed, never flagged —
-new schedules (e.g. the tuned/dual rows) must be able to land.
+new schedules (e.g. the dual rows) must be able to land, and retired
+ones to leave.
 ``--pair`` files are required (missing → nonzero exit: the primary gate
 cannot pass vacuously); ``--optional-pair`` skips a pair whose files are
 absent, so one gate can also cover benchmark files a given run didn't

@@ -98,7 +98,7 @@ def fig2_ssm_operator_profile():
       blocked         SSD-style block-parallel schedule, backend-default
                       in-chunk evaluator (core/ssm.py::_blocked_ssm)
       blocked_matmul  same schedule, explicit M @ b einsum contraction
-                      (the MXU form the Pallas kernel uses)
+                      (the MXU-shaped form)
       fused_seq       single sequential scan, y fused
       mamba2_blocked  head-structured (scalar per-head decay) blocked
                       schedule at MATCHED channels (D = H·dh) — the decay
@@ -110,9 +110,6 @@ def fig2_ssm_operator_profile():
       mamba2w_*       wide-head family (H=2, dh=128) at matched channels
                       with a small chunk (T=16) — the dh ≫ T regime where
                       the dual form's T²·(dh+N) beats quad's T²·dh·N
-      *tuned*         knobs resolved from the shape-keyed tuning cache
-                      (repro/tune; fig2 warms TUNE_CACHE.json for its own
-                      shapes, so tuned rows are the measured winners)
 
     The blocked_noreset row repeats `blocked` with reset-free positions:
     its delta vs `blocked` is the cost of PackMamba reset-correctness
@@ -125,8 +122,6 @@ def fig2_ssm_operator_profile():
           "(B=1, D=256, N=16, packed segments ~300; mamba2 rows: H=4 "
           "dh=64, mamba2w rows: H=2 dh=128, both at matched channels)")
     from repro.core.ssm import selective_scan, selective_scan_heads
-    from repro.tune import get_cache
-    from repro.tune import runner as tune_runner
     rng = np.random.default_rng(0)
     D, N = 256, 16
     H2 = 4
@@ -138,8 +133,6 @@ def fig2_ssm_operator_profile():
     Dk = jnp.ones((D,), jnp.float32)
     D2k = jnp.ones((H2,), jnp.float32)
     D2wk = jnp.ones((H2w,), jnp.float32)
-    cache = get_cache()             # TUNE_CACHE.json when present
-    warmed = False
     scheds = [
         ("chunked", dict(method="chunked", chunk=256)),
         ("blocked", dict(method="blocked", chunk=128)),
@@ -171,27 +164,12 @@ def fig2_ssm_operator_profile():
                            selective_scan_heads(u, dt, A2, Bm, Cm, D2k, pos,
                                                 **dict(kw)))
 
-        # warm the tuning cache for this L's three shape families (no-op
-        # when `make bench-tune` already measured them)
-        warmed |= tune_runner.ensure("selective_scan", B=1, L=L, D=D, N=N,
-                                     cache=cache)
-        warmed |= tune_runner.ensure("selective_scan_heads", B=1, L=L,
-                                     H=H2, dh=P2, N=N, cache=cache)
-        warmed |= tune_runner.ensure("selective_scan_heads", B=1, L=L,
-                                     H=H2w, dh=P2w, N=N, cache=cache)
-
         # each cell: (name, jitted fn, args)
         cells = [(name, mk1(kw), (u, dt, Bm, Cm, pos))
                  for name, kw in scheds]
         cells.append(("blocked_noreset",
                       mk1(dict(method="blocked", chunk=128)),
                       (u, dt, Bm, Cm, pos_flat)))
-        # tuned rows resolve through the SAME trace-time resolver models
-        # use (tune= → core/ssm.py; xla winners only on this cell's core
-        # path, explicit args the miss fallback) — no parallel re-mapping
-        cells.append(("tuned",
-                      mk1(dict(method="blocked", chunk=128, tune=cache)),
-                      (u, dt, Bm, Cm, pos)))
         cells.append(("mamba2_blocked",
                       mk2(dict(method="blocked", chunk=64)),
                       (u2, dt2, Bm, Cm, pos)))
@@ -201,9 +179,6 @@ def fig2_ssm_operator_profile():
         cells.append(("mamba2_noreset",
                       mk2(dict(method="blocked", chunk=64)),
                       (u2, dt2, Bm, Cm, pos_flat)))
-        cells.append(("mamba2_tuned",
-                      mk2(dict(method="blocked", chunk=64, tune=cache)),
-                      (u2, dt2, Bm, Cm, pos)))
         # the dh ≫ T regime: quad must pay T²·dh·N, dual only T²·(dh+N)
         cells.append(("mamba2w_quad",
                       mk2(dict(method="blocked", chunk=16, intra="quad"),
@@ -213,13 +188,9 @@ def fig2_ssm_operator_profile():
                       mk2(dict(method="blocked", chunk=16, intra="dual"),
                           A2w, D2wk),
                       (u2w, dt2w, Bm, Cm, pos)))
-        cells.append(("mamba2w_tuned",
-                      mk2(dict(method="blocked", chunk=16, intra="quad",
-                               tune=cache), A2w, D2wk),
-                      (u2w, dt2w, Bm, Cm, pos)))
         # interleave schedules round-robin: min-of-rounds is robust to the
         # machine-load drift that would bias per-schedule timing blocks
-        # (shared protocol: benchmarks/timing.py, also used by the tuner)
+        # (shared protocol: benchmarks/timing.py)
         best, _ = interleaved_min_of_rounds(
             [(name, (lambda fn=fn, args=args: fn(*args)))
              for name, fn, args in cells], rounds=7)
@@ -230,9 +201,6 @@ def fig2_ssm_operator_profile():
             _row(f"fig2/ssm_{name}_L{L}", us,
                  f"{L / (us / 1e6):.0f} tok/s{tag}")
             _bench("selective_scan", shape, name, us, L)
-    if warmed:
-        print(f"# fig2 tune: warmed {cache.save()} "
-              f"({len(cache.entries)} entries)")
     if SMOKE:       # the HLO evidence below is compile-heavy; smoke skips it
         return
     # ---- peak-memory evidence: no (B, L, D, N) buffer in the blocked HLO

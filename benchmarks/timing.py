@@ -1,4 +1,4 @@
-"""Shared wall-clock measurement discipline for benchmarks and the autotuner.
+"""Shared wall-clock measurement discipline for the CPU benchmarks.
 
 One helper, one protocol: *interleaved min-of-rounds*. All candidate cells
 are warmed (compiled) first, then timed round-robin — each round times every
@@ -6,8 +6,7 @@ cell once — and each cell keeps its best round. Interleaving means a
 machine-load drift hits every cell in the same round instead of biasing
 whichever cell happened to own a contiguous timing block; min-of-rounds
 discards the drifty rounds entirely. This is the protocol
-``benchmarks/run.py`` fig2/serve always used; ``repro/tune/runner.py``
-reuses it so tuner measurements are comparable with the benchmark matrix.
+``benchmarks/run.py`` fig2/serve always used.
 
 ``clock`` and ``sync`` are injectable so tests can drive the loop with a
 fake clock and no real device work.

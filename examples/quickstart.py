@@ -7,8 +7,8 @@ CI: `.github/workflows/ci.yml` runs `make ci` on every push — the fast
 tier-1 lane (`pytest -m "not slow"`; the slow-marked engine round-trips
 and grid sweeps stay in the full local `make verify`), the fault-injection
 chaos lane (`make verify-faults`, a randomized-but-seeded FaultPlan —
-same FAULT_CHAOS_SEED, same faults, any machine), the tune-cache
-audit (`make tune-check`), a tiny-shape benchmark smoke whose JSON
+same FAULT_CHAOS_SEED, same faults, any machine), a tiny-shape
+benchmark smoke whose JSON
 structure is schema-checked while its timings are never gated
 (`make bench-smoke`), and the observability smoke (`make obs-smoke`:
 tiny traced serve+train launcher runs, Chrome-trace structure validated
@@ -153,28 +153,7 @@ def main():
           f"{len(engine.stats.buckets)} prefill shape(s) compiled; "
           f"TTFT p50 {pct['p50']:.0f}ms incl. compiles)")
 
-    # 6. autotuning: every scan-schedule knob above (blocked chunk, in-chunk
-    #    evaluator, Pallas subtile, backend) is a measured, shape-keyed
-    #    decision when scan_tune != "off". `make bench-tune` sweeps the
-    #    candidate spaces once per machine into TUNE_CACHE.json
-    #    (fingerprinted by device/jax version; `make tune-check` audits it);
-    #    a model with scan_tune="auto" then resolves its knobs from the
-    #    cache at trace time, and launch/train.py / launch/serve.py warm the
-    #    cache for their exact shape buckets at startup (--scan-tune auto).
-    #    The default scan_tune="off" keeps the hard-coded paths bit-for-bit.
-    from repro.tune import TuneCache, tuned
-    demo = TuneCache()     # normally loaded from TUNE_CACHE.json
-    from repro.tune import shape_key
-    demo.put(shape_key("selective_scan", B=1, L=256, D=cfg.d_inner,
-                       N=cfg.d_state),
-             {"backend": "xla", "method": "blocked", "chunk": 32,
-              "intra": "assoc"}, us=1234.0)
-    knobs = tuned("selective_scan", B=1, L=256, D=cfg.d_inner,
-                  N=cfg.d_state, cache=demo)
-    print(f"tuned scan knobs for (B=1, L=256): {knobs} "
-          f"(cfg: scan_tune='auto' applies these at trace time)")
-
-    # 7. observability (repro.obs): every engine/trainer above metered
+    # 6. observability (repro.obs): every engine/trainer above metered
     #    through ONE MetricsRegistry — stats objects are thin views over it
     #    — and, when you pass Obs.on(), a span tracer records per-request
     #    lifecycles (queued → prefill → decode → done, one Perfetto row per
@@ -204,7 +183,7 @@ def main():
     print(obs.tracer.timeline("req0"))
     obs.close()
 
-    # 8. prefix caching on the O(1) SSM state (docs/serving.md §4): a
+    # 7. prefix caching on the O(1) SSM state (docs/serving.md §4): a
     #    session's whole inference state is a few KB regardless of prompt
     #    length, so the post-prefill state of a shared system prompt can be
     #    stored ONCE in a host-side LRU (launch/state_cache.py) and every

@@ -1,11 +1,9 @@
 """Packed-training lane end to end: PackingLoader (mode/policy knobs) +
-background prefetch + bf16 mixed precision with f32 scan carries + the
-fwd+bwd tuner objective.
+background prefetch + bf16 mixed precision with f32 scan carries.
 
     PYTHONPATH=src python examples/train_packed.py
     PYTHONPATH=src python examples/train_packed.py --mode pad --dtype float32
-    PYTHONPATH=src python examples/train_packed.py --policy sequential \
-        --scan-tune auto
+    PYTHONPATH=src python examples/train_packed.py --policy sequential
 
 This is the example-sized version of `python -m repro.launch.train`; the
 launcher adds checkpoint/resume, SIGTERM safety, and mesh sharding on top
@@ -48,24 +46,12 @@ def main():
     ap.add_argument("--prefetch", type=int, default=2,
                     help="batches packed ahead on a background thread "
                          "(0 = synchronous)")
-    ap.add_argument("--scan-tune", default="off",
-                    help="off | auto | <cache path>: resolve scan "
-                         "schedules from the shape-keyed cache, warmed "
-                         "here with the fwdbwd (training) objective")
     args = ap.parse_args()
 
     # a small model from the paper's family; dtype knobs are config fields
     cfg = dataclasses.replace(
         get_config("mamba-110m"), d_model=128, n_layers=4, vocab=512,
         scan_chunk=64, dtype=args.dtype, param_dtype=args.param_dtype)
-    if args.scan_tune != "off":
-        # training shapes want schedules timed on forward+backward, not
-        # inference's forward-only sweep — the objective tags the cache key
-        cfg = dataclasses.replace(cfg, scan_tune=args.scan_tune,
-                                  tune_objective="fwdbwd")
-        from repro.tune import warm_for_config
-        warm_for_config(cfg, [(args.rows, args.seq_len)],
-                        objective="fwdbwd")
     model = build_model(cfg)
 
     # lognormal variable-length stream -> packed (rows, seq_len) buffers;

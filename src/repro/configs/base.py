@@ -84,10 +84,8 @@ class ArchConfig:
                                       # True: Pallas conv + scan everywhere
                                       # (Mosaic on a TPU, interpreted on
                                       # the CPU). False: XLA everywhere.
-    pallas_schedule: str = "step"     # step | blocked (Pallas scan kernel;
-                                      # step = per-step walk, 2.2x faster
-                                      # fwd+bwd on a v5e; blocked = SSD-
-                                      # style subtile contraction)
+                                      # The table: models/blocks.py::
+                                      # _scan_backend.
     scan_chunk: int = 256             # chunk length for XLA-path scans
     scan_impl: str = "blocked"        # blocked | chunked | fused_seq (XLA
                                       # ssm path; blocked = SSD-style
@@ -103,20 +101,6 @@ class ArchConfig:
                                       # "quad" | "dual" (mamba2; dual = the
                                       # C·Bᵀ attention-like form, wins when
                                       # head dim ≫ chunk)
-    scan_tune: str = "off"            # shape-keyed autotuning (repro/tune):
-                                      # "off" = the knobs above stand as-is
-                                      # (bit-identical HLO); "auto" = resolve
-                                      # measured winners from the process-
-                                      # default TUNE_CACHE.json; a path =
-                                      # resolve from that cache file.
-                                      # launch/train.py + launch/serve.py
-                                      # warm the cache for their shapes.
-    tune_objective: str = "fwd"       # which sweep's winners scan_tune
-                                      # resolves: "fwd" (forward-only —
-                                      # serving) | "fwdbwd" (forward+backward
-                                      # — what launch/train.py sets so the
-                                      # training step gets schedules tuned
-                                      # for its own gradient shapes)
     scan_dtype: str = "float32"       # recurrence compute dtype (bf16 halves
                                       # the scan's HBM traffic on the XLA path)
     act_pspec: Optional[Tuple] = None  # sharding constraint on the residual

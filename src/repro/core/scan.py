@@ -27,7 +27,7 @@ Schedule taxonomy (who wins when):
   * ``chunked``      — lax.scan over L/T chunks carrying h, with an
                        intra-chunk associative scan. Peak memory O(B·T·S)
                        instead of O(B·L·S) for the scan internals; this is
-                       the direct XLA analogue of the Pallas ``step``
+                       the direct XLA analogue of the Pallas Mamba-1
                        kernel's grid-sequential VMEM-resident carry. Still
                        elementwise (VPU) work end to end.
   * ``blocked``      — SSD-style block-parallel schedule (Gu & Dao's
@@ -45,8 +45,7 @@ Schedule taxonomy (who wins when):
                        benchmarks/run.py fig2). The selective-scan
                        specialization (exp-of-cumsum log decays, y = C·h
                        folded in, (B, L, D, N) never materialized) is
-                       core/ssm.py::method='blocked'; its TPU-kernel twin is
-                       kernels/selective_scan.py::schedule='blocked'.
+                       core/ssm.py::method='blocked'.
   * ``blocked`` with *per-head scalar decay* (Mamba-2 / SSD proper) — the
                        head-structured specialization: with state (H, dh, N)
                        and one scalar decay a_t per head (instead of one per
@@ -62,11 +61,10 @@ Schedule taxonomy (who wins when):
                        twin is kernels/selective_scan.py::
                        schedule='blocked_heads'.
 
-The Pallas kernels mirror the last two: ``schedule='step'`` walks time with
-a per-step VPU update (chunk carry in VMEM scratch), ``schedule='blocked'``
-applies the same masked-triangular-decay contraction per in-chunk subtile,
-and ``schedule='blocked_heads'`` applies the per-head scalar-decay form as
-one dense (Tt, Tt) @ (Tt, dh·N) matmul per subtile.
+The Pallas kernels: the Mamba-1 kernel walks time with a per-step VPU
+update (chunk carry in VMEM scratch), and ``schedule='blocked_heads'``
+applies the per-head scalar-decay form as one dense (Tt, Tt) @ (Tt, dh·N)
+matmul per subtile.
 """
 from __future__ import annotations
 

@@ -40,10 +40,10 @@ blocked schedules gather them from the in-chunk state slice they already
 compute — one O(B·S·state) gather per chunk, no extra scan passes and still
 no (B, L, …, N) materialization.
 
-This module is the default (dry-run / roofline) path; the Pallas TPU kernels
-live in kernels/selective_scan.py and match this to numerical tolerance
-(``schedule='blocked'``/``'step'`` for per-channel, ``'blocked_heads'`` for
-per-head scalar decay).
+This module is the XLA path; the Pallas TPU kernels live in
+kernels/selective_scan.py and match this to numerical tolerance (the
+per-step walk for per-channel decay, ``'blocked_heads'`` for per-head
+scalar decay).
 """
 from __future__ import annotations
 
@@ -62,30 +62,7 @@ _HEADS_CHUNK_CAP = 64     # blocked heads (quad): bounds the (T, T, H) decay
 _HEADS_DUAL_CHUNK_CAP = 128  # dual form: the T² term is only (dh + N) wide,
 #   so a larger T pays off — but the (B, T, T, H) Gram/decay matrices still
 #   grow as T², hence a cap of their own
-# The caps bound worst-case memory whatever the tuner asks for; WITHIN them
-# the chunk is a measured per-shape decision of repro/tune, not a constant.
-
-
-def _tuned_knobs(op, tune, *, B, L, D=0, N=0, H=0, dh=0, dtype,
-                 positions, objective="fwd"):
-    """Resolve measured xla-path knobs for one call site (or {} on miss).
-
-    ``tune``: "auto" (process-default cache), a cache path, or a TuneCache.
-    ``objective``: "fwd" | "fwdbwd" — which sweep's winner to serve (a
-    training step resolves against forward+backward timings). Resolution is
-    trace-time Python over static shapes — nothing here ever blocks a
-    traced computation; a cache miss falls through to the caller's explicit
-    arguments. Winners recorded for the pallas backend are ignored at this
-    (xla-only) level — kernels/ops.py resolves those.
-    """
-    from repro.tune import tuned       # lazy: repro.tune imports this module
-    kn = tuned(op, cache=None if tune == "auto" else tune,
-               B=B, L=L, D=D, N=N, H=H, dh=dh, dtype=dtype,
-               reset_density=None if positions is not None else 0.0,
-               objective=objective)
-    if not kn or kn.get("backend", "xla") != "xla":
-        return {}
-    return kn
+# The caps bound worst-case memory whatever chunk the caller asks for.
 
 
 def selective_scan(u: jnp.ndarray, delta: jnp.ndarray, A: jnp.ndarray,
@@ -96,8 +73,7 @@ def selective_scan(u: jnp.ndarray, delta: jnp.ndarray, A: jnp.ndarray,
                    method: str = "chunked", chunk: int = 256,
                    return_state: bool = False,
                    compute_dtype=None, intra: Optional[str] = None,
-                   collect_ends: Optional[jnp.ndarray] = None,
-                   tune=None, tune_objective: str = "fwd"):
+                   collect_ends: Optional[jnp.ndarray] = None):
     """Mamba-1 surface: u,delta: (B,L,D); A: (D,N); B,C: (B,L,N); D: (D,).
 
     The degenerate head-structured case H = D, dh = 1 — dispatches through
@@ -110,9 +86,6 @@ def selective_scan(u: jnp.ndarray, delta: jnp.ndarray, A: jnp.ndarray,
     default picks 'matmul' on TPU, 'assoc' elsewhere — see _blocked_ssm).
     collect_ends: (B, S) int32 segment-end indices (−1 = absent) — per-
     segment serving handoff (module docstring).
-    tune: None (off — use the explicit arguments as-is) | "auto" | cache
-    path | TuneCache: resolve (method, chunk, intra) from the shape-keyed
-    tuning cache, explicit arguments serving as the miss fallback.
     Returns y (B, L, D) [, h_last (B, D, N)] [, h_ends (B, S, D, N)].
     """
     out = selective_scan_heads(
@@ -120,7 +93,7 @@ def selective_scan(u: jnp.ndarray, delta: jnp.ndarray, A: jnp.ndarray,
         h0=None if h0 is None else h0[:, :, None, :],
         method=method, chunk=chunk, return_state=return_state,
         compute_dtype=compute_dtype, intra=intra,
-        collect_ends=collect_ends, tune=tune, tune_objective=tune_objective)
+        collect_ends=collect_ends)
     if not (return_state or collect_ends is not None):
         return out[..., 0]
     out = list(out)
@@ -138,8 +111,7 @@ def selective_scan_heads(u: jnp.ndarray, delta: jnp.ndarray, A: jnp.ndarray,
                          method: str = "blocked", chunk: int = 64,
                          return_state: bool = False,
                          compute_dtype=None, intra: Optional[str] = None,
-                         collect_ends: Optional[jnp.ndarray] = None,
-                         tune=None, tune_objective: str = "fwd"):
+                         collect_ends: Optional[jnp.ndarray] = None):
     """Unified head-structured state-space interface (module docstring).
 
     u: (B, L, H, dh); delta: (B, L, H); B, C: (B, L, N) (shared across the
@@ -159,25 +131,10 @@ def selective_scan_heads(u: jnp.ndarray, delta: jnp.ndarray, A: jnp.ndarray,
                  | 'fused_seq' | 'sequential' | 'associative', plus
                  ``intra`` ∈ ('matmul', 'assoc') for 'blocked').
 
-    ``tune``: None (off) | "auto" | cache path | TuneCache — resolve
-    (method, chunk, intra) from the shape-keyed tuning cache at trace time;
-    the explicit arguments are the miss fallback (repro/tune).
-
     Returns y (B, L, H, dh) [, h_last (B, H, dh, N)]
     [, h_ends (B, S, H, dh, N)].
     """
     Bsz, L, H, P = u.shape
-    if tune is not None:
-        kn = _tuned_knobs(
-            "selective_scan" if A.ndim == 2 else "selective_scan_heads",
-            tune, B=Bsz, L=L, D=(H if A.ndim == 2 else 0),
-            N=B.shape[-1], H=(0 if A.ndim == 2 else H),
-            dh=(0 if A.ndim == 2 else P), dtype=u.dtype,
-            positions=positions, objective=tune_objective)
-        if kn:
-            method = kn.get("method", method)
-            chunk = kn.get("chunk", chunk)
-            intra = kn.get("intra", intra)
     if A.ndim == 2:
         # Mamba-1 degenerate case: fold dh into the channel axis and run the
         # per-channel evaluators.
@@ -286,14 +243,13 @@ def _blocked_ssm(u, delta, A, B, C, D, positions, h0, cdt,
         every (b, d, n), so the einsum is a batched matrix–vector product:
         no MXU shape, and the VPU/EUP pays T exponentials and T
         multiply-adds per state element where the recurrence pays one of
-        each — the carry chain shrinks by T, the work grows by T. The form
-        the Pallas ``blocked`` kernel implements; default when running on
-        TPU, where the train step's scan now runs the Pallas ``step``
-        kernel instead (kernels/ops.py, 'auto') and this form serves only
-        the state-collecting prefills. Peak per-chunk intermediate is the
-        (B, T, T, D, N) masked decay (s_i − s_j ≤ 0 for unmasked pairs
-        since A < 0, Δ ≥ 0; masked pairs are clamped before the exp, so
-        no overflow anywhere).
+        each — the carry chain shrinks by T, the work grows by T. The
+        default when running on TPU, where the train step's scan runs the
+        Pallas kernel instead (models/blocks.py::_scan_backend) and this
+        form serves only the state-collecting prefills. Peak per-chunk
+        intermediate is the (B, T, T, D, N) masked decay (s_i − s_j ≤ 0
+        for unmasked pairs since A < 0, Δ ≥ 0; masked pairs are clamped
+        before the exp, so no overflow anywhere).
       * ``"assoc"`` — evaluate the same masked operator with an in-chunk
         associative tree (log₂T passes of elementwise combines). No matrix
         units to feed on CPU, so this is the default there; it keeps the
@@ -313,8 +269,7 @@ def _blocked_ssm(u, delta, A, B, C, D, positions, h0, cdt,
     if intra == "matmul":
         # the (B, T, T, D, N) contraction operand grows as T²·D·N: an
         # uncapped scan_chunk (256) would dwarf the (B, L, D, N) buffer
-        # this schedule exists to avoid. Matches the Pallas kernel's
-        # DEF_SUB_T-scale subtiling.
+        # this schedule exists to avoid.
         T = min(T, _MATMUL_CHUNK_CAP)
     A32 = A.astype(cdt)
     reset = (positions == 0) if positions is not None else \
@@ -470,8 +425,8 @@ def _blocked_ssm_heads(u, delta, A, B, C, D, positions, h0,
 
     ``intra="quad"`` is an exact pin of the default path (same
     ``_HEADS_CHUNK_CAP`` clamp, same trace); ``"dual"`` clamps at its own
-    ``_HEADS_DUAL_CHUNK_CAP``. Within those bounds the chunk is the
-    autotuner's (repro/tune) measured decision.
+    ``_HEADS_DUAL_CHUNK_CAP``. Within those bounds the caller's chunk
+    stands.
     """
     if intra not in (None, "quad", "dual"):
         raise ValueError(f"unknown heads blocked intra mode {intra!r}")

@@ -5,10 +5,10 @@ layout transposes, custom_vjp wiring, and backend dispatch.
 repro.core (SPMD-partitionable and visible to cost_analysis);
 ``backend='pallas'`` routes to the TPU kernels — compiled by Mosaic on a
 TPU, run under the Pallas interpreter on the CPU (kernels/__init__.py
-decides from the platform the call is lowered for). The Mamba-1 scan also
-takes ``backend='auto'``: the kernel where the default backend is a TPU,
-the XLA evaluator everywhere else. A Pallas call is not SPMD-partitioned,
-so under an abstract mesh of more than one device the scan kernel runs per
+decides from the platform the call is lowered for). Which backend a model
+block's scan takes is decided above this module, by
+models/blocks.py::_scan_backend. A Pallas call is not SPMD-partitioned, so
+under an abstract mesh of more than one device the scan kernel runs per
 shard inside ``shard_map``.
 
 Structure note: the custom_vjp is defined over *already padded, fully
@@ -63,27 +63,22 @@ def _pad_to(x: jnp.ndarray, axis: int, mult: int, value=0):
 # selective scan
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
-def _scan_padded(u, delta, At, B, C, Dp, pos, block_d, chunk, schedule,
-                 sub_t):
-    y, _ = _scan_fwd_rule(u, delta, At, B, C, Dp, pos, block_d, chunk,
-                          schedule, sub_t)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _scan_padded(u, delta, At, B, C, Dp, pos, block_d, chunk):
+    y, _ = _scan_fwd_rule(u, delta, At, B, C, Dp, pos, block_d, chunk)
     return y
 
 
-def _scan_fwd_rule(u, delta, At, B, C, Dp, pos, block_d, chunk, schedule,
-                   sub_t):
+def _scan_fwd_rule(u, delta, At, B, C, Dp, pos, block_d, chunk):
     y, ckpts = scan_k.selective_scan_fwd_pallas(
-        u, delta, At, B, C, Dp, pos, block_d=block_d, chunk=chunk,
-        schedule=schedule, sub_t=sub_t)
+        u, delta, At, B, C, Dp, pos, block_d=block_d, chunk=chunk)
     return y, (u, delta, At, B, C, Dp, pos, ckpts)
 
 
-def _scan_bwd_rule(block_d, chunk, schedule, sub_t, res, dy):
+def _scan_bwd_rule(block_d, chunk, res, dy):
     u, delta, At, B, C, Dp, pos, ckpts = res
     du, ddelta, dB_p, dC_p, dA_p, dD_p = scan_k.selective_scan_bwd_pallas(
-        u, delta, At, B, C, Dp, pos, ckpts, dy, block_d=block_d, chunk=chunk,
-        schedule=schedule, sub_t=sub_t)
+        u, delta, At, B, C, Dp, pos, ckpts, dy, block_d=block_d, chunk=chunk)
     return (du.astype(u.dtype), ddelta.astype(delta.dtype),
             dA_p.sum(0).astype(At.dtype),
             dB_p.sum(1).reshape(B.shape).astype(B.dtype),
@@ -95,66 +90,22 @@ def _scan_bwd_rule(block_d, chunk, schedule, sub_t, res, dy):
 _scan_padded.defvjp(_scan_fwd_rule, _scan_bwd_rule)
 
 
-def _resolve_tune(op, tune, *, B, L, D=0, N=0, H=0, dh=0, dtype, positions,
-                  objective="fwd"):
-    """Resolve the measured winner for one call site from the tuning cache.
-
-    Unlike the xla-only resolver in core/ssm.py, this level owns the
-    backend decision too: a pallas winner flips ``backend`` and carries
-    (schedule, pchunk, sub_t); an xla winner carries (method, chunk, intra).
-    ``objective`` picks which sweep's winner ("fwd" | "fwdbwd") is served.
-    Returns {} on miss (→ the caller's explicit arguments stand).
-    """
-    from repro.tune import tuned       # lazy: repro.tune imports this module
-    return tuned(op, cache=None if tune == "auto" else tune,
-                 B=B, L=L, D=D, N=N, H=H, dh=dh, dtype=dtype,
-                 reset_density=None if positions is not None else 0.0,
-                 objective=objective) or {}
-
-
 def selective_scan(u, delta, A, B, C, D=None, positions=None, *,
                    backend: str = "xla", block_d: int = scan_k.DEF_BLOCK_D,
                    chunk: int = scan_k.DEF_CHUNK_T, xla_chunk: int = 256,
                    xla_method: str = "blocked", xla_dtype=None,
-                   xla_intra=None, schedule: str = "step",
-                   sub_t=None, tune=None, tune_objective: str = "fwd"):
+                   xla_intra=None):
     """Fused segmented selective scan. See kernels/ref.py for semantics.
 
     u, delta: (B, L, Dm) | A: (Dm, N) | B, C: (B, L, N) | D: (Dm,) |
     positions: (B, L) i32 (reset where == 0) → y (B, L, Dm).
 
-    ``backend``: 'xla' | 'pallas' | 'auto' (the kernel where the default
-    backend is a TPU, the XLA evaluator elsewhere — chosen while tracing,
-    as core/ssm.py picks its in-chunk evaluator, so a CPU trace holds only
-    the XLA evaluator).
-
-    ``schedule`` (pallas backend): 'step' (per-step walk, the measured
-    default — kernels/selective_scan.py) | 'blocked' (SSD-style subtile
-    contraction; ``sub_t`` overrides its subtile).
-    Both wire the same custom_vjp; ``xla_method='blocked'`` (+ optional
-    ``xla_intra``) is the XLA twin.
-
-    ``tune``: None (off) | "auto" | cache path | TuneCache — resolve every
-    knob above (backend included) from the shape-keyed tuning cache; the
-    explicit arguments are the miss fallback (repro/tune).
+    ``backend``: 'xla' — core/ssm.py's evaluators (``xla_method``,
+    ``xla_chunk``, ``xla_dtype``, ``xla_intra``) | 'pallas' — the
+    per-step walk kernel (kernels/selective_scan.py; ``block_d`` channels
+    by ``chunk`` rows a grid step) and its backward, through one
+    custom_vjp.
     """
-    if tune is not None:
-        kn = _resolve_tune("selective_scan", tune, B=u.shape[0],
-                           L=u.shape[1], D=u.shape[2], N=A.shape[-1],
-                           dtype=u.dtype, positions=positions,
-                           objective=tune_objective)
-        if kn:
-            backend = kn.get("backend", backend)
-            if backend == "pallas":
-                schedule = kn.get("schedule", schedule)
-                chunk = kn.get("pchunk", chunk)
-                sub_t = kn.get("sub_t", sub_t)
-            else:
-                xla_method = kn.get("method", xla_method)
-                xla_chunk = kn.get("chunk", xla_chunk)
-                xla_intra = kn.get("intra", xla_intra)
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "xla"
     if backend == "xla":
         return core_ssm.selective_scan(u, delta, A, B, C, D,
                                        positions=positions,
@@ -164,8 +115,7 @@ def selective_scan(u, delta, A, B, C, D=None, positions=None, *,
     if backend != "pallas":
         raise ValueError(f"unknown backend {backend!r}")
     return _scan_pallas_on_mesh(u, delta, A, B, C, D, positions,
-                                block_d=block_d, chunk=chunk,
-                                schedule=schedule, sub_t=sub_t)
+                                block_d=block_d, chunk=chunk)
 
 
 def _scan_pallas_on_mesh(u, delta, A, B, C, D, positions, **kw):
@@ -190,8 +140,7 @@ def _scan_pallas_on_mesh(u, delta, A, B, C, D, positions, **kw):
         out_specs=x, check_vma=False)(u, delta, A, B, C, D, positions)
 
 
-def _scan_pallas(u, delta, A, B, C, D, positions, *, block_d, chunk,
-                 schedule, sub_t):
+def _scan_pallas(u, delta, A, B, C, D, positions, *, block_d, chunk):
     """The scan kernel on one device's operands: pad to whole tiles, run
     the custom_vjp, slice the padding off."""
     Bz, L, Dm = u.shape
@@ -206,7 +155,7 @@ def _scan_pallas(u, delta, A, B, C, D, positions, *, block_d, chunk,
     up, dtp = _pad_to(up, 1, T), _pad_to(dtp, 1, T)
     Bp, Cp = _pad_to(B, 1, T), _pad_to(C, 1, T)
     posp = _positions(positions, Bz, L, T)
-    y = _scan_padded(up, dtp, At, Bp, Cp, Dp, posp, bd, T, schedule, sub_t)
+    y = _scan_padded(up, dtp, At, Bp, Cp, Dp, posp, bd, T)
     return y[:, :L, :Dm]
 
 
@@ -251,8 +200,7 @@ def selective_scan_heads(u, delta, A, B, C, D=None, positions=None, *,
                          chunk: int = scan_k.DEF_CHUNK_T,
                          xla_chunk: int = 64, xla_method: str = "blocked",
                          xla_dtype=None, xla_intra=None,
-                         schedule: str = "blocked_heads",
-                         sub_t=None, tune=None, tune_objective: str = "fwd"):
+                         schedule: str = "blocked_heads", sub_t=None):
     """Fused head-structured segmented selective scan (scalar per-head
     decay — Mamba-2/SSD). See core/ssm.py::selective_scan_heads for
     semantics; this wrapper adds backend dispatch.
@@ -265,24 +213,8 @@ def selective_scan_heads(u, delta, A, B, C, D=None, positions=None, *,
     head-major kernel layout ((B, H, L, dh)), pads L to the chunk, and runs
     the ``schedule`` kernels ('blocked_heads' | 'blocked_heads_dual', with
     optional subtile ``sub_t``) through a custom_vjp (the shared
-    transpose-contraction backward). ``tune`` resolves every knob —
-    backend included — from the shape-keyed tuning cache (repro/tune).
+    transpose-contraction backward).
     """
-    if tune is not None:
-        kn = _resolve_tune("selective_scan_heads", tune, B=u.shape[0],
-                           L=u.shape[1], N=B.shape[-1], H=u.shape[2],
-                           dh=u.shape[3], dtype=u.dtype, positions=positions,
-                           objective=tune_objective)
-        if kn:
-            backend = kn.get("backend", backend)
-            if backend == "pallas":
-                schedule = kn.get("schedule", schedule)
-                chunk = kn.get("pchunk", chunk)
-                sub_t = kn.get("sub_t", sub_t)
-            else:
-                xla_method = kn.get("method", xla_method)
-                xla_chunk = kn.get("chunk", xla_chunk)
-                xla_intra = kn.get("intra", xla_intra)
     if backend == "xla":
         return core_ssm.selective_scan_heads(u, delta, A, B, C, D,
                                              positions=positions,

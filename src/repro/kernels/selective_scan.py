@@ -32,29 +32,20 @@ coalesced loads. The TPU-native reformulation:
     in VMEM scratch.
 
 VMEM per grid step at the defaults (T=256, bd=128, N=16): blocks ≈ 1.5 MiB
-double-buffered. The ``step`` backward adds the recomputed states hbuf
+double-buffered. The Mamba-1 backward adds the recomputed states hbuf
 ((T+1)·N, bd), 2 MiB, and one row group's stacked (G·N, bd) tiles of
 adjoints, states and products, 256 KiB each at G 32, which Mosaic keeps
-in its own scratch; ``blocked`` adds (N, T, bd) state and adjoint
-trajectories, 2 MiB each. Both fit v5e's 16 MiB scoped default, which
-``blocked`` exceeds at T=512; neither compiles at bd=256.
+in its own scratch. It fits v5e's 16 MiB scoped default; it does not
+compile at bd=256.
 
-Schedules (``schedule=`` knob):
-  * ``step``    — per-step walk over aligned row groups, one exponential
+Schedules:
+  * Mamba-1 (per-channel decay, ``selective_scan_{fwd,bwd}_pallas``) —
+                  per-step walk over aligned row groups, one exponential
                   per (t, d, n); matches the paper's ScanOp_pack closely.
-                  The default: on a v5e its forward+backward takes 31 ms
-                  per layer at (8, 4096, 2048, N 16), forward 11.1 and
-                  backward 20.0 (52.4 with the per-step backward, when
-                  the pair took 64 ms against 140 ms for ``blocked``,
-                  115 ms at subtile 8, and 372 ms for the XLA blocked
-                  evaluator).
-  * ``blocked`` — SSD-style (Gu & Dao duality): each in-chunk subtile of
-                  length Tt is evaluated at once, per state row n, as the
-                  masked cumulative-decay contraction over (Tt, Tt, bd)
-                  (core/ssm.py::_blocked_ssm has the math). The sequential
-                  chain shrinks T→T/Tt. Backward blocks the same way
-                  (transpose contraction for the adjoint scan; elementwise
-                  grads vectorized over the chunk).
+                  On a v5e its forward+backward takes 31 ms per layer at
+                  (8, 4096, 2048, N 16), forward 11.1 and backward 20.0
+                  (52.4 with the per-step backward, when the pair took
+                  64 ms against 372 ms for the XLA blocked evaluator).
   * ``blocked_heads`` — head-structured (Mamba-2/SSD proper): grid
                   ``(B, H, L/T)``, per-head SCALAR decay, state (dh, N) per
                   head in VMEM scratch. The masked cumulative-decay matrix
@@ -68,8 +59,6 @@ Schedules (``schedule=`` knob):
                   forming the in-chunk states. Both heads schedules share
                   one backward kernel, written in the same dual form
                   (identical ckpt contract; adjoint math is schedule-free).
-                  The quad-vs-dual pick, chunk, and subtile are shape-keyed
-                  autotuner decisions (repro/tune), not constants.
 """
 from __future__ import annotations
 
@@ -84,13 +73,11 @@ import jax.experimental.pallas.tpu as pltpu
 from repro.kernels import pallas_call
 
 DEF_BLOCK_D = 128
-DEF_CHUNK_T = 256      # on a TPU v5e the `step` schedule's forward+backward
+DEF_CHUNK_T = 256      # on a TPU v5e the Mamba-1 kernel's forward+backward
 #   at (8, 4096, 2048, N 16) read 64.7 / 64.4 / 64.1 / 64.0 ms at chunks
-#   128 / 256 / 512 / 1024 with the per-step backward (not measured since),
-#   and `blocked` fits in VMEM only up to 256
-DEF_SUB_T = 16     # blocked schedule: in-chunk subtile for the M contraction
-#   (the *default* — every kernel entry takes an explicit ``sub_t`` so the
-#   shape-keyed autotuner (repro/tune) can sweep measured subtiles instead)
+#   128 / 256 / 512 / 1024 with the per-step backward (not measured since)
+DEF_SUB_T = 16     # rows per group of the Mamba-1 forward's walk, and the
+#   heads schedules' default in-chunk subtile
 
 f32 = jnp.float32
 _PARAMS = pltpu.CompilerParams(
@@ -101,14 +88,13 @@ _TN = ((0,), (0,))      # aᵀ @ b
 
 
 def _pick_subtile(T: int, sub_t=None) -> int:
-    """Subtile length for a chunk of length T: the explicit (tuned) request
-    when given, else the largest supported default dividing the chunk.
+    """Subtile length for a chunk of length T: the explicit request when
+    given, else the largest supported default dividing the chunk.
 
     A requested ``sub_t`` that does not divide T degrades to the largest
-    divisor ≤ the request instead of raising: tuned knobs resolve through
-    bucketed/nearest-key cache lookups, so a winner measured at one L can
-    legally arrive at a chunk it does not divide — the tuner must never
-    turn a working call into a trace-time error."""
+    divisor ≤ the request instead of raising, so a subtile chosen for one
+    sequence length never turns a call at another into a trace-time
+    error."""
     if sub_t:
         st = min(int(sub_t), T)
         while T % st:
@@ -208,96 +194,18 @@ def _fwd_kernel(pos_ref, u_ref, dt_ref, At_ref, Bm_ref, Cm_ref, Dp_ref,
     jax.lax.fori_loop(0, T // G, group, ())
 
 
-def _blocked_tile(pos_ref, u_ref, dt_ref, Bm_ref, Cm_ref, t0, tt):
-    """Shared per-subtile tensors of the Mamba-1 blocked schedule:
-    the rows, cdt = Σ_{k≤i} dt_k (tt, bd), rid = resets in [0, i] (tt, 1),
-    and the (tt, tt, 1) mask [j ≤ i]·[no reset in (j, i]]."""
-    dt, u, Bv, Cv, keep = _rows(pos_ref, u_ref, dt_ref, Bm_ref, Cm_ref,
-                                t0, tt)
-    incl = _tri(tt)
-    cdt = _dot(incl, dt)
-    rid = _dot(incl, (~keep).astype(f32))
-    i3 = jax.lax.broadcasted_iota(jnp.int32, (tt, tt, 1), 0)
-    j3 = jax.lax.broadcasted_iota(jnp.int32, (tt, tt, 1), 1)
-    same = (rid[:, None, :] == rid[None, :, :]) & (i3 >= j3)
-    return dt, u, Bv, Cv, keep, cdt, rid, same
-
-
-def _pair_decay(s, same):
-    """dec[i, j, :] = exp(s_i − s_j) where ``same`` else 0 — (tt, tt, bd)."""
-    diff = s[:, None, :] - s[None, :, :]
-    return jnp.where(same, jnp.exp(jnp.where(same, diff, 0.0)), 0.0)
-
-
-def _fwd_kernel_blocked(pos_ref, u_ref, dt_ref, At_ref, Bm_ref, Cm_ref,
-                        Dp_ref, y_ref, ckpt_ref, h_ref, *, sub_t):
-    """Same block shapes and carry semantics as ``_fwd_kernel``, but instead
-    of T dependent per-step VPU updates, each in-chunk subtile of length Tt
-    is evaluated at once, per state row n, via the masked cumulative-decay
-    contraction (core/ssm.py 'blocked'/'matmul' formulation):
-
-        s_i      = A_n · Σ_{k≤i} Δ_k
-        dec[i,j] = exp(s_i − s_j)·[j ≤ i]·[no reset in (j, i]]
-        h_i      = Σ_j dec[i,j]·b_j + 1[no reset ≤ i]·exp(s_i)·h_carry
-
-    The sequential chain shrinks from T steps to T/Tt subtile steps.
-    """
-    T = u_ref.shape[1]
-    N = At_ref.shape[0]
-    tt = sub_t
-
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        h_ref[...] = jnp.zeros_like(h_ref)
-
-    ckpt_ref[0, 0] = h_ref[...]
-    At = At_ref[...].astype(f32)                  # (N, bd)
-    Dp = Dp_ref[...].astype(f32)                  # (1, bd)
-
-    def sub(si, _):
-        t0 = _at(si, tt)
-        dt, u, Bv, Cv, _, cdt, rid, same = _blocked_tile(
-            pos_ref, u_ref, dt_ref, Bm_ref, Cm_ref, t0, tt)
-        du = dt * u
-        carry_in = rid == 0                       # (tt, 1)
-        h0 = h_ref[...]
-        y = Dp * u
-        for n in range(N):
-            s = cdt * At[n:n + 1]                 # (tt, bd)
-            h = jnp.sum(_pair_decay(s, same) * (Bv[:, n:n + 1] * du)[None],
-                        axis=1)                   # Σ_j
-            h = h + jnp.where(carry_in, jnp.exp(s), 0.0) * h0[n:n + 1]
-            y = y + Cv[:, n:n + 1] * h
-            h_ref[n:n + 1, :] = h[tt - 1:tt]
-        y_ref[0, pl.ds(t0, tt), :] = y.astype(y_ref.dtype)
-        return ()
-
-    jax.lax.fori_loop(0, T // tt, sub, ())
-
-
 def selective_scan_fwd_pallas(u, delta, At, Bm, Cm, Dp, positions,
                               block_d: int = DEF_BLOCK_D,
-                              chunk: int = DEF_CHUNK_T,
-                              schedule: str = "step",
-                              sub_t: Optional[int] = None):
+                              chunk: int = DEF_CHUNK_T):
     """Shapes (already padded by ops.py): u, delta (B, L, Dm); At (N, Dm);
     Bm, Cm (B, L, N); Dp (1, Dm); positions (B, L, 1) i32.
-    ``schedule``: 'step' (per-step VPU walk) | 'blocked' (SSD-style subtile
-    contraction; ``sub_t`` overrides the default subtile).
     Returns (y (B, L, Dm), ckpts (B, L/T, N, Dm))."""
     Bz, L, Dm = u.shape
     N = At.shape[0]
     T, bd = chunk, block_d
     nL, nD = L // T, Dm // bd
-    if schedule == "blocked":
-        kernel = functools.partial(_fwd_kernel_blocked,
-                                   sub_t=_pick_subtile(T, sub_t))
-    elif schedule == "step":
-        kernel = _fwd_kernel
-    else:
-        raise ValueError(f"unknown schedule {schedule!r}")
     return pallas_call(
-        kernel,
+        _fwd_kernel,
         grid=(Bz, nD, nL),
         in_specs=[
             pl.BlockSpec((1, T, 1), lambda b, d, l: (b, l, 0)),    # pos
@@ -350,7 +258,7 @@ def _lane_sums_mxu(x):
 
 
 def _bwd_group(T: int, nL: int) -> int:
-    """Rows per group of the `step` backward: the most, up to 32, that
+    """Rows per group of the Mamba-1 backward: the most, up to 32, that
     divide the chunk and keep its lane-dense (T/G, G·N) dB, dC block in
     whole 8-row tiles (or the chunk is the whole sequence). Larger groups
     give the scheduler more of the bulk work to fill the walk's chain with:
@@ -463,144 +371,25 @@ def _bwd_kernel(pos_ref, u_ref, dt_ref, At_ref, Bm_ref, Cm_ref, Dp_ref,
         dD_ref[0] = dD_acc[...]
 
 
-def _bwd_kernel_blocked(pos_ref, u_ref, dt_ref, At_ref, Bm_ref, Cm_ref,
-                        Dp_ref, ckpt_ref, dy_ref,
-                        du_ref, ddt_ref, dB_ref, dC_ref, dA_ref, dD_ref,
-                        hbuf_ref, gbuf_ref, g_ref, dA_acc, dD_acc, *, sub_t):
-    """Adjoint of one chunk under the blocked formulation. Outputs and carry
-    semantics identical to ``_bwd_kernel``; the two inner walks are blocked:
-
-      * h recompute: the forward subtile contraction refilled into hbuf.
-      * adjoint g: the reverse recurrence g_t = C_t⊗dy_t + a_{t+1}·g_{t+1}
-        is itself a segmented scan running backwards, so per subtile
-        g_j = Σ_{i≥j} dec[i,j]·(C⊗dy)_i + dec[last,j]·G_in — the transpose
-        contraction of the same masked decay matrix, with the VMEM carry
-        G = a_first·g_first handed to the previous subtile/chunk.
-
-    The per-position parameter/input adjoints are then elementwise
-    (T, bd) work per state row n — no sequential walk at all.
-    Scratch: hbuf, gbuf (N, T, bd) — the post-step states and adjoints.
-    """
-    T = u_ref.shape[1]
-    N, bd = At_ref.shape
-    tt = sub_t
-    nsub = T // tt
-
-    @pl.when(pl.program_id(2) == 0)          # first step of the REVERSE walk
-    def _init():
-        g_ref[...] = jnp.zeros_like(g_ref)
-        dA_acc[...] = jnp.zeros_like(dA_acc)
-        dD_acc[...] = jnp.zeros_like(dD_acc)
-
-    At = At_ref[...].astype(f32)
-    Dp = Dp_ref[...].astype(f32)
-    h_entry = ckpt_ref[0, 0]                        # (N, bd)
-
-    # ---- recompute h within the chunk, blocked per subtile ----
-    def fsub(si, _):
-        t0 = _at(si, tt)
-        dt, u, Bv, _, _, cdt, rid, same = _blocked_tile(
-            pos_ref, u_ref, dt_ref, Bm_ref, Cm_ref, t0, tt)
-        du = dt * u
-        carry_in = rid == 0
-        for n in range(N):
-            s = cdt * At[n:n + 1]
-            h0 = jnp.where(si == 0, h_entry[n:n + 1],
-                           hbuf_ref[n, pl.ds(jnp.maximum(t0 - 1, 0), 1), :])
-            h = jnp.sum(_pair_decay(s, same) * (Bv[:, n:n + 1] * du)[None],
-                        axis=1)
-            h = h + jnp.where(carry_in, jnp.exp(s), 0.0) * h0
-            hbuf_ref[n, pl.ds(t0, tt), :] = h
-        return ()
-
-    jax.lax.fori_loop(0, nsub, fsub, ())
-
-    # ---- reverse adjoint walk, blocked per subtile ----
-    def rsub(k, _):
-        t0 = _at(nsub - 1 - k, tt)
-        dt, _, _, Cv, keep, cdt, rid, same = _blocked_tile(
-            pos_ref, u_ref, dt_ref, Bm_ref, Cm_ref, t0, tt)
-        dy = dy_ref[0, pl.ds(t0, tt), :].astype(f32)
-        to_last = rid == rid[tt - 1:tt]           # no reset in (j, last]
-        G = g_ref[...]
-        for n in range(N):
-            s = cdt * At[n:n + 1]
-            c = Cv[:, n:n + 1] * dy                               # (tt, bd)
-            g = jnp.sum(_pair_decay(s, same) * c[:, None, :], axis=0)
-            g = g + jnp.where(to_last, jnp.exp(s[tt - 1:tt] - s), 0.0) * \
-                G[n:n + 1]                                # carry M[last,j]
-            gbuf_ref[n, pl.ds(t0, tt), :] = g
-            a0 = jnp.where(keep[0:1], jnp.exp(dt[0:1] * At[n:n + 1]), 0.0)
-            g_ref[n:n + 1, :] = a0 * g[0:1]               # hand to t0 − 1
-        return ()
-
-    jax.lax.fori_loop(0, nsub, rsub, ())
-
-    # ---- elementwise adjoints, vectorized over the whole chunk ----
-    dt = dt_ref[0].astype(f32)                      # (T, bd)
-    u = u_ref[0].astype(f32)
-    dy = dy_ref[0].astype(f32)
-    Bv = Bm_ref[0].astype(f32)                      # (T, N)
-    keep = pos_ref[0] != 0                          # (T, 1)
-    du = dt * u
-    first = _rowsel(T, bd) == 0
-    gB = jnp.zeros_like(dt)
-    ddt = jnp.zeros_like(dt)
-    for n in range(N):
-        a = jnp.where(keep, jnp.exp(dt * At[n:n + 1]), 0.0)
-        h = hbuf_ref[n]                                  # h_t
-        h_prev = jnp.where(first, h_entry[n:n + 1], pltpu.roll(h, 1, 0))
-        g = gbuf_ref[n]
-        da = g * h_prev * a
-        ddt = ddt + da * At[n:n + 1]
-        gB = gB + g * Bv[:, n:n + 1]
-        dB_ref[0, 0, :, n:n + 1] = jnp.sum(
-            g * du, axis=1, keepdims=True).astype(dB_ref.dtype)
-        dC_ref[0, 0, :, n:n + 1] = jnp.sum(
-            h * dy, axis=1, keepdims=True).astype(dC_ref.dtype)
-        dA_acc[n:n + 1, :] += jnp.sum(da * dt, axis=0, keepdims=True)
-    du_ref[0] = (dt * gB + Dp * dy).astype(du_ref.dtype)
-    ddt_ref[0] = (ddt + u * gB).astype(ddt_ref.dtype)
-    dD_acc[...] += jnp.sum(dy * u, axis=0, keepdims=True)
-
-    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
-    def _flush():
-        dA_ref[0] = dA_acc[...]
-        dD_ref[0] = dD_acc[...]
-
-
 def selective_scan_bwd_pallas(u, delta, At, Bm, Cm, Dp, positions, ckpts, dy,
                               block_d: int = DEF_BLOCK_D,
-                              chunk: int = DEF_CHUNK_T,
-                              schedule: str = "step",
-                              sub_t: Optional[int] = None):
+                              chunk: int = DEF_CHUNK_T):
     """Returns (du, ddelta, dB_partial, dC_partial, dA_partial (B,N,Dm),
     dD_partial (B,1,Dm)). The dB, dC partials hold (B, nD, L·N) values in
-    (t, n) row-major order: shaped (B,nD,L,N) for ``blocked``, lane-dense
-    (B,nD,L/G,G·N) for ``step`` (G its row group)."""
+    (t, n) row-major order, lane-dense as (B, nD, L/G, G·N) (G the row
+    group, ``_bwd_group``)."""
     Bz, L, Dm = u.shape
     N = At.shape[0]
     T, bd = chunk, block_d
     nL, nD = L // T, Dm // bd
     rev = lambda l: nL - 1 - l                 # walk the L dimension backwards
-    if schedule == "blocked":
-        kernel = functools.partial(_bwd_kernel_blocked,
-                                   sub_t=_pick_subtile(T, sub_t))
-        traj = [pltpu.VMEM((N, T, bd), f32),   # recomputed h trajectory
-                pltpu.VMEM((N, T, bd), f32)]   # adjoint trajectory g
-        part = (L, N)
-        part_spec = pl.BlockSpec((1, 1, T, N),
-                                 lambda b, d, l: (b, d, rev(l), 0))
-    elif schedule == "step":
-        G = _bwd_group(T, nL)
-        kernel = functools.partial(_bwd_kernel, G=G)
-        traj = [pltpu.VMEM(((T + 1) * N, bd), f32)]   # recomputed h
-        part = (L // G, G * N)
-        part_spec = pl.BlockSpec((1, 1, T // G, G * N),
-                                 lambda b, d, l: (b, d, rev(l), 0))
-    else:
-        raise ValueError(f"unknown schedule {schedule!r}")
-    scratch = traj + [
+    G = _bwd_group(T, nL)
+    kernel = functools.partial(_bwd_kernel, G=G)
+    part = (L // G, G * N)
+    part_spec = pl.BlockSpec((1, 1, T // G, G * N),
+                             lambda b, d, l: (b, d, rev(l), 0))
+    scratch = [
+        pltpu.VMEM(((T + 1) * N, bd), f32),    # recomputed h
         pltpu.VMEM((N, bd), f32),              # adjoint carry
         pltpu.VMEM((N, bd), f32),              # dA accumulator
         pltpu.VMEM((1, bd), f32),              # dD accumulator
@@ -728,7 +517,7 @@ def _fwd_kernel_blocked_heads_dual(pos_ref, u_ref, dt_ref, A_ref, Bm_ref,
         h_new    = (wl ⊙ x)ᵀ @ B  +  c[last]·h_in
 
     FLOPs Tt²·(N + P) + Tt·P·N vs the state form's Tt²·P·N — the measured
-    winner when dh ≫ Tt (see repro/tune; core/ssm.py has the XLA math).
+    winner when dh ≫ Tt (core/ssm.py has the XLA math).
     """
     T = u_ref.shape[2]
     tt = sub_t

@@ -61,25 +61,8 @@ VARIANTS = {
     "act_sp+accum2+moe8k": {"act_pspec": ("auto",), "__accum__": 2,
                             "moe_token_chunk": 8192},
     "act_sp+moe8k": {"act_pspec": ("auto",), "moe_token_chunk": 8192},
-    # It-10: scan knobs read from the measured tuning cache
-    # (TUNE_CACHE.json, see repro/tune) instead of hand-derived combos —
-    # resolved per (arch, shape) at run time by tuned_overrides(); an empty
-    # or stale cache degrades to baseline
-    "tuned": {},
 }
 
-
-def tuned_overrides(arch: str, shape: str) -> dict:
-    """ArchConfig overrides for the ``tuned`` variant: the tuning cache's
-    measured winner for this arch's scan op at this cell's (batch, seq)."""
-    from repro.configs.base import get_config
-    from repro.launch.shapes import SHAPES as _S
-    from repro.tune import tuned_config_overrides
-    s = _S[shape]
-    ov = tuned_config_overrides(get_config(arch), B=s["batch"], L=s["seq"])
-    if not ov:
-        print(f"  (tuned: no cache entry for {arch}:{shape} — baseline)")
-    return ov
 
 # the three hillclimbed cells (DESIGN.md §Perf) + the paper-faithful extra
 HILLCLIMB = [
@@ -89,27 +72,19 @@ HILLCLIMB = [
     ("gemma-7b", "prefill_32k", ["act_sp"]),
     ("mamba-2.8b", "train_4k",
      ["act_dp", "scan_bf16", "act_dp+scan_bf16", "scan_chunked",
-      "scan_blocked+bf16", "tuned"]),
+      "scan_blocked+bf16"]),
     # It-9: head-structured (Mamba-2/SSD) variant at matched packed shapes —
     # tracks the per-head vs per-channel schedule gap across PRs
     ("mamba2-370m", "train_4k",
-     ["baseline", "act_dp", "scan_bf16", "act_dp+scan_bf16", "tuned"]),
+     ["baseline", "act_dp", "scan_bf16", "act_dp+scan_bf16"]),
 ]
 
 
 def run_variant(arch, shape, variant, out="experiments/perf",
                 multi_pod=False):
-    label = variant
-    if variant == "tuned":
-        overrides = tuned_overrides(arch, shape)
-        if not overrides:
-            # don't let a baseline-identical row masquerade as tuned in the
-            # persisted perf series — the miss is visible in the label
-            label = "tuned:miss(baseline)"
-    else:
-        overrides = VARIANTS[variant]
+    overrides = VARIANTS[variant]
     rec = run_cell(arch, shape, multi_pod, out_dir=None, overrides=overrides)
-    rec["variant"] = label
+    rec["variant"] = variant
     rec["overrides"] = {k: v for k, v in overrides.items()
                         if k != "__accum__"}      # audit what was applied
     os.makedirs(out, exist_ok=True)

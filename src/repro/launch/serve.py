@@ -390,23 +390,6 @@ class ServeEngine:
         self.refill_threshold = max(1, num_slots // 2) \
             if refill_threshold is None else refill_threshold
 
-        cfg = getattr(model, "cfg", None)
-        if cfg is not None and getattr(cfg, "scan_tune", "off") != "off":
-            # warm the scan autotuning cache for every prefill shape this
-            # engine can compile — (prefill_rows, bucket) plus the chunk
-            # slab — so the packed forwards resolve measured schedule
-            # winners at trace time
-            from repro.tune import warm_for_config
-            shapes = [(prefill_rows, b) for b in self.buckets]
-            if self.chunk_enabled:
-                # the chunk lane's slab widths are dynamic now: any bucket
-                # ≤ chunk_size (packing.slab_width), not just the full slab
-                for w in sorted({b for b in self.buckets
-                                 if b <= self.chunk_size}
-                                | {self.chunk_size}):
-                    shapes.append((self.chunk_rows, w))
-            warm_for_config(cfg, shapes)
-
         self.cache = model.init_cache(num_slots, max_len)
         self.cache_len = jnp.zeros((num_slots,), jnp.int32)
         self.cur_tok = jnp.zeros((num_slots, 1), jnp.int32)
@@ -1944,10 +1927,6 @@ def main():
                     help="sampling temperature for every request (0=greedy)")
     ap.add_argument("--top-k", type=int, default=0)
     ap.add_argument("--top-p", type=float, default=1.0)
-    ap.add_argument("--scan-tune", default="off",
-                    help="off | auto | <cache path>: shape-keyed scan "
-                         "autotuning (the engine warms the cache for its "
-                         "prefill buckets at startup)")
     ap.add_argument("--obs-trace", default=None, metavar="PATH",
                     help="record request-lifecycle spans and export a "
                          "Chrome trace-event JSON here (open in "
@@ -1962,8 +1941,6 @@ def main():
     if args.tiny:
         cfg = dataclasses.replace(cfg, d_model=128, n_layers=4, vocab=512,
                                   dtype="float32", scan_chunk=64)
-    if args.scan_tune != "off":
-        cfg = dataclasses.replace(cfg, scan_tune=args.scan_tune)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     obs = Obs.on() if args.obs_trace else Obs.off()

@@ -65,10 +65,6 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--model-axis", type=int, default=1,
                     help="TP size on the local mesh")
-    ap.add_argument("--scan-tune", default="off",
-                    help="off | auto | <cache path>: shape-keyed scan "
-                         "autotuning (repro/tune); the cache is warmed for "
-                         "the training shape before the first step")
     ap.add_argument("--dry-run", action="store_true",
                     help="lower+compile on the 16x16 production mesh")
     ap.add_argument("--obs-trace", default=None, metavar="PATH",
@@ -97,17 +93,6 @@ def main():
         cfg = dataclasses.replace(
             cfg, dtype=args.dtype or cfg.dtype,
             param_dtype=args.param_dtype or cfg.param_dtype)
-    if args.scan_tune != "off":
-        # measure-or-load the scan schedule winners for THIS run's shape
-        # bucket before any step compiles — the model then resolves its
-        # scan knobs from the cache at trace time (configs/base.py).
-        # objective="fwdbwd": this is a training launcher, so the sweep
-        # times forward+backward and the step resolves those winners.
-        cfg = dataclasses.replace(cfg, scan_tune=args.scan_tune,
-                                  tune_objective="fwdbwd")
-        from repro.tune import warm_for_config
-        warm_for_config(cfg, [(args.rows, args.seq_len)],
-                        objective="fwdbwd")
     model = build_model(cfg)
     obs = Obs.on() if args.obs_trace else Obs.off()
     corpus = SyntheticCorpus(CorpusConfig(vocab=cfg.vocab, seed=0))

@@ -386,30 +386,32 @@ def init_mamba(key, cfg: ArchConfig) -> Dict[str, Any]:
     }
 
 
-def _cfg_tune(cfg: ArchConfig):
-    """ArchConfig.scan_tune → the ``tune=`` argument of the scan entry
-    points (None keeps every call site bit-identical to the pre-tuner
-    code path)."""
-    return None if cfg.scan_tune == "off" else cfg.scan_tune
+def _scan_backend(cfg: ArchConfig, A) -> str:
+    """Backend of a block's sequence scan, 'pallas' or 'xla': the one place
+    that chooses it. Read while tracing, so a CPU trace holds only the XLA
+    evaluator.
 
+    | decay ``A``            | use_pallas | scan_dtype | platform | backend |
+    | ---------------------- | ---------- | ---------- | -------- | ------- |
+    | (D, N), Mamba-1        | None       | float32    | TPU      | pallas  |
+    | (D, N), Mamba-1        | None       | float32    | other    | xla     |
+    | (D, N), Mamba-1        | None       | other      | any      | xla     |
+    | (H,), Mamba-2 heads    | None       | any        | any      | xla     |
+    | any                    | True       | any        | any      | pallas  |
+    | any                    | False      | any        | any      | xla     |
 
-def _tune_kw(cfg: ArchConfig):
-    """The scan entry points' tuning kwargs: cache identity plus which
-    sweep objective's winners to resolve (fwd vs fwdbwd — training configs
-    set tune_objective="fwdbwd")."""
-    return {"tune": _cfg_tune(cfg), "tune_objective": cfg.tune_objective}
-
-
-def _scan_backend(cfg: ArchConfig, A):
-    """Backend of a block's sequence scan. ``use_pallas`` True or False
-    stands as given. Unset (None), the decay's form decides: a per-channel
-    decay (A (D, N), Mamba-1) at float32 state, the kernel's precision,
-    runs 'auto' — the Pallas kernel where the default backend is a TPU,
-    the XLA evaluator elsewhere — and scalar-decay heads (A (H,)) keep the
-    XLA evaluator, whose blocked form is matmul-shaped."""
+    Unset, the kernel runs only where it is compiled (a TPU) and at its own
+    precision (float32 state); scalar-decay heads keep the XLA evaluator,
+    whose blocked form is matmul-shaped. True runs the kernels everywhere
+    (Mosaic on a TPU, interpreted elsewhere). The state-collecting prefills
+    (``collect`` in apply_mamba/apply_mamba2, chunk_mamba/chunk_mamba2)
+    call core/ssm.py directly, so they run the XLA evaluator whatever this
+    says; its in-chunk form is core/ssm.py's choice.
+    """
     if cfg.use_pallas is not None:
         return "pallas" if cfg.use_pallas else "xla"
-    return "auto" if A.ndim == 2 and cfg.scan_dtype == "float32" else "xla"
+    return "pallas" if (A.ndim == 2 and cfg.scan_dtype == "float32" and
+                        jax.default_backend() == "tpu") else "xla"
 
 
 def _mamba_in(p, x, cfg: ArchConfig):
@@ -464,8 +466,7 @@ def apply_mamba(p, x, ctx: Ctx, cfg: ArchConfig, collect: int = 0,
             y, h_ends = core_ssm.selective_scan(
                 x_c, delta, A, Bm, Cm, p["D"], positions=ctx.positions,
                 method=cfg.scan_impl, chunk=cfg.scan_chunk,
-                intra=cfg.scan_intra, collect_ends=collect_ends,
-                **_tune_kw(cfg))
+                intra=cfg.scan_intra, collect_ends=collect_ends)
         with jax.named_scope("mamba.conv"):
             conv = _conv_tail_ends(x_in, collect_ends,
                                    _ends_lens(ctx, collect_ends), cfg.d_conv)
@@ -482,7 +483,7 @@ def apply_mamba(p, x, ctx: Ctx, cfg: ArchConfig, collect: int = 0,
             y, h_last = core_ssm.selective_scan(
                 x_c, delta, A, Bm, Cm, p["D"], positions=pos_nz,
                 method=cfg.scan_impl, chunk=cfg.scan_chunk,
-                return_state=True, intra=cfg.scan_intra, **_tune_kw(cfg))
+                return_state=True, intra=cfg.scan_intra)
         with jax.named_scope("mamba.conv"):
             conv = _conv_tail(x_in, valid.sum(-1), cfg.d_conv)
         return _mamba_out(p, x, y, z), {"conv": conv, "ssm": h_last}
@@ -496,9 +497,7 @@ def apply_mamba(p, x, ctx: Ctx, cfg: ArchConfig, collect: int = 0,
                                 xla_dtype=(None
                                            if cfg.scan_dtype == "float32"
                                            else cfg.scan_dtype),
-                                xla_intra=cfg.scan_intra,
-                                schedule=cfg.pallas_schedule,
-                                **_tune_kw(cfg))
+                                xla_intra=cfg.scan_intra)
     return _mamba_out(p, x, y, z)
 
 
@@ -600,7 +599,7 @@ def apply_mamba2(p, x, ctx: Ctx, cfg: ArchConfig, collect: int = 0,
         y, h_ends = core_ssm.selective_scan_heads(
             u_h, delta, A, Bm, Cm, p["D"], positions=ctx.positions,
             method="blocked", chunk=cfg.scan_chunk, intra=cfg.scan_intra,
-            collect_ends=collect_ends, **_tune_kw(cfg))
+            collect_ends=collect_ends)
         state = {"conv": _conv_tail_ends(x_in, collect_ends,
                                          _ends_lens(ctx, collect_ends),
                                          cfg.d_conv),
@@ -617,7 +616,7 @@ def apply_mamba2(p, x, ctx: Ctx, cfg: ArchConfig, collect: int = 0,
         y, h_last = core_ssm.selective_scan_heads(
             u_h, delta, A, Bm, Cm, p["D"], positions=pos_nz,
             method="blocked", chunk=cfg.scan_chunk, return_state=True,
-            intra=cfg.scan_intra, **_tune_kw(cfg))
+            intra=cfg.scan_intra)
         state = {"conv": _conv_tail(x_in, valid.sum(-1), cfg.d_conv),
                  "ssm": h_last}
         y = _mamba2_gate_out(p, y.reshape(B, L, di), z, cfg)
@@ -629,8 +628,7 @@ def apply_mamba2(p, x, ctx: Ctx, cfg: ArchConfig, collect: int = 0,
                                   xla_dtype=(None
                                              if cfg.scan_dtype == "float32"
                                              else cfg.scan_dtype),
-                                  xla_intra=cfg.scan_intra,
-                                  **_tune_kw(cfg))
+                                  xla_intra=cfg.scan_intra)
     y = _mamba2_gate_out(p, y.reshape(B, L, di), z, cfg)
     return x + y @ p["out_proj"].astype(x.dtype)
 
@@ -1018,7 +1016,7 @@ def chunk_mamba(p, x, cache, ctx: Ctx, cfg: ArchConfig):
         y, h_last = core_ssm.selective_scan(
             x_c, delta, A, Bm, Cm, p["D"], positions=pos_nz,
             method=cfg.scan_impl, chunk=cfg.scan_chunk, return_state=True,
-            h0=cache["ssm"], intra=cfg.scan_intra, **_tune_kw(cfg))
+            h0=cache["ssm"], intra=cfg.scan_intra)
     with jax.named_scope("mamba.conv"):
         ext = jnp.concatenate([cache["conv"].astype(x_in.dtype), x_in],
                               axis=1)
@@ -1044,8 +1042,7 @@ def chunk_mamba2(p, x, cache, ctx: Ctx, cfg: ArchConfig):
     y, h_last = core_ssm.selective_scan_heads(
         x_c.reshape(B, T, H, P), delta, A, Bm, Cm, p["D"],
         positions=pos_nz, method="blocked", chunk=cfg.scan_chunk,
-        return_state=True, h0=cache["ssm"], intra=cfg.scan_intra,
-        **_tune_kw(cfg))
+        return_state=True, h0=cache["ssm"], intra=cfg.scan_intra)
     ext = jnp.concatenate([cache["conv"].astype(x_in.dtype), x_in], axis=1)
     state = {"conv": _conv_tail(ext, (W - 1) + valid.sum(-1), W),
              "ssm": h_last}

@@ -1,10 +1,13 @@
 """benchmarks/compare.py CLI contracts: the --accept baseline promotion
-(staging .new.json → committed baseline, staging file removed) and the
---schema structural check the CI bench smoke gates on."""
+(staging .new.json → committed baseline, staging file removed), the
+--schema structural check the CI bench smoke gates on, and one report of
+every offender; benchmarks/timing.py's interleaved min-of-rounds."""
 import json
 import os
 import subprocess
 import sys
+
+import pytest
 
 REPO = os.path.join(os.path.dirname(__file__), "..")
 COMPARE = os.path.join(REPO, "benchmarks", "compare.py")
@@ -90,3 +93,71 @@ def test_schema_ok_and_violations(tmp_path):
     _write(empty, [])
     assert _run("--schema", str(empty)).returncode == 1
     assert _run("--schema", str(tmp_path / "missing.json")).returncode == 1
+
+
+def test_interleaved_min_of_rounds_injectable_clock():
+    from benchmarks.timing import interleaved_min_of_rounds
+    t = [0.0]
+    # fake clock: "a" costs 10us, "b" costs 5us, with a drifty round
+    costs = iter([10e-6, 5e-6, 30e-6, 25e-6, 10e-6, 5e-6])
+
+    def clock():
+        return t[0]
+
+    calls = {"a": 0, "b": 0}
+
+    def mk(name):
+        def thunk():
+            calls[name] += 1
+            t[0] += next(costs)
+            return name
+        return thunk
+
+    best, last = interleaved_min_of_rounds(
+        [("a", mk("a")), ("b", mk("b"))], rounds=3, warmup=0,
+        clock=clock, sync=lambda x: x)
+    assert calls == {"a": 3, "b": 3}
+    assert best["a"] == pytest.approx(10.0)       # min over rounds, in us
+    assert best["b"] == pytest.approx(5.0)
+    assert last == {"a": "a", "b": "b"}
+
+
+def test_compare_reports_all_offenders(tmp_path):
+    import benchmarks.compare as cmp
+    old = [{"op": "s", "shape": "a", "schedule": "x", "us_per_call": 100.0,
+            "tok_per_s": 1},
+           {"op": "s", "shape": "a", "schedule": "y", "us_per_call": 100.0,
+            "tok_per_s": 1},
+           {"op": "s", "shape": "a", "schedule": "z", "us_per_call": 100.0,
+            "tok_per_s": 1}]
+    new = [dict(r, us_per_call=us) for r, us in
+           zip(old, (150.0, 95.0, 200.0))]
+    po, pn = str(tmp_path / "o.json"), str(tmp_path / "n.json")
+    json.dump(old, open(po, "w"))
+    json.dump(new, open(pn, "w"))
+    lines, offenders = cmp.compare(po, pn, pct=10.0)
+    # BOTH regressions reported in one pass, plus the header + ok row
+    assert len(offenders) == 2
+    assert {o[0] for o in offenders} == {"s/a/x", "s/a/z"}
+    assert any("ok" in ln and "s/a/y" in ln for ln in lines)
+
+
+def test_compare_missing_required_still_reports_other_pairs(tmp_path,
+                                                            capsys,
+                                                            monkeypatch):
+    """A missing required pair fails the gate but must not hide offenders
+    in the remaining pairs (one run surfaces everything)."""
+    import benchmarks.compare as cmp
+    row = {"op": "s", "shape": "a", "schedule": "x", "tok_per_s": 1}
+    po, pn = str(tmp_path / "o.json"), str(tmp_path / "n.json")
+    json.dump([dict(row, us_per_call=100.0)], open(po, "w"))
+    json.dump([dict(row, us_per_call=200.0)], open(pn, "w"))
+    absent = str(tmp_path / "absent.json")
+    monkeypatch.setattr("sys.argv", ["compare.py", "--pair", absent, absent,
+                                     "--pair", po, pn])
+    with pytest.raises(SystemExit) as e:
+        cmp.main()
+    assert e.value.code == 1
+    out = capsys.readouterr().out
+    assert "MISSING required" in out
+    assert "s/a/x" in out and "+100.0%" in out     # 2nd pair still compared

@@ -5,8 +5,9 @@ Covers the acceptance surface of the block-parallel schedule:
     resets, chunk not dividing L, segments straddling chunk boundaries
   * ``core.ssm.selective_scan(method='blocked')`` fwd + grads, both
     in-chunk evaluators ('matmul' einsum and 'assoc' tree), h0 carry
-  * the Pallas ``schedule='step'`` and ``'blocked'`` kernels (interpret
-    mode) fwd + grads, resets on a chunk's first row included
+  * the Pallas scan kernel (interpret mode) fwd + grads, resets on a
+    chunk's first row included, bf16 operands, and a packed row equal to
+    its documents scanned alone; the backward's row-group rule
   * structural memory claim: no (B, L, D, N) intermediate in the jaxpr
 """
 import numpy as np
@@ -179,57 +180,85 @@ def test_blocked_jaxpr_has_no_full_trajectory():
 
 
 # ---------------------------------------------------------------------------
-# Pallas blocked kernels (interpret mode)
+# Pallas scan kernel (interpret mode)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("schedule", ["step", "blocked"])
-@pytest.mark.parametrize("Bz,L,Dm,N", [(2, 24, 10, 4), (1, 33, 8, 16)])
-def test_pallas_schedules_fwd(rng, schedule, Bz, L, Dm, N):
+@pytest.mark.parametrize("Bz,L,Dm,N,block_d,chunk", [
+    (2, 24, 10, 4, 8, 8),
+    (1, 33, 8, 16, 8, 8),
+    # three channel blocks, the last chunk padded (40 = 16 + 16 + 8)
+    (2, 40, 24, 4, 8, 16),
+    (1, 100, 16, 16, 128, 32),
+])
+def test_pallas_scan_fwd(rng, Bz, L, Dm, N, block_d, chunk):
     u, dt, A, Bm, Cm, Dk, pos = _ssm_inputs(rng, Bz, L, Dm, N)
     y_ref = selective_scan_ref(u, dt, A, Bm, Cm, Dk, pos)
     y = kops_scan(u, dt, A, Bm, Cm, Dk, pos, backend="pallas",
-                  block_d=8, chunk=8, schedule=schedule)
+                  block_d=block_d, chunk=chunk)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=1e-4)
 
 
-def _pallas_grads_match_ref(args, pos, schedule, **kw):
+def _pallas_grads_match_ref(args, pos, of_max=None, **kw):
     """Gradients of Σ y² through the Pallas custom_vjp against kernels/ref.py
-    (the naive f32 recurrence), for u, Δ, A, B, C, D."""
+    (the naive f32 recurrence on the same operands, upcast), for u, Δ, A, B,
+    C, D: elementwise at f32, or with ``of_max`` each leaf's largest error
+    within that share of the reference leaf's largest magnitude."""
     def lp(*a):
-        return (kops_scan(*a, pos, backend="pallas", schedule=schedule,
-                          **kw) ** 2).sum()
+        return (kops_scan(*a, pos, backend="pallas", **kw)
+                .astype(jnp.float32) ** 2).sum()
 
     def lr(*a):
+        a = [x.astype(jnp.float32) for x in a]
         return (selective_scan_ref(*a, pos) ** 2).sum()
 
     gp = jax.grad(lp, argnums=tuple(range(6)))(*args)
     gr = jax.grad(lr, argnums=tuple(range(6)))(*args)
     for name, a, b in zip("u dt A B C D".split(), gp, gr):
-        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-3,
-                                   err_msg=f"grad {name}")
+        a, b = np.asarray(a, np.float32), np.asarray(b)
+        if of_max is None:
+            np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-3,
+                                       err_msg=f"grad {name}")
+        else:
+            err, top = np.abs(a - b).max(), np.abs(b).max()
+            assert err <= of_max * top, (name, err, top)
 
 
-@pytest.mark.parametrize("schedule", ["step", "blocked"])
-def test_pallas_blocked_grads(rng, schedule):
-    Bz, L, Dm, N = 2, 24, 10, 4
+@pytest.mark.parametrize("Bz,L,Dm,N,dtype", [
+    pytest.param(2, 24, 10, 4, "float32", id="step"),
+    # under one chunk, and not a whole number of 8-row sublane groups
+    pytest.param(3, 17, 5, 3, "float32", id="step-short"),
+    # bf16 activations, as the train cells run the kernel (A, D stay f32)
+    pytest.param(2, 24, 10, 4, "bfloat16", id="step-bf16"),
+])
+def test_pallas_blocked_grads(rng, Bz, L, Dm, N, dtype):
     u, dt, A, Bm, Cm, Dk, pos = _ssm_inputs(rng, Bz, L, Dm, N)
-    _pallas_grads_match_ref((u, dt, A, Bm, Cm, Dk), pos, schedule,
-                            block_d=8, chunk=8)
+    if dtype == "float32":
+        _pallas_grads_match_ref((u, dt, A, Bm, Cm, Dk), pos,
+                                block_d=8, chunk=8)
+        return
+    bf = lambda x: x.astype(jnp.bfloat16)
+    # the reference runs on the same bf16-rounded operands in f32; the
+    # kernel returns y and the gradients of u, Δ, B, C in bf16 (8-bit
+    # mantissa), so a leaf may be off by a few of its largest value's ulps
+    _pallas_grads_match_ref((bf(u), bf(dt), A, bf(Bm), bf(Cm), Dk), pos,
+                            of_max=2e-2, block_d=8, chunk=8)
 
 
-@pytest.mark.parametrize("schedule,L,Dm,N,block_d,chunk,starts", [
-    pytest.param("step", 64, 8, 4, 8, 16, ([0, 5, 48], [0, 16, 40]),
-                 id="step"),
-    pytest.param("blocked", 64, 8, 4, 8, 16, ([0, 5, 48], [0, 16, 40]),
-                 id="blocked"),
+@pytest.mark.parametrize("L,Dm,N,block_d,chunk,starts", [
+    pytest.param(64, 8, 4, 8, 16, ([0, 5, 48], [0, 16, 40]), id="step"),
     # the chip's tile: block_d 128, N 16, chunk 256 (the backward's row
     # groups of 32); a reset on the second chunk's first row and one inside
     # a row group
-    pytest.param("step", 512, 128, 16, 128, 256, ([0, 104, 256],),
+    pytest.param(512, 128, 16, 128, 256, ([0, 104, 256],),
                  id="step-chip-tile"),
+    # chunk 128 over two chunks: the backward's row groups of 16
+    # (_bwd_group(128, 2)); resets on the second chunk's first row and
+    # inside a group
+    pytest.param(256, 128, 16, 128, 128, ([0, 40, 128],),
+                 id="step-chunk128"),
 ])
-def test_pallas_grads_document_across_chunks(rng, schedule, L, Dm, N,
-                                             block_d, chunk, starts):
+def test_pallas_grads_document_across_chunks(rng, L, Dm, N, block_d, chunk,
+                                             starts):
     """Documents cross chunk boundaries, and resets fall on a chunk's first
     row and inside it: the backward's adjoint carry must pass the first and
     stop at the others. In the small case documents cross the boundaries
@@ -242,19 +271,97 @@ def test_pallas_grads_document_across_chunks(rng, schedule, L, Dm, N,
         for s0, s1 in zip(cuts, cuts[1:] + [L]):
             pos[b, s0:s1] = np.arange(s1 - s0)
     _pallas_grads_match_ref((u, dt, A, Bm, Cm, Dk), jnp.asarray(pos),
-                            schedule, block_d=block_d, chunk=chunk)
+                            block_d=block_d, chunk=chunk)
 
 
-@pytest.mark.parametrize("schedule", ["step", "blocked"])
-def test_pallas_blocked_reset_blocks_grad(rng, schedule):
-    u, dt, A, Bm, Cm, Dk, _ = _ssm_inputs(rng, 1, 16, 8, 4)
-    pos = jnp.concatenate([jnp.arange(8), jnp.arange(8)])[None]
+@pytest.mark.parametrize("L,Dm,cut,block_d,chunk", [
+    pytest.param(16, 8, 8, 8, 8, id="step"),
+    # the reset on the first row of the second chunk, channels over two
+    # blocks of 8
+    pytest.param(32, 16, 16, 8, 16, id="step-two-blocks"),
+])
+def test_pallas_blocked_reset_blocks_grad(rng, L, Dm, cut, block_d, chunk):
+    u, dt, A, Bm, Cm, Dk, _ = _ssm_inputs(rng, 1, L, Dm, 4)
+    pos = jnp.concatenate([jnp.arange(cut), jnp.arange(L - cut)])[None]
 
     def loss(u_in):
         y = kops_scan(u_in, dt, A, Bm, Cm, Dk, pos, backend="pallas",
-                      block_d=8, chunk=8, schedule=schedule)
-        return (y[:, 8:] ** 2).sum()
+                      block_d=block_d, chunk=chunk)
+        return (y[:, cut:] ** 2).sum()
 
     g = jax.grad(loss)(u)
-    np.testing.assert_allclose(g[:, :8], 0.0, atol=1e-7)
-    assert float(jnp.abs(g[:, 8:]).max()) > 0
+    np.testing.assert_allclose(g[:, :cut], 0.0, atol=1e-7)
+    assert float(jnp.abs(g[:, cut:]).max()) > 0
+
+
+_DOCS = (0, 11, 40, 41, 70)          # document starts in one row of 96
+
+
+def _docs_alone(f, L, u, dt, Bm, Cm):
+    """``f(u, dt, Bm, Cm, positions)`` with each document of the packed row
+    alone in a row of its own, from a zero state (positions 0.., zeros
+    after it), and the outputs gathered back into the packed row."""
+    starts = np.asarray(_DOCS)
+    lens = np.diff(np.append(starts, L))
+    t = np.arange(lens.max())
+    take = np.minimum(starts[:, None] + t, L - 1)         # (docs, T)
+    keep = jnp.asarray(t < lens[:, None])[..., None]
+    rows = [jnp.where(keep, x[0][take], 0) for x in (u, dt, Bm, Cm)]
+    pos = jnp.broadcast_to(jnp.arange(len(t), dtype=jnp.int32), take.shape)
+    y = f(*rows, pos)
+    doc = np.searchsorted(starts, np.arange(L), side="right") - 1
+    return y[doc, np.arange(L) - starts[doc]][None]
+
+
+@pytest.mark.parametrize("direction", ["fwd", "grad"])
+def test_pallas_packed_row_equals_documents_alone(rng, direction):
+    """PackMamba's property on the kernel the train cells run: a packed row
+    through ``ops.selective_scan(backend="pallas")`` gives each document
+    what it gets scanned alone from a zero state (a one-token document
+    included), outputs and input gradients alike."""
+    L = 96
+    u, dt, A, Bm, Cm, Dk, _ = _ssm_inputs(rng, 1, L, 16, 4)
+    pos = np.zeros((1, L), np.int32)
+    for s0, s1 in zip(_DOCS, list(_DOCS[1:]) + [L]):
+        pos[0, s0:s1] = np.arange(s1 - s0)
+    pos = jnp.asarray(pos)
+    kw = dict(backend="pallas", block_d=8, chunk=32)
+    w = jnp.asarray(rng.normal(size=(1, L, 16)), jnp.float32)
+
+    def packed(u, dt, Bm, Cm):
+        return kops_scan(u, dt, A, Bm, Cm, Dk, pos, **kw)
+
+    def alone(u, dt, Bm, Cm):
+        return _docs_alone(lambda u, dt, Bm, Cm, p: kops_scan(
+            u, dt, A, Bm, Cm, Dk, p, **kw), L, u, dt, Bm, Cm)
+
+    args = (u, dt, Bm, Cm)
+    if direction == "fwd":
+        np.testing.assert_allclose(packed(*args), alone(*args), atol=1e-5,
+                                   rtol=1e-5)
+        return
+    grad = lambda f: jax.grad(lambda *a: (f(*a) * w).sum(),
+                              argnums=(0, 1, 2, 3))
+    gp, ga = grad(packed)(*args), grad(alone)(*args)
+    for name, a, b in zip(("u", "dt", "B", "C"), gp, ga):
+        np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-4,
+                                   err_msg=f"grad {name}")
+
+
+@pytest.mark.parametrize("T,nL,G", [
+    (256, 16, 32),       # the train cells: chunk 256 over 4096 rows
+    (256, 1, 32),
+    (128, 4, 16),
+    (64, 2, 8),
+    (24, 1, 8),
+    (16, 2, 2),
+])
+def test_bwd_group(T, nL, G):
+    """The backward's row group: the most rows, up to 32, that divide the
+    chunk and leave the lane-dense dB, dC block whole 8-row tiles (T/G a
+    multiple of 8) unless the chunk is the whole sequence."""
+    from repro.kernels.selective_scan import _bwd_group
+    got = _bwd_group(T, nL)
+    assert got == G
+    assert T % got == 0
+    assert nL == 1 or (T // got) % 8 == 0

@@ -9,6 +9,8 @@ Acceptance surface of the single-matmul blocked path:
   * Mamba-1 degenerate dispatch: ``selective_scan`` ≡ heads with dh = 1
   * mamba2 block: single-token ``step_`` decode == full-sequence apply
   * structural memory claim: no (B, L, H, dh, N) trajectory in the jaxpr
+  * the dual (C·Bᵀ) in-chunk form: fwd, grads, carried and segment-end
+    states against the sequential reference, XLA and Pallas
 """
 import dataclasses
 
@@ -18,6 +20,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import ssm as core_ssm
+from repro.kernels import ops as kops
 from repro.kernels.ops import selective_scan_heads as kops_heads
 
 
@@ -273,3 +276,124 @@ def test_blocked_heads_jaxpr_has_no_full_trajectory():
                     yield from shapes(sub)
 
     assert not any(s == want for s in shapes(jaxpr.jaxpr))
+
+
+# ---------------------------------------------------------------------------
+# dual-form vs quad-form parity (XLA + Pallas interpret)
+# ---------------------------------------------------------------------------
+
+def test_heads_default_intra_is_quad_jaxpr():
+    """intra=None must trace exactly as the historical (quad) path."""
+    rng = np.random.default_rng(0)
+    u = jnp.asarray(rng.normal(size=(1, 64, 4, 8)), jnp.float32)
+    dt = jnp.asarray(rng.uniform(0.1, 0.5, (1, 64, 4)), jnp.float32)
+    Bm = jnp.asarray(rng.normal(size=(1, 64, 8)), jnp.float32)
+    A = -jnp.exp(jnp.asarray(rng.normal(size=(4,)), jnp.float32))
+    f = lambda intra: str(jax.make_jaxpr(
+        lambda u, dt, Bm: core_ssm.selective_scan_heads(
+            u, dt, A, Bm, Bm, None, method="blocked", chunk=32,
+            intra=intra))(u, dt, Bm))
+    assert f(None) == f("quad")
+    assert f(None) != f("dual")
+
+
+# ---------------------------------------------------------------------------
+# dual-form vs quad-form parity (XLA + Pallas interpret)
+# ---------------------------------------------------------------------------
+
+def _dual_inputs(B=2, L=96, H=3, P=16, N=8, seed=3):
+    rng = np.random.default_rng(seed)
+    u = jnp.asarray(rng.normal(size=(B, L, H, P)), jnp.float32)
+    dt = jnp.asarray(rng.uniform(0.05, 0.4, (B, L, H)), jnp.float32)
+    Bm = jnp.asarray(rng.normal(size=(B, L, N)), jnp.float32)
+    Cm = jnp.asarray(rng.normal(size=(B, L, N)), jnp.float32)
+    A = -jnp.exp(jnp.asarray(rng.normal(size=(H,)), jnp.float32))
+    Dk = jnp.asarray(rng.normal(size=(H,)), jnp.float32)
+    pos = jnp.asarray(np.concatenate(
+        [np.arange(41), np.arange(30), np.arange(L - 71)])[None]
+        .repeat(B, 0), jnp.int32)
+    return u, dt, Bm, Cm, A, Dk, pos
+
+
+@pytest.mark.parametrize("chunk", [16, 32, 64])
+def test_dual_fwd_parity_xla(chunk):
+    u, dt, Bm, Cm, A, Dk, pos = _dual_inputs()
+    ref = core_ssm.selective_scan_heads(u, dt, A, Bm, Cm, Dk, pos,
+                                        method="sequential")
+    got = core_ssm.selective_scan_heads(u, dt, A, Bm, Cm, Dk, pos,
+                                        method="blocked", chunk=chunk,
+                                        intra="dual")
+    np.testing.assert_allclose(got, ref, atol=2e-5, rtol=1e-5)
+
+
+def test_dual_grad_parity_xla():
+    u, dt, Bm, Cm, A, Dk, pos = _dual_inputs()
+
+    def loss(intra):
+        def f(u, dt, Bm, Cm):
+            kw = dict(method="sequential") if intra == "seq" else \
+                dict(method="blocked", chunk=32, intra=intra)
+            y = core_ssm.selective_scan_heads(u, dt, A, Bm, Cm, Dk, pos,
+                                              **kw)
+            return (y ** 2).sum()
+        return jax.grad(f, argnums=(0, 1, 2, 3))(u, dt, Bm, Cm)
+
+    for g_d, g_r in zip(loss("dual"), loss("seq")):
+        np.testing.assert_allclose(g_d, g_r, atol=2e-3, rtol=1e-4)
+
+
+def test_dual_state_and_ends_parity():
+    """h_last carry + collect_ends handoff match sequential under dual."""
+    u, dt, Bm, Cm, A, Dk, pos = _dual_inputs()
+    rng = np.random.default_rng(7)
+    h0 = jnp.asarray(rng.normal(size=(2, 3, 16, 8)), jnp.float32)
+    ends = jnp.asarray([[40, 70, 95, -1], [40, -1, 95, 70]], jnp.int32)
+    ref = core_ssm.selective_scan_heads(
+        u, dt, A, Bm, Cm, Dk, pos, h0=h0, method="sequential",
+        return_state=True, collect_ends=ends)
+    got = core_ssm.selective_scan_heads(
+        u, dt, A, Bm, Cm, Dk, pos, h0=h0, method="blocked", chunk=32,
+        intra="dual", return_state=True, collect_ends=ends)
+    for a, b in zip(ref, got):
+        np.testing.assert_allclose(b, a, atol=2e-5, rtol=1e-5)
+
+
+def test_dual_pallas_fwd_and_grad_parity():
+    u, dt, Bm, Cm, A, Dk, pos = _dual_inputs()
+    ref = core_ssm.selective_scan_heads(u, dt, A, Bm, Cm, Dk, pos,
+                                        method="sequential")
+    y = kops.selective_scan_heads(u, dt, A, Bm, Cm, Dk, pos,
+                                  backend="pallas", chunk=32,
+                                  schedule="blocked_heads_dual")
+    np.testing.assert_allclose(y, ref, atol=2e-5, rtol=1e-5)
+    # explicit subtile override
+    y8 = kops.selective_scan_heads(u, dt, A, Bm, Cm, Dk, pos,
+                                   backend="pallas", chunk=32,
+                                   schedule="blocked_heads_dual", sub_t=8)
+    np.testing.assert_allclose(y8, ref, atol=2e-5, rtol=1e-5)
+
+    def loss(fn):
+        return jax.grad(lambda u, dt, Bm, Cm: (fn(u, dt, Bm, Cm) ** 2).sum(),
+                        argnums=(0, 1, 2, 3))(u, dt, Bm, Cm)
+
+    g_d = loss(lambda u, dt, Bm, Cm: kops.selective_scan_heads(
+        u, dt, A, Bm, Cm, Dk, pos, backend="pallas", chunk=32,
+        schedule="blocked_heads_dual"))
+    g_r = loss(lambda u, dt, Bm, Cm: core_ssm.selective_scan_heads(
+        u, dt, A, Bm, Cm, Dk, pos, method="sequential"))
+    for a, b in zip(g_d, g_r):
+        np.testing.assert_allclose(a, b, atol=2e-3, rtol=1e-4)
+
+
+def test_pallas_non_dividing_sub_t_degrades_not_raises():
+    """A sub_t that does not divide the chunk degrades to a valid subtile
+    (largest divisor ≤ request), never crashes the trace."""
+    from repro.kernels.selective_scan import _pick_subtile
+    assert _pick_subtile(32, 7) == 4
+    assert _pick_subtile(16, 32) == 16
+    u, dt, Bm, Cm, A, Dk, pos = _dual_inputs()
+    ref = core_ssm.selective_scan_heads(u, dt, A, Bm, Cm, Dk, pos,
+                                        method="sequential")
+    y = kops.selective_scan_heads(u, dt, A, Bm, Cm, Dk, pos,
+                                  backend="pallas", chunk=32, sub_t=7)
+    np.testing.assert_allclose(y, ref, atol=2e-5, rtol=1e-5)

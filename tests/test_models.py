@@ -229,3 +229,26 @@ def test_pattern_units():
     cfg2 = get_config("xlstm-125m")
     model2 = build_model(cfg2)
     assert model2.n_units == 2 and model2.n_tail == 0
+
+
+@pytest.mark.parametrize("arch,use_pallas,scan_dtype,platform,want", [
+    ("mamba-110m", None, "float32", "tpu", "pallas"),
+    ("mamba-110m", None, "float32", "cpu", "xla"),
+    ("mamba-110m", None, "bfloat16", "tpu", "xla"),
+    ("mamba2-370m", None, "float32", "tpu", "xla"),
+    ("mamba-110m", True, "float32", "cpu", "pallas"),
+    ("mamba2-370m", True, "bfloat16", "cpu", "pallas"),
+    ("mamba-110m", False, "float32", "tpu", "xla"),
+])
+def test_scan_backend(monkeypatch, arch, use_pallas, scan_dtype, platform,
+                      want):
+    """The sequence scan's backend from the decay's form (per-channel for
+    Mamba-1, per-head scalar for Mamba-2), ``use_pallas``, ``scan_dtype``
+    and the platform traced for: the table of ``_scan_backend``."""
+    from repro.models.blocks import _scan_backend
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    cfg = dataclasses.replace(get_config(arch), use_pallas=use_pallas,
+                              scan_dtype=scan_dtype)
+    A = jnp.zeros((cfg.d_inner, cfg.d_state) if cfg.ssm_variant == "mamba1"
+                  else (cfg.n_ssm_heads,))
+    assert _scan_backend(cfg, A) == want

@@ -78,11 +78,8 @@ def _conv(x, w, b, pos):
     return ops.conv1d_pack(x, w, b, pos, backend="pallas")
 
 
-def _scan(schedule):
-    def f(u, dt, A, Bm, Cm, D, pos):
-        return ops.selective_scan(u, dt, A, Bm, Cm, D, pos, backend="pallas",
-                                  schedule=schedule)
-    return f
+def _scan(u, dt, A, Bm, Cm, D, pos):
+    return ops.selective_scan(u, dt, A, Bm, Cm, D, pos, backend="pallas")
 
 
 def _heads(schedule):
@@ -106,8 +103,7 @@ def _operands(kind):
 
 CASES = {
     "conv1d_pack": ("conv", _conv),
-    "scan_blocked": ("scan", _scan("blocked")),
-    "scan_step": ("scan", _scan("step")),
+    "scan_step": ("scan", _scan),
     "heads_blocked_heads": ("heads", _heads("blocked_heads")),
     "heads_blocked_heads_dual": ("heads", _heads("blocked_heads_dual")),
 }
@@ -127,25 +123,35 @@ def test_kernel_compiles_for_v5e(one_chip, name, direction):
     assert hlo.count("tpu_custom_call") >= (1 if direction == "fwd" else 2)
 
 
-@pytest.mark.parametrize("rows,length,channels", [
+_CELL_SHAPES = [
     (8, 4096, 2048),        # train.mamba-110m.pack4k
     (4, 4096, 4096),        # train.mamba-1.4b-s12.pack4k
     (4, 4096, 2048),        # train.mamba-1.4b.pack4k.x4, per shard
-])
-def test_scan_step_bwd_compiles_for_v5e_at_cell_shapes(one_chip, rows, length,
+]
+
+
+@pytest.mark.parametrize("direction,rows,length,channels", [
+    pytest.param(d, *shape, id=("fwd-" if d == "fwd" else "") +
+                 "-".join(map(str, shape)))
+    for d in ("bwd", "fwd") for shape in _CELL_SHAPES])
+def test_scan_step_bwd_compiles_for_v5e_at_cell_shapes(one_chip, direction,
+                                                       rows, length,
                                                        channels):
-    """The `step` backward at the train cells' per-device kernel shapes:
-    its state buffer and the row group's bulk adjoint tiles fit the scoped
-    VMEM (Mosaic refuses the compile otherwise)."""
+    """The scan kernel at the train cells' per-device kernel shapes, its
+    forward alone and with the backward: the backward's state buffer and
+    the row group's bulk adjoint tiles fit the scoped VMEM (Mosaic refuses
+    the compile otherwise)."""
     shapes = [((rows, length, channels), BF), ((rows, length, channels), BF),
               ((channels, N), jnp.float32), ((rows, length, N), BF),
               ((rows, length, N), BF), ((channels,), jnp.float32),
               ((rows, length), jnp.int32)]
     args = [jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
             for shape, dt in shapes]
-    hlo = jax.jit(_grad(_scan("step"))).lower(*args).compile().as_text()
+    fn = _scan if direction == "fwd" else _grad(_scan)
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
     calls = _kernel_calls(hlo)
-    assert {n for n, _ in calls} == {2, 6}, calls      # forward, backward
+    want = {2} if direction == "fwd" else {2, 6}        # forward, backward
+    assert {n for n, _ in calls} == want, calls
 
 
 # ---------------------------------------------------------------------------
